@@ -9,7 +9,26 @@ comparisons.
 """
 from __future__ import annotations
 
+import os
 import platform
+
+
+def use_compile_cache(root: str) -> str:
+    """Turn on JAX's persistent compilation cache for an entry point.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and
+    nothing else is set here; otherwise the cache lives at the fixed
+    path ``<root>/.jax_cache`` (a fixed path, because the directory is
+    part of the cache key).  Returns the directory in use.
+    """
+    import jax
+
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = os.path.join(os.path.abspath(root), ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def env_metadata() -> dict:

@@ -77,6 +77,10 @@ def main(argv=None) -> None:
                          "JSON meta block (compare.py-ignored)")
     args = ap.parse_args(argv)
 
+    from benchmarks._env import use_compile_cache
+
+    use_compile_cache(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
     modules = collect_modules(args.tier)
     print("name,us_per_call,derived")
     rows = []

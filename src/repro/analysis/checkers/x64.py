@@ -1,7 +1,7 @@
 """RPA004: x64 hygiene.
 
 The jit PON backend does float64 queue arithmetic under a *scoped*
-``jax.experimental.enable_x64()`` context (DESIGN §11); the ambient
+``jax.enable_x64(True)`` context (DESIGN §11); the ambient
 ``jax_enable_x64`` flag is never flipped, because an ambient flip
 changes dtypes (and therefore bits) for every other jitted program in
 the process — including the traffic sampler's pinned uint32/float32
@@ -12,8 +12,8 @@ streams.  This rule flags every ambient flip:
 * attribute assignment ``jax.config.jax_enable_x64 = ...``;
 * ``os.environ["JAX_ENABLE_X64"] = ...`` / ``putenv``.
 
-Reads of the flag and the scoped ``enable_x64()`` context manager are
-allowed.
+Reads of the flag and the scoped ``jax.enable_x64(True)`` context
+manager are allowed.
 """
 
 from __future__ import annotations
@@ -41,7 +41,7 @@ class X64HygieneChecker(Checker):
     name = "x64-hygiene"
     description = (
         "the ambient jax_enable_x64 flag must never be flipped — use the "
-        "scoped jax.experimental.enable_x64() context"
+        "scoped jax.enable_x64(True) context"
     )
 
     def check_module(self, mod: ModuleInfo) -> Iterator[Finding]:
@@ -55,7 +55,7 @@ class X64HygieneChecker(Checker):
                             mod, node,
                             "ambient `config.update(\"jax_enable_x64\", …)` "
                             "— flip x64 only through the scoped "
-                            "jax.experimental.enable_x64() context "
+                            "jax.enable_x64(True) context "
                             "(DESIGN §11 precision policy)",
                             symbols.get(node, "<module>"),
                         )
@@ -65,7 +65,7 @@ class X64HygieneChecker(Checker):
                             mod, node,
                             "setting JAX_ENABLE_X64 via the environment "
                             "flips x64 process-wide — use the scoped "
-                            "enable_x64() context",
+                            "jax.enable_x64(True) context",
                             symbols.get(node, "<module>"),
                         )
             elif isinstance(node, ast.Assign):
@@ -76,7 +76,7 @@ class X64HygieneChecker(Checker):
                             mod, target,
                             "direct assignment to the ambient "
                             "jax_enable_x64 flag — use the scoped "
-                            "enable_x64() context",
+                            "jax.enable_x64(True) context",
                             symbols.get(node, "<module>"),
                         )
                     elif (
@@ -90,6 +90,6 @@ class X64HygieneChecker(Checker):
                             mod, target,
                             "setting JAX_ENABLE_X64 via os.environ flips "
                             "x64 process-wide — use the scoped "
-                            "enable_x64() context",
+                            "jax.enable_x64(True) context",
                             symbols.get(node, "<module>"),
                         )

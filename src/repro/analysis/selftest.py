@@ -55,9 +55,9 @@ FIXTURES: List[Tuple[str, str, str, str]] = [
         "RPA004",
         "import jax\n"
         "jax.config.update(\"jax_enable_x64\", True)\n",
-        "from jax.experimental import enable_x64\n"
+        "import jax\n"
         "def run(fn):\n"
-        "    with enable_x64():\n"
+        "    with jax.enable_x64(True):\n"
         "        return fn()\n",
         "repro/net/_fixture_x64.py",
     ),

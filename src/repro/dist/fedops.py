@@ -64,9 +64,12 @@ def pod_weighted_mean(leaf: jnp.ndarray, w_norm: jnp.ndarray) -> jnp.ndarray:
 
     Same semantics as ``repro.fl.aggregation.fedavg`` (fp32 accumulate,
     cast back to the leaf dtype) but expressed over a stacked axis so it
-    lowers to a single cross-pod reduce under GSPMD.
+    lowers to a single cross-pod reduce under GSPMD. The contraction
+    asks for full f32 precision: a TPU's default f32 matmul precision
+    rounds the operands to bf16.
     """
-    g = jnp.tensordot(w_norm, leaf.astype(jnp.float32), axes=1)
+    g = jnp.tensordot(w_norm, leaf.astype(jnp.float32), axes=1,
+                      precision=jax.lax.Precision.HIGHEST)
     return jnp.broadcast_to(g.astype(leaf.dtype)[None], leaf.shape)
 
 
@@ -251,7 +254,8 @@ def fedbuff_pods(pending, global_params, weights: jnp.ndarray,
             decoded, cand = compress_deltas(
                 leaf_delta, scheme, topk_frac, residual=res
             )
-        upd = jnp.tensordot(w_norm, decoded.astype(jnp.float32), axes=1)
+        upd = jnp.tensordot(w_norm, decoded.astype(jnp.float32), axes=1,
+                            precision=jax.lax.Precision.HIGHEST)
         newg = (
             g.astype(jnp.float32) + server_lr * upd[None]
         ).astype(g.dtype)

@@ -9,6 +9,7 @@ kernel/ops/ref layout.
 from repro.kernels.ponsim.ops import (  # noqa: F401
     HISTORY_CYCLES,
     compile_count,
+    host_fallbacks,
     run_phase_device,
 )
 from repro.kernels.ponsim.ref import (  # noqa: F401

@@ -13,17 +13,26 @@ uses the O(N^2) *rank-sum* form instead:
 ``S_i`` is exactly the sorted-prefix "water already poured" for queue
 ``i`` under a *stable* oldest-first order, so the grants match the sort
 formulation (up to f32 accumulation order).  The comparison matrix is a
-natural MXU/VPU shape: a (BI, BJ) mask contracted against a BJ backlog
-tile, streamed over j-tiles with a fori accumulator — no sort, no
-scatter.
+natural VPU shape: a (BJ, BI) mask — contributing queues ``j`` on
+sublanes, granted queues ``i`` on lanes — reduced over sublanes, streamed
+over j-tiles with a fori accumulator: no sort, no scatter.
+
+Layout for the TPU tiling: a grid cell covers ``ROWS`` (8) rows by a
+128-lane tile of queues.  The j-side operands arrive pre-transposed as
+``(R / 8, N, 8)`` (queues on sublanes, the cell's 8 rows on lanes), so a
+row's contributing column is a plain ref slice and the kernel needs no
+in-register transpose; ``cap`` rides as an ``(R, 1)`` column block.
+Every index and loop bound is explicitly int32 — the kernel is traced
+inside the engine's scoped x64 context, and Mosaic takes no 64-bit type.
 
 Full drains must stay *exact* (the serve step detects them by float
 equality), so callers recover them from ``g == backlog`` in f32 — when
 ``room >= backlog`` the kernel emits bitwise ``backlog`` — and restore
 the f64 backlog for those lanes (``ops._waterfill_device``).
 
-The kernel is only dispatched on TPU backends; the CPU container
-exercises it through ``interpret=True`` (tests/test_ponsim_jit.py).
+On TPU the engine dispatches it compiled; the CPU test suite runs it
+through ``interpret=True`` (tests/test_ponsim_jit.py) and compiles it
+for a described v5e (tests/test_tpu_compile.py).
 """
 from __future__ import annotations
 
@@ -31,42 +40,43 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax import lax
 from jax.experimental import pallas as pl
 
 CAP_EPS = 1e-9   # repro.net.engine.CAP_EPS
 
+ROWS = 8         # rows per grid cell (f32 sublane tile)
 BLOCK_I = 128    # queues granted per grid cell (lane width)
 BLOCK_J = 128    # contribution tile streamed per fori step
+_I0 = np.int32(0)  # block index literal that stays int32 under x64
 
 
-def _waterfill_kernel(b_ref, key_ref, cap_ref, brow_ref, krow_ref, g_ref,
-                      *, n_cols: int):
+def _waterfill_kernel(b_ref, key_ref, cap_ref, bt_ref, kt_ref, g_ref, *,
+                      n_cols: int):
     i = pl.program_id(1)
-    bi = b_ref[0, :]                              # (BI,) this row's tile
-    ki = key_ref[0, :]
-    idx_i = i * BLOCK_I + jax.lax.broadcasted_iota(
-        jnp.int32, (BLOCK_I,), 0)
+    idx_i = i * BLOCK_I + lax.broadcasted_iota(jnp.int32, (1, BLOCK_I), 1)
+    n_tiles = jnp.int32(n_cols // BLOCK_J)
+    for r in range(ROWS):
+        ki = key_ref[r:r + 1, :]                  # (1, BI) granted keys
 
-    def body(jc, acc):
-        sl = (pl.dslice(0, 1), pl.dslice(jc * BLOCK_J, BLOCK_J))
-        bj = pl.load(brow_ref, sl)[0]             # (BJ,) whole-row tile
-        kj = pl.load(krow_ref, sl)[0]
-        idx_j = jc * BLOCK_J + jax.lax.broadcasted_iota(
-            jnp.int32, (BLOCK_J,), 0)
-        earlier = (kj[None, :] < ki[:, None]) | (
-            (kj[None, :] == ki[:, None])
-            & (idx_j[None, :] < idx_i[:, None])
-        )
-        return acc + jnp.sum(
-            jnp.where(earlier, bj[None, :], jnp.float32(0.0)), axis=1)
+        def body(jc, acc, r=r, ki=ki):
+            j0 = pl.multiple_of(jc * BLOCK_J, BLOCK_J)
+            bj = bt_ref[0, pl.ds(j0, BLOCK_J), r:r + 1]     # (BJ, 1)
+            kj = kt_ref[0, pl.ds(j0, BLOCK_J), r:r + 1]
+            idx_j = j0 + lax.broadcasted_iota(jnp.int32, (BLOCK_J, 1), 0)
+            earlier = (kj < ki) | ((kj == ki) & (idx_j < idx_i))
+            return acc + jnp.sum(
+                jnp.where(earlier, bj, jnp.float32(0.0)), axis=0,
+                keepdims=True)
 
-    n_tiles = n_cols // BLOCK_J
-    served = jax.lax.fori_loop(
-        0, n_tiles, body, jnp.zeros((BLOCK_I,), jnp.float32))
-    room = cap_ref[0] - served
-    g = jnp.where(room > jnp.float32(CAP_EPS),
-                  jnp.minimum(bi, room), jnp.float32(0.0))
-    g_ref[0, :] = g
+        served = lax.fori_loop(
+            jnp.int32(0), n_tiles, body,
+            jnp.zeros((1, BLOCK_I), jnp.float32))
+        room = cap_ref[r:r + 1, :] - served
+        g_ref[r:r + 1, :] = jnp.where(
+            room > jnp.float32(CAP_EPS),
+            jnp.minimum(b_ref[r:r + 1, :], room), jnp.float32(0.0))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -76,24 +86,34 @@ def waterfill_grants_pallas(backlog, key, cap, *, interpret: bool = False):
     backlog: (R, N) f32, key: (R, N) f32 (lower = older; +inf = empty),
     cap: (R,) f32.  N must be a multiple of 128 — pad with
     ``backlog=0, key=+inf`` (a zero-backlog queue contributes nothing
-    and takes nothing).  Returns (R, N) f32 grants; full drains are
-    bitwise ``backlog``.
+    and takes nothing).  Rows are padded to a multiple of 8 here.
+    Returns (R, N) f32 grants; full drains are bitwise ``backlog``.
     """
     r, n = backlog.shape
     if n % BLOCK_I:
         raise ValueError(f"n_queues {n} not a multiple of {BLOCK_I}")
-    grid = (r, n // BLOCK_I)
-    return pl.pallas_call(
+    rp = -(-r // ROWS) * ROWS
+    if rp != r:
+        backlog = jnp.pad(backlog, ((0, rp - r), (0, 0)))
+        key = jnp.pad(key, ((0, rp - r), (0, 0)),
+                      constant_values=jnp.float32(jnp.inf))
+        cap = jnp.pad(cap, (0, rp - r))
+
+    def _cols(x):                                 # (R, N) -> (R/8, N, 8)
+        return x.reshape(rp // ROWS, ROWS, n).transpose(0, 2, 1)
+
+    g = pl.pallas_call(
         functools.partial(_waterfill_kernel, n_cols=n),
-        grid=grid,
+        grid=(rp // ROWS, n // BLOCK_I),
         in_specs=[
-            pl.BlockSpec((1, BLOCK_I), lambda i, j: (i, j)),
-            pl.BlockSpec((1, BLOCK_I), lambda i, j: (i, j)),
-            pl.BlockSpec((1,), lambda i, j: (i,)),
-            pl.BlockSpec((1, n), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, n), lambda i, j: (i, 0)),
+            pl.BlockSpec((ROWS, BLOCK_I), lambda a, b: (a, b)),
+            pl.BlockSpec((ROWS, BLOCK_I), lambda a, b: (a, b)),
+            pl.BlockSpec((ROWS, 1), lambda a, b: (a, _I0)),
+            pl.BlockSpec((1, n, ROWS), lambda a, b: (a, _I0, _I0)),
+            pl.BlockSpec((1, n, ROWS), lambda a, b: (a, _I0, _I0)),
         ],
-        out_specs=pl.BlockSpec((1, BLOCK_I), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((r, n), jnp.float32),
+        out_specs=pl.BlockSpec((ROWS, BLOCK_I), lambda a, b: (a, b)),
+        out_shape=jax.ShapeDtypeStruct((rp, n), jnp.float32),
         interpret=interpret,
-    )(backlog, key, cap, backlog, key)
+    )(backlog, key, cap.reshape(rp, 1), _cols(backlog), _cols(key))
+    return g[:r]

@@ -35,21 +35,26 @@ marginal queue), and its head almost always sits within the last few
 cycles — so the carry keeps a ``Wr``-cycle prefix ring and the serve
 step walks that.  A marginal queue whose head has aged out of the ring
 (sustained overload) clears the ``exact`` flag; the host entry then
-returns ``None`` and the engine transparently re-runs that phase on
-the numpy path, so the backend is *always* exact, merely slower in
-regimes the device program was not sized for.
+returns ``None`` and the engine re-runs that phase on the numpy path,
+so the backend is *always* exact, merely slower in regimes the device
+program was not sized for.  Each such re-run is counted in
+:func:`host_fallbacks` (``"ring_overflow"``).
 
 Multi-tenant sweeps (``SweepCase.jobs``, PR 9) are NOT compiled: the
 per-cycle inter-job fairness split (``repro.net.jobs.job_fair_split``)
 and the per-job prefix spending would add a ragged job axis to every
-carry above.  The engine silently clears ``use_jit`` for multi-job
-sweeps and runs the numpy path (documented in DESIGN.md §12); a
+carry above.  The engine runs multi-job sweeps on the numpy path and
+counts each in :func:`host_fallbacks` (``"multi_job"``, DESIGN.md §12); a
 degenerate all-single-job sweep normalises to the plain layout and
 keeps this backend.
 
 Precision policy: queue state is float64, so the program is built and
-called under a scoped ``jax.experimental.enable_x64()`` context — the
+called under a scoped ``jax.enable_x64(True)`` context — the
 global x64 flag is never flipped for library users (regression-tested).
+The TPU has no native float64: XLA emulates it, and every op on this
+path compiles in seconds except ``cumsum``, whose float64 reduce-window
+form the TPU compiler does not finish, so prefix sums go through
+``ref.prefix_sum`` (a parallel scan).
 The fused sampler keeps the traffic kernels' explicit uint32/float32
 dtypes, which is what makes its stream bit-identical to the host
 backends.
@@ -62,7 +67,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.experimental import enable_x64
 
 from repro.kernels.ponsim import ref as _ref
 from repro.kernels.ponsim.kernel import waterfill_grants_pallas
@@ -79,10 +83,23 @@ HISTORY_CYCLES = 128                 # bg prefix ring length (pow2)
 _programs: dict = {}
 _COMPILE_COUNT = 0                   # bumped at trace time (tested)
 _PALLAS_INTERPRET = False            # tests flip to run the kernel on CPU
+# phases (or whole sweeps) that asked for the device and ran on the host
+# numpy engine instead: "ring_overflow" (the bg ring walk lost
+# exactness) and "multi_job" (the carry has no job axis)
+_HOST_FALLBACKS = {"ring_overflow": 0, "multi_job": 0}
 
 
 def compile_count() -> int:
     return _COMPILE_COUNT
+
+
+def host_fallbacks() -> dict:
+    """Per-reason count of device requests the host engine served."""
+    return dict(_HOST_FALLBACKS)
+
+
+def note_host_fallback(reason: str) -> None:
+    _HOST_FALLBACKS[reason] += 1
 
 
 def clear_cache() -> None:
@@ -117,7 +134,8 @@ def _build_program(spec, lay, n_draws: int):
     """Close the static layout/config into one jitted phase program."""
     global _COMPILE_COUNT
     (mode, R, U, N, S, P, has_bg, has_cps, has_deadline, has_outage,
-     fill_unfinished, use_pallas, max_slots, _ndraws, _onu_sig) = spec
+     fill_unfinished, use_pallas, max_slots, _ndraws, _nlevels,
+     _onu_sig) = spec
     single, identity = lay.single, lay.identity
     fast = mode == "fcfs" and single
     lay_onu = np.asarray(lay.onu, np.int64)              # (U,)
@@ -192,7 +210,7 @@ def _build_program(spec, lay, n_draws: int):
             want = jnp.minimum(
                 want, jnp.take_along_axis(backlog_onu, dyn["sonu"], 1))
             want = jnp.where(active & (want > 0.0), want, 0.0)
-            prefix = jnp.cumsum(want, axis=1)
+            prefix = _ref.prefix_sum(want)
             grants = jnp.minimum(
                 want, jnp.maximum(cap[:, None] - (prefix - want), 0.0))
             rows = jnp.broadcast_to(jnp.arange(R)[:, None], (R, S))
@@ -267,7 +285,7 @@ def _build_program(spec, lay, n_draws: int):
                     lambda _: _ref.sample_window_ref(
                         dyn["keys"], dyn["thr"], k >> _WIN_SHIFT,
                         n_onus=N, n_draws=n_draws,
-                        inv_burst=dyn["inv_burst"],
+                        burst_thr=dyn["burst_thr"],
                         packet_bits=dyn["packet_bits"]),
                     lambda _: c["buf"], None)
                 bits = lax.dynamic_index_in_dim(
@@ -359,7 +377,7 @@ def _build_program(spec, lay, n_draws: int):
                             rws = jnp.arange(R)[:, None]
                             bs = jnp.zeros((R, Wr), b.dtype).at[
                                 rws, aidx].add(bval)
-                            flip = jnp.cumsum(bs[:, ::-1], axis=1)
+                            flip = _ref.prefix_sum(bs[:, ::-1])
                             csame = flip[:, ::-1]          # Σ age ≥ a
                             colder = csame - bs            # Σ age > a
                             tq = jnp.take_along_axis(colder, aidx, 1)
@@ -368,7 +386,7 @@ def _build_program(spec, lay, n_draws: int):
                             fullq = has & (cq <= capq)
                             marg = has & (tq < capq) & (cq > capq)
                             bm = jnp.where(marg, bval, 0.0)
-                            wq = jnp.cumsum(bm, axis=1) - bm
+                            wq = _ref.prefix_sum(bm) - bm
                             room = capq - (tq + wq)
                             pour = jnp.where(room > CAP_EPS,
                                              jnp.minimum(b, room), 0.0)
@@ -577,26 +595,17 @@ def _build_program(spec, lay, n_draws: int):
             done_t = jnp.where(left, t + prop, done_t)
         return done_t, rem_f, final["exact"]
 
-    donate = () if jax.default_backend() == "cpu" else (0,)
-    return jax.jit(program, donate_argnums=donate)
+    return jax.jit(program)
 
 
-def run_phase_device(cfg, lay, rem_init, ready_t, mode: str, *,
-                     keys=None, lams=None, slot_arrays=None,
-                     max_t: float = 600.0, fill_unfinished: bool = True,
-                     cap_row=None, cps_cap: Optional[float] = None,
-                     n_pons: int = 1, deadline_row=None,
-                     outage_row=None, use_pallas: Optional[bool] = None,
-                     ) -> Optional[Tuple[np.ndarray, np.ndarray]]:
-    """Run one phase on device.  Mirrors ``engine._run_phase``'s
-    signature with the host ``_Stream`` replaced by its raw
-    ``(keys, lams)`` so sampling fuses into the program.
-
-    Returns ``(done_t, rem)`` numpy arrays, or ``None`` when the bg
-    ring walk lost exactness (sustained overload aged a marginal head
-    out of the ``HISTORY_CYCLES`` ring) — the caller re-runs the phase
-    on the numpy engine.
-    """
+def _phase_inputs(cfg, lay, rem_init, ready_t, mode: str, *,
+                  keys=None, lams=None, slot_arrays=None,
+                  max_t: float = 600.0, fill_unfinished: bool = True,
+                  cap_row=None, cps_cap: Optional[float] = None,
+                  n_pons: int = 1, deadline_row=None,
+                  outage_row=None, use_pallas: Optional[bool] = None):
+    """Host tables of one phase: ``(spec, dyn, n_draws)``, where
+    ``spec`` keys the program cache and ``dyn`` holds its inputs."""
     R, U = rem_init.shape
     N = int(cfg.n_onus)
     cyc = float(cfg.cycle_time_s)
@@ -683,13 +692,14 @@ def run_phase_device(cfg, lay, rem_init, ready_t, mode: str, *,
     if has_bg:
         from repro.kernels.traffic.ops import (_poisson_thresholds,
                                                _tail_bound)
+        from repro.kernels.traffic.ref import geometric_tables
         from repro.net.engine import PACKET_BITS
 
         lam_w = np.asarray(lams, np.float64) * WINDOW
         n_draws = _tail_bound(float(lam_w.max()))
         dyn["keys"] = np.asarray(keys, np.uint32)
         dyn["thr"] = _poisson_thresholds(lam_w, n_draws)
-        dyn["inv_burst"] = np.float32(1.0 / cfg.bg_burst_packets)
+        dyn["burst_thr"] = geometric_tables(1.0 / cfg.bg_burst_packets)[1]
         dyn["packet_bits"] = np.float32(PACKET_BITS)
     S = 1
     if mode == "bs":
@@ -705,15 +715,41 @@ def run_phase_device(cfg, lay, rem_init, ready_t, mode: str, *,
     spec = (mode, R, U, N, S, int(n_pons), has_bg, has_cps,
             has_deadline, has_outage, bool(fill_unfinished),
             bool(use_pallas), max_slots, n_draws,
+            len(dyn.get("burst_thr", ())),
             hash(np.asarray(lay.onu).tobytes()))
 
-    with enable_x64():
+    return spec, dyn, n_draws
+
+
+def phase_program(cfg, lay, rem_init, ready_t, mode: str, **kw):
+    """The jitted (not yet compiled) program of one phase and its host
+    inputs ``dyn``; arguments as for :func:`run_phase_device`.  Lower it
+    under ``jax.enable_x64(True)`` — its queue state is float64."""
+    spec, dyn, n_draws = _phase_inputs(cfg, lay, rem_init, ready_t, mode,
+                                       **kw)
+    return _build_program(spec, lay, n_draws), dyn
+
+
+def run_phase_device(cfg, lay, rem_init, ready_t, mode: str, **kw,
+                     ) -> Optional[Tuple[np.ndarray, np.ndarray]]:
+    """Run one phase on device.  Mirrors ``engine._run_phase``'s
+    signature with the host ``_Stream`` replaced by its raw
+    ``(keys, lams)`` so sampling fuses into the program.
+
+    Returns ``(done_t, rem)`` numpy arrays, or ``None`` when the bg
+    ring walk lost exactness (sustained overload aged a marginal head
+    out of the ``HISTORY_CYCLES`` ring) — the caller re-runs the phase
+    on the numpy engine and counts it (:func:`host_fallbacks`).
+    """
+    spec, dyn, n_draws = _phase_inputs(cfg, lay, rem_init, ready_t, mode,
+                                       **kw)
+    with jax.enable_x64(True):
+        dyn_dev = {key: jnp.asarray(val) for key, val in dyn.items()}
         prog = _programs.get(spec)
         if prog is None:
             if len(_programs) > 64:
                 _programs.clear()
             prog = _programs[spec] = _build_program(spec, lay, n_draws)
-        dyn_dev = {key: jnp.asarray(val) for key, val in dyn.items()}
         done_t, rem, exact = prog(dyn_dev)
         if not bool(exact):
             return None

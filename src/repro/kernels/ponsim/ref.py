@@ -17,7 +17,8 @@ semantic mirrors of their numpy counterparts:
 * :func:`sample_window_ref` — one 64-cycle window of the counter-based
   Poisson-burst sampler with a *traced* window index, so the scan can
   generate arrival bits on-device. It reuses the integer threefry and
-  the float32 burst mappings of ``repro.kernels.traffic.ref`` verbatim,
+  the integer count and burst-length thresholds of
+  ``repro.kernels.traffic.ref`` verbatim,
   which is what makes the fused stream bit-identical to the host
   sampler (pinned by tests/test_ponsim_jit.py).
 
@@ -33,13 +34,22 @@ from jax import lax
 
 from repro.kernels.traffic.ref import (
     _WIN_SHIFT,
-    UNIT_SCALE,
     WINDOW,
+    burst_length,
     draw_key,
     threefry2x32_ref,
 )
 
 CAP_EPS = 1e-9        # repro.net.engine.CAP_EPS
+
+
+def prefix_sum(x):
+    """Inclusive prefix sum along axis 1 as a parallel scan.
+
+    ``jnp.cumsum`` lowers to an O(N^2) reduce-window that the TPU
+    compiler does not finish in bounded time for float64; the
+    log-depth ``associative_scan`` compiles in well under a second."""
+    return lax.associative_scan(jnp.add, x, axis=1)
 
 
 def waterfill_grants_ref(backlog, hol, cap):
@@ -60,7 +70,7 @@ def waterfill_grants_ref(backlog, hol, cap):
         order = jnp.argsort(hol, axis=1, stable=True)
         rows = jnp.arange(R)[:, None]
         b_s = jnp.take_along_axis(backlog, order, axis=1)
-        prefix = jnp.cumsum(b_s, axis=1)
+        prefix = prefix_sum(b_s)
         room = cap[:, None] - (prefix - b_s)
         g_s = jnp.where(room > CAP_EPS, jnp.minimum(b_s, room), 0.0)
         g = jnp.zeros_like(backlog).at[rows, order].set(g_s)
@@ -86,7 +96,7 @@ def cps_waterfill_ref(want, cap):
     tot = want.sum(axis=1)
     over = tot > cap + CAP_EPS
     ws = jnp.sort(want, axis=1)
-    cum = jnp.cumsum(ws, axis=1)
+    cum = prefix_sum(ws)
     prev = cum - ws
     mu_k = (cap - prev) / (P - jnp.arange(P, dtype=want.dtype))
     kk = jnp.argmax(mu_k <= ws, axis=1)
@@ -95,18 +105,18 @@ def cps_waterfill_ref(want, cap):
 
 
 def sample_window_ref(keys, thresholds, win, *, n_onus: int,
-                      n_draws: int, inv_burst, packet_bits):
+                      n_draws: int, burst_thr, packet_bits):
     """Arrival bits ``(R, WINDOW, n_onus)`` float32 for window ``win``.
 
     The in-scan variant of ``traffic.ref.sample_arrival_bits_ref``: one
     window at a time, with the window index *traced* (it is the scan
     counter ``k >> 6``) instead of static. Same draws, same integer
-    thresholds, same float32 burst mappings — the produced stream is
+    count and burst-length thresholds (``burst_thr`` from
+    ``traffic.ref.geometric_tables``) — the produced stream is
     bit-identical to every host backend (tested).
     """
     keys = jnp.asarray(keys, jnp.uint32)
     thresholds = jnp.asarray(thresholds, jnp.int32)
-    inv_burst = jnp.asarray(inv_burst, jnp.float32)
     R = keys.shape[0]
     c0 = jnp.asarray(win, jnp.uint32)                    # window counter
     c1 = jnp.arange(n_onus, dtype=jnp.uint32)[None, :]
@@ -129,17 +139,12 @@ def sample_window_ref(keys, thresholds, win, *, n_onus: int,
     # half of the draws is usually all-dead: it is scattered (and its
     # threefry evaluated) only under a ``lax.cond`` — adding nothing is
     # bitwise adding zeros, so the skip is exact.
-    inv_log_q = jnp.float32(1.0) / jnp.log1p(-inv_burst)
-
     def _scatter(buf, j0: int, j1: int):
         j = jnp.arange(j0 + 1, j1 + 1, dtype=jnp.uint32)[None, :, None]
         bd0, bd1 = draw_key(k0[:, None, :], k1[:, None, :], j)
         x0, x1 = threefry2x32_ref(bd0, bd1, c0, c1[None])  # (R, j, N)
         place = (x0 >> jnp.uint32(32 - _WIN_SHIFT)).astype(jnp.int32)
-        u = (x1 >> jnp.uint32(8)).astype(jnp.float32) * jnp.float32(
-            UNIT_SCALE
-        )
-        glen = jnp.float32(1.0) + jnp.floor(jnp.log1p(-u) * inv_log_q)
+        glen = burst_length(x1, burst_thr)
         live = j.astype(jnp.int32) <= count[:, None, :]
         return buf.at[
             jnp.arange(R)[:, None, None], place,

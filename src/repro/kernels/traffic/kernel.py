@@ -9,11 +9,14 @@ accumulated output-stationary — burst ``j``'s placement (top 6 bits of
 word 0) is compared against every cycle row of its window, its
 geometric length (word 1) added where it lands.
 
-Distribution parameters (``inv_burst``, ``packet_bits``, ``n_draws``)
-are compile-time constants — a sweep has a handful of distinct values —
-while the per-case window rate ``lam_w`` and the seek offset ``win0``
-stay runtime inputs so one compilation serves every chunk of every
-case.
+Distribution parameters (``packet_bits``, ``n_draws``) are
+compile-time constants — a sweep has a handful of distinct values —
+while the per-case keys and window-rate thresholds, the burst-length
+thresholds and the seek offset ``win0`` stay runtime inputs in SMEM so
+one compilation serves every chunk of every case.  Burst lengths count
+integer thresholds (``ref.geometric_tables``) instead of evaluating
+``log1p`` in the kernel, whose float32 result on a TPU differs from the
+CPU's in the last ulp for some inputs.
 
 The kernel intentionally avoids ``pltpu.prng_*``: the hand-rolled
 threefry keeps the stream identical to the XLA oracle (``ref.py``) and
@@ -27,20 +30,22 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.traffic.ref import (
     _C240,
     _ROTS,
     KEY_WEYL_0,
     KEY_WEYL_1,
-    UNIT_SCALE,
     WINDOW,
 )
 
 DEFAULT_BLOCK_WINDOWS = 4
 _LANE = 128                       # TPU lane tiling for the trailing axis
+_I0 = np.int32(0)                 # block index literal, int32 under x64
 
 
 def _rotl(x, r: int):
@@ -61,14 +66,15 @@ def _threefry2x32(k0, k1, c0, c1):
     return x0, x1
 
 
-def _traffic_kernel(keys_ref, thr_ref, win0_ref, out_ref, *,
+def _traffic_kernel(keys_ref, thr_ref, glen_ref, win0_ref, out_ref, *,
                     block_windows: int, n_onus_pad: int, n_draws: int,
-                    inv_burst: float, packet_bits: float):
+                    n_levels: int, packet_bits: float):
     i = pl.program_id(1)
-    k0 = keys_ref[0, 0]
-    k1 = keys_ref[0, 1]
+    k0 = keys_ref[0, 0, 0].astype(jnp.uint32)
+    k1 = keys_ref[0, 0, 1].astype(jnp.uint32)
     wshape = (block_windows, n_onus_pad)
-    c0 = (win0_ref[0] + jnp.uint32(i * block_windows)
+    c0 = (win0_ref[0].astype(jnp.uint32)
+          + (i * block_windows).astype(jnp.uint32)
           + lax.broadcasted_iota(jnp.uint32, wshape, 0))
     c1 = lax.broadcasted_iota(jnp.uint32, wshape, 1)
 
@@ -84,17 +90,17 @@ def _traffic_kernel(keys_ref, thr_ref, win0_ref, out_ref, *,
     b24 = (w0 >> jnp.uint32(8)).astype(jnp.int32)
 
     def pois_body(j, count):
-        return count + (b24 > thr_ref[0, j]).astype(jnp.int32)
+        return count + (b24 > thr_ref[0, 0, j]).astype(jnp.int32)
 
     count = lax.fori_loop(
-        0, n_draws, pois_body, jnp.zeros(wshape, jnp.int32)
+        jnp.int32(0), jnp.int32(n_draws), pois_body,
+        jnp.zeros(wshape, jnp.int32)
     )
 
-    inv_log_q = jnp.float32(1.0) / jnp.log1p(jnp.float32(-inv_burst))
     n_cyc = block_windows * WINDOW
     cyc_in_win = lax.broadcasted_iota(
         jnp.int32, (n_cyc, n_onus_pad), 0
-    ) % WINDOW
+    ) & jnp.int32(WINDOW - 1)
 
     def expand(x):
         """(windows, onus) -> (windows*64 cycles, onus)."""
@@ -107,29 +113,34 @@ def _traffic_kernel(keys_ref, thr_ref, win0_ref, out_ref, *,
     def burst_body(j, packets):
         x0, x1 = words(j)
         place = (x0 >> jnp.uint32(32 - 6)).astype(jnp.int32)
-        u = (x1 >> jnp.uint32(8)).astype(jnp.float32) * jnp.float32(
-            UNIT_SCALE
-        )
-        glen = jnp.float32(1.0) + jnp.floor(jnp.log1p(-u) * inv_log_q)
+        u24 = (x1 >> jnp.uint32(8)).astype(jnp.int32)
+
+        def level(n, g):
+            return g + (u24 >= glen_ref[0, n]).astype(jnp.int32)
+
+        glen = (1 + lax.fori_loop(jnp.int32(0), jnp.int32(n_levels),
+                                  level, jnp.zeros(wshape, jnp.int32))
+                ).astype(jnp.float32)
         hit = (expand(place) == cyc_in_win) & (j <= count_c)
         return packets + jnp.where(hit, expand(glen), jnp.float32(0.0))
 
     packets = lax.fori_loop(
-        1, n_draws + 1, burst_body,
+        jnp.int32(1), jnp.int32(n_draws + 1), burst_body,
         jnp.zeros((n_cyc, n_onus_pad), jnp.float32),
     )
     out_ref[0, :, :] = packets * jnp.float32(packet_bits)
 
 
-def sample_arrival_bits_tpu(keys, cycle0: int, thresholds, *,
+def sample_arrival_bits_tpu(keys, cycle0: int, thresholds, burst_thr, *,
                             n_cycles: int, n_onus: int, n_draws: int,
-                            inv_burst: float, packet_bits: float,
+                            packet_bits: float,
                             block_windows: int = DEFAULT_BLOCK_WINDOWS,
                             interpret: bool = False):
     """Arrival bits ``(B, n_cycles, n_onus)`` float32 via the TPU kernel.
 
     ``keys`` uint32 ``(B, 2)``; ``thresholds`` int32 ``(B, n_draws)``
-    from ``ref.poisson_thresholds``; ``cycle0`` the absolute cycle of
+    from ``ref.poisson_thresholds``; ``burst_thr`` int32 from
+    ``ref.geometric_tables``; ``cycle0`` the absolute cycle of
     the first row. Only the intra-window offset (``cycle0 % 64``, at
     most 64 alignment classes) is compile-time; the window base stays a
     runtime input so one compilation serves every chunk of a stream.
@@ -137,8 +148,8 @@ def sample_arrival_bits_tpu(keys, cycle0: int, thresholds, *,
     win0 = cycle0 >> 6
     lo = cycle0 - (win0 << 6)
     return _sample_tpu_jit(
-        keys, jnp.uint32(win0), thresholds, lo=lo, n_cycles=n_cycles,
-        n_onus=n_onus, n_draws=n_draws, inv_burst=inv_burst,
+        keys, jnp.uint32(win0), thresholds, burst_thr, lo=lo,
+        n_cycles=n_cycles, n_onus=n_onus, n_draws=n_draws,
         packet_bits=packet_bits, block_windows=block_windows,
         interpret=interpret,
     )
@@ -147,11 +158,10 @@ def sample_arrival_bits_tpu(keys, cycle0: int, thresholds, *,
 @functools.partial(
     jax.jit,
     static_argnames=("lo", "n_cycles", "n_onus", "n_draws",
-                     "inv_burst", "packet_bits", "block_windows",
-                     "interpret"),
+                     "packet_bits", "block_windows", "interpret"),
 )
-def _sample_tpu_jit(keys, win0, thresholds, *, lo: int, n_cycles: int,
-                    n_onus: int, n_draws: int, inv_burst: float,
+def _sample_tpu_jit(keys, win0, thresholds, burst_thr, *, lo: int,
+                    n_cycles: int, n_onus: int, n_draws: int,
                     packet_bits: float, block_windows: int,
                     interpret: bool):
     B = keys.shape[0]
@@ -160,26 +170,37 @@ def _sample_tpu_jit(keys, win0, thresholds, *, lo: int, n_cycles: int,
     n_win_pad = math.ceil(n_win / bw) * bw
     n_onu_pad = math.ceil(n_onus / _LANE) * _LANE
     grid = (B, n_win_pad // bw)
-    win0_arr = jnp.reshape(jnp.asarray(win0, jnp.uint32), (1,))
+    # keys, thresholds and the window base are scalars read by the
+    # kernel body, so they ride in SMEM (keys as int32 bit patterns)
+    win0_arr = lax.bitcast_convert_type(
+        jnp.reshape(jnp.asarray(win0, jnp.uint32), (1,)), jnp.int32)
+    n_levels = burst_thr.shape[0]
+
+    def smem_row(width):              # this case's (1, width) row
+        return pl.BlockSpec((1, 1, width), lambda b, i: (b, _I0, _I0),
+                            memory_space=pltpu.SMEM)
+
     out = pl.pallas_call(
         functools.partial(
             _traffic_kernel,
             block_windows=bw, n_onus_pad=n_onu_pad, n_draws=n_draws,
-            inv_burst=inv_burst, packet_bits=packet_bits,
+            n_levels=n_levels, packet_bits=packet_bits,
         ),
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 2), lambda b, i: (b, 0)),
-            pl.BlockSpec((1, n_draws), lambda b, i: (b, 0)),
-            pl.BlockSpec((1,), lambda b, i: (0,)),
-        ],
+        in_specs=[smem_row(2), smem_row(n_draws),
+                  pl.BlockSpec((1, n_levels), lambda b, i: (_I0, _I0),
+                               memory_space=pltpu.SMEM),
+                  pl.BlockSpec((1,), lambda b, i: (_I0,),
+                               memory_space=pltpu.SMEM)],
         out_specs=pl.BlockSpec(
-            (1, bw * WINDOW, n_onu_pad), lambda b, i: (b, i, 0)
+            (1, bw * WINDOW, n_onu_pad), lambda b, i: (b, i, _I0)
         ),
         out_shape=jax.ShapeDtypeStruct(
             (B, n_win_pad * WINDOW, n_onu_pad), jnp.float32
         ),
         interpret=interpret,
-    )(jnp.asarray(keys, jnp.uint32),
-      jnp.asarray(thresholds, jnp.int32), win0_arr)
+    )(lax.bitcast_convert_type(jnp.asarray(keys, jnp.uint32),
+                               jnp.int32)[:, None, :],
+      jnp.asarray(thresholds, jnp.int32)[:, None, :],
+      jnp.asarray(burst_thr, jnp.int32)[None, :], win0_arr)
     return out[:, lo:lo + n_cycles, :n_onus]

@@ -138,11 +138,6 @@ def _threefry_blocked(k0: int, k1: int, c0_flat, c1_flat, out0, out1,
             np.add(x1, inj[block][1], out=x1)
 
 
-@functools.lru_cache(maxsize=8)
-def _geometric_lut(inv_burst: float) -> np.ndarray:
-    return np.asarray(_ref.geometric_lut(inv_burst))
-
-
 _cdf_cache: Dict[Tuple[bytes, int], np.ndarray] = {}
 
 
@@ -255,7 +250,7 @@ def _burst_bits(keys, cnt, c0_flat, c1_flat, cycle0, win0, n_cycles,
         kd0, kd1, c0_flat[g_f], c1_flat[g_f]
     )
     place = (x0 >> np.uint32(32 - 6)).astype(np.int64)
-    glen = _geometric_lut(float(inv_burst))[x1 >> np.uint32(8)]
+    glen = _ref.geometric_tables(float(inv_burst))[0][x1 >> np.uint32(8)]
     win_i = g_f // n_onus
     onu_i = g_f - win_i * n_onus
     cyc = (win_i << 6) + place - (cycle0 - (win0 << 6))
@@ -293,10 +288,10 @@ def _sample_numpy(keys, cycle0, lam_arr, inv_burst, packet_bits,
 @functools.partial(
     jax.jit, static_argnames=("cycle0", "n_cycles", "n_onus", "n_draws"),
 )
-def _sample_xla(keys, thresholds, inv_burst, packet_bits, *, cycle0,
+def _sample_xla(keys, thresholds, burst_thr, packet_bits, *, cycle0,
                 n_cycles, n_onus, n_draws):
     return _ref.sample_arrival_bits_ref(
-        keys, cycle0, thresholds, inv_burst, packet_bits,
+        keys, cycle0, thresholds, burst_thr, packet_bits,
         n_cycles=n_cycles, n_onus=n_onus, n_draws=n_draws,
     )
 
@@ -330,9 +325,10 @@ def sample_arrival_bits(keys, cycle0: int, n_cycles: int, n_onus: int,
     thresholds = _poisson_thresholds(
         np.asarray(lam_arr, np.float64) * _ref.WINDOW, n_draws
     )
+    burst_thr = _ref.geometric_tables(float(inv_burst))[1]
     if backend == "xla":
         out = _sample_xla(
-            keys, thresholds, inv_burst, packet_bits,
+            keys, thresholds, burst_thr, packet_bits,
             cycle0=int(cycle0),
             n_cycles=n_cycles, n_onus=n_onus, n_draws=n_draws,
         )
@@ -340,9 +336,9 @@ def sample_arrival_bits(keys, cycle0: int, n_cycles: int, n_onus: int,
         from repro.kernels.traffic.kernel import sample_arrival_bits_tpu
 
         out = sample_arrival_bits_tpu(
-            keys, int(cycle0), thresholds,
+            keys, int(cycle0), thresholds, burst_thr,
             n_cycles=n_cycles, n_onus=n_onus, n_draws=n_draws,
-            inv_burst=float(inv_burst), packet_bits=float(packet_bits),
+            packet_bits=float(packet_bits),
             interpret=(backend == "pallas_interpret"),
         )
     else:

@@ -19,10 +19,14 @@ The draw index is folded into the threefry *key* (Weyl increments), the
 ``(window, onu)`` pair is the *counter*, so any cycle range is
 O(1)-seekable and chunk boundaries can never change the stream. Burst
 counts go through host-built integer thresholds
-(:func:`poisson_thresholds`) and burst lengths through an XLA-evaluated
-float32 LUT (:func:`geometric_lut`) with a fixed operation order, so
-the Pallas kernel and the sparse numpy host path (``ops.py``) reproduce
-the stream bit-for-bit (tested).
+(:func:`poisson_thresholds`). Burst lengths are defined by a float32
+LUT (:func:`geometric_lut`) that XLA evaluates on the CPU backend; the
+device paths count integer thresholds of that LUT
+(:func:`geometric_tables`, :func:`burst_length`) instead of evaluating
+``log1p`` themselves, because a TPU's float32 ``log1p`` differs from the
+CPU's in the last ulp for some inputs. So the Pallas kernels, this
+oracle and the sparse numpy host path (``ops.py``) reproduce the stream
+bit-for-bit on every backend (tested).
 """
 from __future__ import annotations
 
@@ -30,6 +34,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 # Threefry-2x32 constants (Random123 / JAX's PRNG).
@@ -108,8 +113,9 @@ def poisson_thresholds(lam_w, n_draws: int):
 @functools.partial(jax.jit, static_argnames=())
 def geometric_lut(inv_burst):
     """int32 ``(2**24,)`` map from 24-bit uniform to a geometric(p)
-    burst length, evaluated once in XLA float32 so every backend applies
-    the identical (ulp-exact) inverse CDF."""
+    burst length: the inverse CDF in XLA float32 with a fixed operation
+    order.  The stream is defined by its value on the CPU backend
+    (:func:`geometric_tables`)."""
     inv_burst = jnp.asarray(inv_burst, jnp.float32)
     u = jnp.arange(1 << 24, dtype=jnp.uint32).astype(jnp.float32) * (
         jnp.float32(UNIT_SCALE)
@@ -119,19 +125,48 @@ def geometric_lut(inv_burst):
             + jnp.floor(jnp.log1p(-u) * inv_log_q)).astype(jnp.int32)
 
 
-def sample_arrival_bits_ref(keys, cycle0, thresholds, inv_burst,
+@functools.lru_cache(maxsize=8)
+def geometric_tables(inv_burst: float):
+    """``(lut, thresholds)`` of the burst-length map for ``inv_burst``.
+
+    ``lut`` is :func:`geometric_lut` evaluated on the CPU backend,
+    whichever device is the default.  The map is non-decreasing in the
+    24-bit uniform (checked), so it is exactly
+    ``length(u24) = 1 + #{n : u24 >= thresholds[n]}`` with int32
+    ``thresholds[n]`` the first ``u24`` whose length exceeds ``n + 1``
+    — integer compares that every backend evaluates identically.
+    """
+    with jax.default_device(jax.devices("cpu")[0]):
+        lut = np.asarray(geometric_lut(float(inv_burst)))
+    if np.any(np.diff(lut) < 0):
+        raise ValueError(f"burst-length LUT for inv_burst={inv_burst} "
+                         "is not monotone")
+    levels = np.arange(2, int(lut[-1]) + 1)
+    thresholds = np.searchsorted(lut, levels, side="left").astype(np.int32)
+    return lut, thresholds
+
+
+def burst_length(x1, thresholds):
+    """float32 burst lengths from threefry word 1 (its top 24 bits) and
+    int32 :func:`geometric_tables` thresholds."""
+    u24 = (jnp.asarray(x1, jnp.uint32) >> jnp.uint32(8)).astype(jnp.int32)
+    n = jnp.searchsorted(jnp.asarray(thresholds, jnp.int32), u24,
+                         side="right")
+    return (n + 1).astype(jnp.float32)
+
+
+def sample_arrival_bits_ref(keys, cycle0, thresholds, burst_thr,
                             packet_bits, *, n_cycles: int, n_onus: int,
                             n_draws: int):
     """Arrival bits ``(B, n_cycles, n_onus)`` float32.
 
     ``keys``: uint32 ``(B, 2)`` stream keys; ``thresholds``: int32
     ``(B, n_draws)`` from :func:`poisson_thresholds` (per-window burst
-    count inverse CDF); ``inv_burst``: scalar geometric parameter
-    (1/mean burst packets).
+    count inverse CDF); ``burst_thr``: int32 burst-length thresholds
+    from :func:`geometric_tables`.
     """
     keys = jnp.asarray(keys, jnp.uint32)
     thresholds = jnp.asarray(thresholds, jnp.int32)
-    inv_burst = jnp.asarray(inv_burst, jnp.float32)
     cycle0 = int(cycle0)
     win0 = cycle0 >> _WIN_SHIFT
     n_win = ((cycle0 + n_cycles - 1) >> _WIN_SHIFT) - win0 + 1
@@ -159,17 +194,13 @@ def sample_arrival_bits_ref(keys, cycle0, thresholds, inv_burst,
 
     # bursts: word 0 places (top 6 bits — exact uniform over the
     # window), word 1 draws the geometric length; accumulate densely
-    inv_log_q = jnp.float32(1.0) / jnp.log1p(-inv_burst)
     slot = jnp.arange(WINDOW, dtype=jnp.int32)[None, None, :, None]
 
     def burst_body(j, packets):
         bd0, bd1 = draw_key(k0, k1, j)
         x0, x1 = threefry2x32_ref(bd0, bd1, c0, c1)
         place = (x0 >> jnp.uint32(32 - _WIN_SHIFT)).astype(jnp.int32)
-        u = (x1 >> jnp.uint32(8)).astype(jnp.float32) * jnp.float32(
-            UNIT_SCALE
-        )
-        glen = jnp.float32(1.0) + jnp.floor(jnp.log1p(-u) * inv_log_q)
+        glen = burst_length(x1, burst_thr)
         live = (j <= count)
         hit = (place[:, :, None, :] == slot) & live[:, :, None, :]
         return packets + jnp.where(hit, glen[:, :, None, :],

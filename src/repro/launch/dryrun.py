@@ -34,6 +34,7 @@ from repro.dist import stepfns
 from repro.launch import specs as specs_mod
 from repro.launch.hlo_analysis import analyze as analyze_hlo
 from repro.launch.mesh import make_production_mesh
+from repro.launch.roofline import V5E
 from repro.optim.optimizers import OptimizerConfig
 
 # ---------------------------------------------------------------------------
@@ -83,6 +84,7 @@ def run_cell(
         "shape": shape.name,
         "mesh": "2x16x16" if multi_pod else "16x16",
         "chips": mesh.size,
+        "device_kind": V5E,          # the production mesh is v5e chips
         "kind": shape.kind,
         "fed": fed,
         "fed_round": fed_round,
@@ -176,8 +178,6 @@ def run_cell(
         rec["compile_s"] = round(time.time() - t1, 2)
 
         cost = compiled.cost_analysis() or {}
-        if isinstance(cost, (list, tuple)):  # older jaxlib: one dict per device
-            cost = cost[0] if cost else {}
         rec["cost_analysis"] = {
             k: float(v)
             for k, v in cost.items()

@@ -8,12 +8,20 @@ pod per EC-node site in the paper's mapping (DESIGN.md §3).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _mesh(shape, axes):
+    # Auto axes: the step functions place arrays through jit in/out
+    # shardings and GSPMD propagation, not sharding-in-types
+    # (``jax.make_mesh`` defaults to Explicit axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def make_host_mesh(model_parallel: int = 1, pods: int = 1):
@@ -21,10 +29,8 @@ def make_host_mesh(model_parallel: int = 1, pods: int = 1):
     n = jax.device_count()
     data = n // (model_parallel * pods)
     if pods > 1:
-        return jax.make_mesh(
-            (pods, data, model_parallel), ("pod", "data", "model")
-        )
-    return jax.make_mesh((data, model_parallel), ("data", "model"))
+        return _mesh((pods, data, model_parallel), ("pod", "data", "model"))
+    return _mesh((data, model_parallel), ("data", "model"))
 
 
 def batch_axes(mesh) -> tuple:

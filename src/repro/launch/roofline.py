@@ -1,10 +1,12 @@
-"""Roofline analysis from dry-run records (TPU v5e targets).
+"""Roofline analysis from dry-run records.
 
-Terms per (arch x shape x mesh) cell, all in seconds:
+Terms per (arch x shape x mesh) cell, all in seconds, with the peaks of
+the record's ``device_kind`` (:data:`PEAKS`; an unknown kind is an
+error, not a default):
 
-  compute    = HLO_dot_FLOPs_per_device / peak_FLOPs        (197 TFLOP/s bf16)
-  memory     = HLO_HBM_bytes_per_device / HBM_bw            (819 GB/s)
-  collective = collective_bytes_per_device / link_bw        (~50 GB/s/link)
+  compute    = HLO_dot_FLOPs_per_device / peak_FLOPs
+  memory     = HLO_HBM_bytes_per_device / HBM_bw
+  collective = collective_bytes_per_device / link_bw
 
 plus MODEL_FLOPS = 6·N·D (active-N for MoE) and the usefulness ratio
 MODEL_FLOPS / (HLO_FLOPs x chips). The dominant term is the bottleneck the
@@ -17,9 +19,33 @@ import json
 from dataclasses import dataclass
 from typing import Dict, List, Optional
 
-PEAK_FLOPS = 197e12          # bf16 per chip
-HBM_BW = 819e9               # bytes/s per chip
-ICI_BW = 50e9                # bytes/s per link
+
+@dataclass(frozen=True)
+class ChipPeaks:
+    flops: float             # bf16 FLOP/s per chip
+    hbm_bw: float            # HBM bytes/s per chip
+    ici_bw: float            # interconnect bytes/s per link
+
+
+V5E = "TPU v5 lite"          # jax's device_kind for a TPU v5e chip
+
+# Published per-chip peaks keyed by ``jax.devices()[i].device_kind``.
+# TPU v5e: Google Cloud documentation, "TPU v5e" — 197 TFLOP/s bf16,
+# 819 GB/s HBM, 1,600 Gbit/s chip-to-chip interconnect over 4 links.
+PEAKS: Dict[str, ChipPeaks] = {
+    V5E: ChipPeaks(flops=197e12, hbm_bw=819e9, ici_bw=1600e9 / 8 / 4),
+}
+
+
+def peaks(device_kind: Optional[str]) -> ChipPeaks:
+    """Peaks of ``device_kind``; raises on a kind without published
+    peaks rather than assuming another chip's."""
+    if device_kind not in PEAKS:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; "
+            f"have {sorted(PEAKS)}"
+        )
+    return PEAKS[device_kind]
 
 TOKENS = {
     "train_4k": 4096 * 256,
@@ -58,9 +84,10 @@ def analyze_record(rec: Dict) -> Optional[RooflineRow]:
     hbm_dev = rec.get("hlo_hbm_bytes", 0.0)
     coll_dev = rec.get("collectives", {}).get("total_bytes", 0.0)
 
-    compute_s = flops_dev / PEAK_FLOPS
-    memory_s = hbm_dev / HBM_BW
-    collective_s = coll_dev / ICI_BW
+    chip = peaks(rec.get("device_kind"))
+    compute_s = flops_dev / chip.flops
+    memory_s = hbm_dev / chip.hbm_bw
+    collective_s = coll_dev / chip.ici_bw
 
     terms = {
         "compute": compute_s, "memory": memory_s, "collective": collective_s

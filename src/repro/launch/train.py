@@ -20,11 +20,14 @@ from __future__ import annotations
 
 import argparse
 import time
+from functools import partial
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
+from jax.sharding import PartitionSpec as P
 
 from repro.checkpoint import CheckpointManager
 from repro.configs import get_config
@@ -33,6 +36,7 @@ from repro.data import TokenBatcher, lm_tokens
 from repro.dist import stepfns
 from repro.faults import FaultSchedule
 from repro.launch.mesh import make_host_mesh
+from repro.launch.specs import state_shapes, state_spec_tree
 from repro.net.api import SweepSpec, simulate
 from repro.net.engine import SweepCase
 from repro.net.jobs import JobSpec, make_competing_jobs
@@ -41,6 +45,60 @@ from repro.net.sim import FLRoundWorkload, PONConfig
 from repro.net.timeline import TimelineSchedule
 from repro.optim.optimizers import OptimizerConfig
 from repro.optim.schedules import warmup_cosine
+
+
+class FedPods:
+    """The federated programs on a ``("pod", "data", "model")`` mesh.
+
+    Every leaf of the pod-stacked state, and every batch, is split over
+    the ``pod`` mesh axis, so each pod's replica and optimizer state
+    live on its own devices; the jitted programs keep that layout
+    (``spmd_axis_name="pod"`` inside the vmapped local step, pod-sharded
+    outputs from the round steps).
+    """
+
+    def __init__(self, cfg, opt_cfg, mesh, n_pods: int, schedule=None):
+        self.cfg, self.opt_cfg, self.n_pods = cfg, opt_cfg, n_pods
+        specs = state_spec_tree(state_shapes(cfg, opt_cfg, n_pods), cfg,
+                                mesh, fed=True)
+        self.state_sharding = jax.tree.map(
+            lambda s: NamedSharding(mesh, s), specs,
+            is_leaf=lambda x: isinstance(x, P))
+        self.batch_sharding = NamedSharding(mesh, P("pod"))
+        self.step = jax.jit(
+            stepfns.make_fed_train_step(cfg, opt_cfg, schedule,
+                                        spmd_axis_name="pod"),
+            in_shardings=(self.state_sharding, self.batch_sharding),
+            out_shardings=(self.state_sharding, None))
+
+    def init_state(self, key) -> stepfns.TrainState:
+        return jax.jit(partial(stepfns.init_fed_state, cfg=self.cfg,
+                               opt_cfg=self.opt_cfg, n_pods=self.n_pods),
+                       out_shardings=self.state_sharding)(key)
+
+    def batch(self, parts) -> dict:
+        """Stack one host batch per pod onto the pod axis."""
+        return {k: jax.device_put(np.stack([np.asarray(p[k]) for p in parts]),
+                                  self.batch_sharding)
+                for k in parts[0]}
+
+    def round_step(self, compress: Optional[str] = None):
+        return jax.jit(stepfns.make_fed_round_step(self.cfg,
+                                                   compress=compress),
+                       out_shardings=self.state_sharding)
+
+    def _async_sharding(self):
+        p = self.state_sharding.params
+        return stepfns.AsyncRoundState(global_params=p, refs=p, pending=p)
+
+    def init_async_state(self, state) -> stepfns.AsyncRoundState:
+        return jax.jit(stepfns.init_async_state,
+                       out_shardings=self._async_sharding())(state)
+
+    def async_round_step(self, **kw):
+        return jax.jit(stepfns.make_async_round_step(self.cfg, **kw),
+                       out_shardings=(self.state_sharding,
+                                      self._async_sharding()))
 
 
 def train(
@@ -120,13 +178,10 @@ def train(
     with mesh:
         fed = pods > 1
         if fed:
-            state = stepfns.init_fed_state(
-                jax.random.PRNGKey(0), cfg, opt_cfg, pods
-            )
-            step = jax.jit(stepfns.make_fed_train_step(cfg, opt_cfg, schedule))
-            round_step = jax.jit(
-                stepfns.make_fed_round_step(cfg, compress=compress)
-            )
+            fed_pods = FedPods(cfg, opt_cfg, mesh, pods, schedule)
+            state = fed_pods.init_state(jax.random.PRNGKey(0))
+            step = fed_pods.step
+            round_step = fed_pods.round_step(compress)
         else:
             state = stepfns.init_train_state(
                 jax.random.PRNGKey(0), cfg, opt_cfg
@@ -141,19 +196,20 @@ def train(
         # matches what gets saved (train + async state as one tree).
         coupled = fed and (deadline_s is not None or async_buffer is not None)
         if coupled:
-            astate = stepfns.init_async_state(state)
-            around = jax.jit(
-                stepfns.make_async_round_step(
-                    cfg, compress=compress, quorum_frac=quorum,
-                    quorum_expected=pods if quorum is not None else None,
-                )
+            astate = fed_pods.init_async_state(state)
+            around = fed_pods.async_round_step(
+                compress=compress, quorum_frac=quorum,
+                quorum_expected=pods if quorum is not None else None,
             )
 
         mgr = CheckpointManager(ckpt_dir, keep=2) if ckpt_dir else None
         start_round = 0
         if mgr is not None and resume:
             template = {"train": state, "async": astate} if coupled else state
-            restored = mgr.restore_latest(like=template)
+            # restore onto the fresh state's placement (pod-sharded)
+            restored = mgr.restore_latest(
+                like=template,
+                shardings=jax.tree.map(lambda x: x.sharding, template))
             if restored is not None:
                 tree, meta = restored
                 if coupled:
@@ -272,11 +328,7 @@ def train(
             losses = []
             for it in range(steps_per_round):
                 if fed:
-                    parts = [next(g) for g in iters]
-                    batch = {
-                        k: jnp.stack([jnp.asarray(p[k]) for p in parts])
-                        for k in parts[0]
-                    }
+                    batch = fed_pods.batch([next(g) for g in iters])
                 else:
                     batch = {
                         k: jnp.asarray(v) for k, v in next(iters[0]).items()

@@ -107,8 +107,10 @@ def ssd_chunked(xh, B_mat, C_mat, dt, a, chunk, h0=None):
     # --- intra-chunk (quadratic in Q, matmul-dominated) ---
     # L[t, s] = exp(cum_t - cum_s) for s <= t else 0
     rel = cum[:, :, :, None, :] - cum[:, :, None, :, :]  # (B,nc,Q,Q,H)
-    tri = jnp.tril(jnp.ones((Q, Q), jnp.float32))
-    L = jnp.exp(rel) * tri[None, None, :, :, None]
+    tri = jnp.tril(jnp.ones((Q, Q), bool))[None, None, :, :, None]
+    # mask before the exp: above the diagonal rel > 0, and at Q=128 with
+    # dt ~ softplus(0) it exceeds float32's exp range (inf * 0 = NaN)
+    L = jnp.exp(jnp.where(tri, rel, -jnp.inf))
     cb = jnp.einsum("bcqn,bcsn->bcqs", Cm, Bm)           # (B,nc,Q,Q)
     scores = cb[..., None] * L                           # (B,nc,Q,Q,H)
     xdt = xh * dt[..., None]                             # (B,nc,Q,H,P)

@@ -1256,8 +1256,9 @@ def _round_sweep(cfg, cases: Sequence[SweepCase],
     host cycle loop (the default, bitwise-pinned); ``"jit"`` compiles
     each phase to one jax device program with the traffic sampler fused
     in (``repro.kernels.ponsim``) — parity with numpy at rtol 1e-6,
-    with a transparent numpy re-run for phases whose background state
-    outgrows the device ring (see ``ops.run_phase_device``).  The jit
+    with a numpy re-run, counted in ``ops.host_fallbacks()``, for phases
+    whose background state outgrows the device ring (see
+    ``ops.run_phase_device``).  The jit
     backend rejects injected arrival matrices and ``collector``
     instrumentation.
     """
@@ -1292,8 +1293,11 @@ def _round_sweep(cfg, cases: Sequence[SweepCase],
             )
         fairness = _multi_job_fairness(cases, ul_deadline_s, ul_outage_s)
         if use_jit:
-            # kernels/ponsim carries no job axis: multi-job sweeps fall
-            # back to the numpy engine transparently (DESIGN §12)
+            # kernels/ponsim carries no job axis: multi-job sweeps run
+            # on the numpy engine, counted as a host fallback (DESIGN §12)
+            from repro.kernels.ponsim.ops import note_host_fallback
+
+            note_host_fallback("multi_job")
             use_jit = False
     topo = _sweep_topology(cases)
     P = topo.n_pons
@@ -1476,9 +1480,11 @@ def _round_sweep(cfg, cases: Sequence[SweepCase],
 
     def run_phase(sub, rem0, ready, sel, phase, mode, **kw):
         """One phase on the selected backend; the jit path falls back
-        to numpy when the device program reports an inexact bg walk."""
+        to numpy, counted, when the device program reports an inexact
+        bg walk."""
         if use_jit:
-            from repro.kernels.ponsim.ops import run_phase_device
+            from repro.kernels.ponsim.ops import (note_host_fallback,
+                                                  run_phase_device)
 
             keys_ = lams_ = None
             if mode == "fcfs":
@@ -1494,6 +1500,7 @@ def _round_sweep(cfg, cases: Sequence[SweepCase],
             )
             if out is not None:
                 return out
+            note_host_fallback("ring_overflow")
         stream = providers(sel, phase) if mode == "fcfs" else None
         return _run_phase(cfg, sub, rem0, ready, stream, mode, **kw)
 

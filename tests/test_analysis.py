@@ -191,9 +191,9 @@ def test_rpa004_silent_on_scoped_context():
     _assert_silent(
         "RPA004",
         "repro/util.py",
-        "from jax.experimental import enable_x64\n"
+        "import jax\n"
         "def run(fn):\n"
-        "    with enable_x64():\n"
+        "    with jax.enable_x64(True):\n"
         "        return fn()\n",
     )
 

@@ -22,10 +22,7 @@ class TestShardingRules:
         # use jax's AbstractMesh for pure spec logic
         from jax.sharding import AbstractMesh
 
-        try:
-            cls.MESH = AbstractMesh((16, 16), ("data", "model"))
-        except TypeError:  # jax<=0.4.x: (name, size) pair signature
-            cls.MESH = AbstractMesh((("data", 16), ("model", 16)))
+        cls.MESH = AbstractMesh((16, 16), ("data", "model"))
 
     def spec(self, names, shape, cfg, **kw):
         return param_spec(names, shape, cfg, self.MESH, **kw)
@@ -125,6 +122,38 @@ class TestSmallMeshEndToEnd:
             capture_output=True, text=True, timeout=600,
         )
         assert "FED_OK" in out.stdout, out.stdout + out.stderr
+
+
+def test_train_splits_pods_over_devices():
+    """``launch.train`` with 2 pods on 2 devices keeps every leaf of the
+    pod-stacked state split over the pod axis, one pod per device,
+    through its local steps and the round."""
+    script = textwrap.dedent("""
+        import os
+        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
+        import jax
+        from repro.launch.train import train
+
+        state, history = train(arch="olmo-1b", smoke=True,
+                               steps_per_round=2, rounds=1, n_pods=2,
+                               global_batch=4, seq_len=16)
+        for leaf in jax.tree.leaves(state):
+            devs = {s.device for s in leaf.addressable_shards}
+            assert len(devs) == 2, (leaf.shape, leaf.sharding)
+            assert leaf.sharding.shard_shape(leaf.shape)[0] == 1, (
+                leaf.shape, leaf.sharding)
+        print("PODS_SPLIT_OK")
+    """)
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.abspath(
+        os.path.join(os.path.dirname(__file__), "..", "src")
+    )
+    env.pop("XLA_FLAGS", None)
+    out = subprocess.run(
+        [sys.executable, "-c", script], env=env,
+        capture_output=True, text=True, timeout=600,
+    )
+    assert "PODS_SPLIT_OK" in out.stdout, out.stdout + out.stderr[-4000:]
 
 
 @pytest.mark.slow

@@ -1,6 +1,8 @@
 """Unit tests for the HLO static analyser (roofline inputs)."""
 import textwrap
 
+import pytest
+
 from repro.launch.hlo_analysis import HloModule, _type_bytes, analyze
 
 
@@ -69,3 +71,28 @@ def test_collective_bytes_per_kind():
 def test_hbm_bytes_counts_fusion_boundaries():
     a = analyze(SAMPLE)
     assert a["hbm_bytes"] > 0
+
+
+def _roofline_record(**kw):
+    rec = {"ok": True, "arch": "a", "shape": "train_4k", "mesh": "16x16",
+           "chips": 256, "kind": "train", "hlo_flops": 197e12,
+           "hlo_hbm_bytes": 819e9 / 2, "collectives": {"total_bytes": 0.0},
+           "device_kind": "TPU v5 lite"}
+    rec.update(kw)
+    return rec
+
+
+def test_roofline_uses_the_record_device_peaks():
+    from repro.launch.roofline import analyze_record
+
+    row = analyze_record(_roofline_record())
+    assert row.compute_s == 1.0 and row.memory_s == 0.5
+    assert row.dominant == "compute"
+
+
+@pytest.mark.parametrize("kind", ["TPU v4", "cpu", None])
+def test_roofline_rejects_unknown_device_kind(kind):
+    from repro.launch.roofline import analyze_record
+
+    with pytest.raises(ValueError, match="no published peaks"):
+        analyze_record(_roofline_record(device_kind=kind))

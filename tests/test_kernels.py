@@ -118,6 +118,27 @@ class TestSSD:
         np.testing.assert_allclose(np.asarray(out), np.asarray(y_model),
                                    atol=1e-4, rtol=1e-4)
 
+    def test_model_chunk_128_finite_at_init(self):
+        """At the configs' chunk of 128 with dt ~ softplus(0) (the
+        init), the decay above the diagonal overflows float32's exp;
+        the model path must mask it, forward and backward."""
+        from repro.models.ssd import ssd_chunked
+
+        ks = jax.random.split(KEY, 4)
+        B, S, H, P, N = 1, 128, 2, 8, 16
+        xh = jax.random.normal(ks[0], (B, S, H, P))
+        bm = jax.random.normal(ks[1], (B, S, N)) * 0.3
+        cm = jax.random.normal(ks[2], (B, S, N)) * 0.3
+        dt = jax.nn.softplus(0.3 * jax.random.normal(ks[3], (B, S, H)))
+        a = -jnp.ones((H,))
+        assert float(jnp.sum(dt[0, 1:, 0])) > 88.8   # exp overflows here
+        y, _ = ssd_chunked(xh, bm, cm, dt, a, chunk=128)
+        np.testing.assert_allclose(np.asarray(y),
+                                   np.asarray(ssd_scan_ref(xh, bm, cm, dt, a)),
+                                   atol=1e-4, rtol=1e-4)
+        g = jax.grad(lambda x: jnp.sum(ssd_chunked(x, bm, cm, dt, a, 128)[0]))
+        assert bool(jnp.isfinite(g(xh)).all())
+
 
 class TestQuant:
     @pytest.mark.parametrize("shape", [(100,), (1000, 37), (5, 5, 5)])

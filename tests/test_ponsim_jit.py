@@ -32,7 +32,7 @@ from repro.kernels.traffic.ops import (
     make_stream_key,
     sample_arrival_bits,
 )
-from repro.kernels.traffic.ref import WINDOW
+from repro.kernels.traffic.ref import WINDOW, geometric_tables
 from repro.net import (
     FLRoundWorkload,
     PONConfig,
@@ -217,7 +217,7 @@ class TestFusedSampler:
             np.asarray(
                 ponsim.sample_window_ref(
                     keys, thr, w, n_onus=n_onus, n_draws=n_draws,
-                    inv_burst=np.float32(1.0 / 16.0),
+                    burst_thr=geometric_tables(1.0 / 16.0)[1],
                     packet_bits=np.float32(PACKET_BITS)),
                 np.float64)
             for w in range(4)
@@ -271,8 +271,59 @@ class TestPrecisionPolicy:
         assert jax.config.jax_enable_x64 is False
 
 
+class TestHostFallbacks:
+    """Every device request the host engine serves is counted."""
+
+    def test_ring_overflow_counted_and_exact(self, monkeypatch):
+        # a 2-cycle prefix ring ages marginal bg heads out at load 0.9
+        monkeypatch.setattr(ponsim_ops, "HISTORY_CYCLES", 2)
+        ponsim_ops.clear_cache()
+        try:
+            before = ponsim_ops.host_fallbacks()["ring_overflow"]
+            cases = [SweepCase(workload=WL, load=0.9, policy="fcfs",
+                               seed=7)]
+            a = simulate_round_sweep(CFG, cases)
+            b = simulate_round_sweep(CFG, cases, backend="jit")
+            assert ponsim_ops.host_fallbacks()["ring_overflow"] > before
+            _assert_round_parity(a[0], b[0])
+        finally:
+            ponsim_ops.clear_cache()
+
+    def test_multi_job_sweep_counted(self):
+        from repro.net import JobSpec
+
+        jobs = (JobSpec(job_id=0, clients=(0, 1), model_bits=1.5e6),
+                JobSpec(job_id=1, clients=(2, 3), model_bits=7.5e5))
+        cases = [SweepCase(workload=WL, load=0.3, policy="fcfs", seed=2,
+                           jobs=jobs)]
+        before = ponsim_ops.host_fallbacks()["multi_job"]
+        a = simulate_round_sweep(CFG, cases)
+        b = simulate_round_sweep(CFG, cases, backend="jit")
+        assert ponsim_ops.host_fallbacks()["multi_job"] == before + 1
+        assert a[0].sync_time == b[0].sync_time
+
+
 class TestPallasWaterfillKernel:
     """Interpret-mode Pallas grant kernel vs the engine's numpy grants."""
+
+    @pytest.mark.parametrize("policy,wl,load", [
+        ("fcfs", WL_MULTI, 0.9), ("fcfs", WL, 0.9), ("bs", WL, 0.9),
+    ], ids=["fcfs_many_per_onu", "fcfs_fast", "bs"])
+    def test_engine_parity_with_kernel(self, monkeypatch, policy, wl, load):
+        # the TPU path: phase programs built with the Pallas waterfill
+        # (interpret mode here) still match the numpy engine
+        import functools
+
+        monkeypatch.setattr(ponsim_ops, "_PALLAS_INTERPRET", True)
+        monkeypatch.setattr(
+            ponsim_ops, "run_phase_device",
+            functools.partial(ponsim_ops.run_phase_device, use_pallas=True))
+        for seed in (0, 3):
+            cases = [SweepCase(workload=wl, load=load, policy=policy,
+                               seed=seed)]
+            a = simulate_round_sweep(CFG, cases)
+            b = simulate_round_sweep(CFG, cases, backend="jit")
+            _assert_round_parity(a[0], b[0])
 
     def test_matches_engine_waterfill(self):
         rng = np.random.default_rng(5)

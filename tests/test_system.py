@@ -102,3 +102,29 @@ class TestEndToEnd:
         d_full = compute_slice(full, 0.0, 10.0, 10e9)
         d_comp = compute_slice(comp, 0.0, 10.0, 10e9)
         assert d_comp.bandwidth_bps <= d_full.bandwidth_bps + 1e-6
+
+
+class TestCompileCacheDir:
+    """Entry points keep JAX's compile cache where the environment says,
+    else at a fixed path inside the checkout."""
+
+    def test_environment_dir_is_used_as_is(self, monkeypatch, tmp_path):
+        from benchmarks._env import use_compile_cache
+
+        env_dir = str(tmp_path / "from_env")
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+        before = jax.config.jax_compilation_cache_dir
+        assert use_compile_cache(str(tmp_path)) == env_dir
+        assert jax.config.jax_compilation_cache_dir == before
+
+    def test_fixed_dir_inside_the_checkout(self, monkeypatch, tmp_path):
+        from benchmarks._env import use_compile_cache
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        before = jax.config.jax_compilation_cache_dir
+        try:
+            path = use_compile_cache(str(tmp_path))
+            assert path == str(tmp_path / ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == path
+        finally:
+            jax.config.update("jax_compilation_cache_dir", before)
