@@ -529,7 +529,7 @@ class FLNetworkCoSim:
         sync = 0.0
         total_time = 0.0
         for _ in range(n_rounds):
-            with maybe_span(self._collector, "fl:train_round"):
+            with maybe_span(self._collector, "fl.train_round"):
                 log = self.server.run_round(eval_fn=eval_fn)
             profiles, m_bits = self._round_profiles(log)
             per_round_profiles.append(profiles)

@@ -115,5 +115,6 @@ def waterfill_grants_pallas(backlog, key, cap, *, interpret: bool = False):
         out_specs=pl.BlockSpec((ROWS, BLOCK_I), lambda a, b: (a, b)),
         out_shape=jax.ShapeDtypeStruct((rp, n), jnp.float32),
         interpret=interpret,
+        name="ponsim_waterfill",
     )(backlog, key, cap.reshape(rp, 1), _cols(backlog), _cols(key))
     return g[:r]

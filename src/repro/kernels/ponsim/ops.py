@@ -61,6 +61,7 @@ backends.
 """
 from __future__ import annotations
 
+import re
 from typing import Optional, Tuple
 
 import jax
@@ -71,6 +72,7 @@ from jax import lax
 from repro.kernels.ponsim import ref as _ref
 from repro.kernels.ponsim.kernel import waterfill_grants_pallas
 from repro.kernels.traffic.ref import _WIN_SHIFT, WINDOW
+from repro.obs.trace import maybe_span
 
 CAP_EPS = 1e-9                       # repro.net.engine constants
 SEG_EPS = 1.0
@@ -79,14 +81,19 @@ _IKEY_INF = np.int64(np.iinfo(np.int64).max // 4)
 
 HISTORY_CYCLES = 128                 # bg prefix ring length (pow2)
 
-# program cache: one compilation per (mode, shapes, flags, layout)
+# program cache: one compilation per (mode, shapes, flags, layout), with
+# the argument shapes each was called with (for :func:`scope_map`)
 _programs: dict = {}
+_arg_shapes: dict = {}
+SCOPES = ("sampler", "push", "grants", "serve")   # loop-body named scopes
 _COMPILE_COUNT = 0                   # bumped at trace time (tested)
 _PALLAS_INTERPRET = False            # tests flip to run the kernel on CPU
 # phases (or whole sweeps) that asked for the device and ran on the host
 # numpy engine instead: "ring_overflow" (the bg ring walk lost
 # exactness) and "multi_job" (the carry has no job axis)
 _HOST_FALLBACKS = {"ring_overflow": 0, "multi_job": 0}
+# cycles the phase programs' loops ran, by program name
+_LOOP_CYCLES: dict = {}
 
 
 def compile_count() -> int:
@@ -102,8 +109,120 @@ def note_host_fallback(reason: str) -> None:
     _HOST_FALLBACKS[reason] += 1
 
 
+def loop_cycles() -> dict:
+    """Cycles the device loop ran, summed per program name
+    (:func:`program_name`) over every phase this process ran."""
+    return dict(_LOOP_CYCLES)
+
+
 def clear_cache() -> None:
     _programs.clear()
+    _arg_shapes.clear()
+
+
+_HLO_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.-]+) \(")
+_HLO_INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%?([\w.-]+) = ")
+_HLO_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_HLO_CALLEE = re.compile(
+    r"(?:calls|body|condition|to_apply)=%?([\w.-]+)"
+    r"|(?:branch|called)_computations=\{([^}]*)\}")
+
+
+def hlo_scopes(text: str) -> dict:
+    """``{instruction: scope}`` of a compiled HLO module's text: the
+    first :data:`SCOPES` component of each instruction's ``op_name``.
+
+    XLA leaves some instructions it builds without metadata (a fusion
+    holding the f64 halves of a scatter, the ops of a conditional's
+    branch).  Such an instruction takes the one scope named inside the
+    computations it calls, else the scope of the instruction that calls
+    its own computation."""
+    own, comp_of, callees, members = {}, {}, {}, {}
+    comp = None
+    for line in text.splitlines():
+        head = _HLO_COMPUTATION.match(line)
+        if head:
+            comp = head.group(1)
+            continue
+        inst = _HLO_INSTRUCTION.match(line)
+        if inst is None:
+            continue
+        name = inst.group(1)
+        comp_of[name] = comp
+        members.setdefault(comp, []).append(name)
+        path = _HLO_OP_NAME.search(line)
+        scope = next((p for p in path.group(1).split("/") if p in SCOPES),
+                     None) if path else None
+        if scope is not None:
+            own[name] = scope
+        called = []
+        for single, many in _HLO_CALLEE.findall(line):
+            called += [single] if single else [
+                c.strip().lstrip("%") for c in many.split(",")]
+        if called:
+            callees[name] = called
+
+    inner: dict = {}
+
+    def named_inside(c):
+        """Scopes named in computation ``c`` and everything it calls."""
+        if c not in inner:
+            inner[c] = set()
+            found = set()
+            for n in members.get(c, ()):
+                if n in own:
+                    found.add(own[n])
+                for cc in callees.get(n, ()):
+                    found |= named_inside(cc)
+            inner[c] = found
+        return inner[c]
+
+    for name, called in callees.items():
+        if name not in own:
+            found = set().union(*(named_inside(c) for c in called))
+            if len(found) == 1:
+                own[name] = found.pop()
+    comp_scope: dict = {}
+    changed = True
+    while changed:
+        changed = False
+        for name, called in callees.items():
+            for c in called:
+                if name in own and c not in comp_scope:
+                    comp_scope[c] = own[name]
+                    changed = True
+        for name, c in comp_of.items():
+            if name not in own and c in comp_scope:
+                own[name] = comp_scope[c]
+                changed = True
+    return own
+
+
+def scope_map() -> dict:
+    """``{module name: {HLO instruction name: scope}}`` for the phase
+    programs in the cache (:func:`hlo_scopes` of each compiled program).
+
+    A profiler trace names device ops by their instruction and groups
+    them by module (``jit_<program_name>``), so this puts each op down
+    to its step of the loop.  It lowers and compiles every cached
+    program again from its argument shapes (served from the compile
+    cache where one is on): call it after the calls to be traced, never
+    on the call path.  Programs that share a name and disagree about an
+    instruction leave that instruction out."""
+    out: dict = {}
+    clash: dict = {}
+    for spec, prog in list(_programs.items()):
+        with jax.enable_x64(True):
+            text = prog.lower(_arg_shapes[spec]).compile().as_text()
+        module = "jit_" + prog.__name__
+        table = out.setdefault(module, {})
+        for op, scope in hlo_scopes(text).items():
+            if table.setdefault(op, scope) != scope:
+                clash.setdefault(module, set()).add(op)
+    for module, ops_ in clash.items():
+        for op in ops_:
+            out[module].pop(op, None)
+    return out
 
 
 def _waterfill_device(backlog, hol, cap, use_pallas: bool):
@@ -130,8 +249,27 @@ def _waterfill_device(backlog, hol, cap, use_pallas: bool):
     return jnp.where(fullm, backlog, g32.astype(backlog.dtype))
 
 
+def program_name(spec, single: bool) -> str:
+    """The phase program's name, ``ponsim_<mode>[_fast][_cps][_deadline]
+    [_outage]``: its module in a profiler trace is ``jit_<name>``.  The
+    mode is ``fcfs`` or ``bs``; ``fast`` marks the scalar-S fcfs carry
+    (one client per ONU)."""
+    mode, has_cps, has_deadline, has_outage = (spec[0], spec[7], spec[8],
+                                               spec[9])
+    flags = (("fast", mode == "fcfs" and single), ("cps", has_cps),
+             ("deadline", has_deadline), ("outage", has_outage))
+    return "_".join(["ponsim", mode] + [f for f, on in flags if on])
+
+
 def _build_program(spec, lay, n_draws: int):
-    """Close the static layout/config into one jitted phase program."""
+    """Close the static layout/config into one jitted phase program,
+    named by :func:`program_name`.  The loop body's steps run under the
+    named scopes ``sampler`` (window refill and bit lookup), ``push``
+    (bg FIFO and FL queue pushes), ``grants`` (capacity masks, bg pour,
+    CPS split, FL waterfill or slot grants) and ``serve`` (bg ring walk,
+    FL serve and completion credit), which label their ops in a
+    profiler trace.  Returns the program's outputs plus its loop's
+    cycle count."""
     global _COMPILE_COUNT
     (mode, R, U, N, S, P, has_bg, has_cps, has_deadline, has_outage,
      fill_unfinished, use_pallas, max_slots, _ndraws, _nlevels,
@@ -271,306 +409,313 @@ def _build_program(spec, lay, n_draws: int):
         def body(c):
             k, t = c["k"], c["t"]
             out = dict(c)
-            cap_cyc = dyn["cap_col"]
-            if has_deadline:
-                cap_cyc = jnp.where(dyn["cap_t"] > t, cap_cyc, 0.0)
-            if has_outage:
-                dark = (dyn["out0"] <= t) & (t < dyn["out1"])
-                cap_cyc = jnp.where(dark, 0.0, cap_cyc)
+            with jax.named_scope("grants"):
+                cap_cyc = dyn["cap_col"]
+                if has_deadline:
+                    cap_cyc = jnp.where(dyn["cap_t"] > t, cap_cyc, 0.0)
+                if has_outage:
+                    dark = (dyn["out0"] <= t) & (t < dyn["out1"])
+                    cap_cyc = jnp.where(dark, 0.0, cap_cyc)
 
             # ---- bg arrivals: fused threefry sampler + FIFO push
             if has_bg:
-                buf = lax.cond(
-                    (k & (WINDOW - 1)) == 0,
-                    lambda _: _ref.sample_window_ref(
-                        dyn["keys"], dyn["thr"], k >> _WIN_SHIFT,
-                        n_onus=N, n_draws=n_draws,
-                        burst_thr=dyn["burst_thr"],
-                        packet_bits=dyn["packet_bits"]),
-                    lambda _: c["buf"], None)
-                bits = lax.dynamic_index_in_dim(
-                    buf, k & (WINDOW - 1), axis=1,
-                    keepdims=False).astype(f64)
-                fresh = (c["backlog"] <= 0.0) & (bits > 0.0)
-                cum = c["cum"] + bits
-                bg_backlog = cum - c["drained"]
-                bg_ptr = jnp.where(fresh, k, c["ptr"])
-                ring = lax.dynamic_update_slice(
-                    c["ring"], cum[:, :, None],
-                    (jnp.int32(0), jnp.int32(0), k & (Wr - 1)))
-                out.update(buf=buf, cum=cum, backlog=bg_backlog,
-                           ptr=bg_ptr, ring=ring)
+                with jax.named_scope("sampler"):
+                    buf = lax.cond(
+                        (k & (WINDOW - 1)) == 0,
+                        lambda _: _ref.sample_window_ref(
+                            dyn["keys"], dyn["thr"], k >> _WIN_SHIFT,
+                            n_onus=N, n_draws=n_draws,
+                            burst_thr=dyn["burst_thr"],
+                            packet_bits=dyn["packet_bits"]),
+                        lambda _: c["buf"], None)
+                    bits = lax.dynamic_index_in_dim(
+                        buf, k & (WINDOW - 1), axis=1,
+                        keepdims=False).astype(f64)
+                with jax.named_scope("push"):
+                    fresh = (c["backlog"] <= 0.0) & (bits > 0.0)
+                    cum = c["cum"] + bits
+                    bg_backlog = cum - c["drained"]
+                    bg_ptr = jnp.where(fresh, k, c["ptr"])
+                    ring = lax.dynamic_update_slice(
+                        c["ring"], cum[:, :, None],
+                        (jnp.int32(0), jnp.int32(0), k & (Wr - 1)))
+                    out.update(buf=buf, cum=cum, backlog=bg_backlog,
+                               ptr=bg_ptr, ring=ring)
 
             # ---- FL push
-            if fast:
-                # pushes are a host-precomputed prefix of the rank
-                # order: the pushed-total boundary T_k replaces all
-                # per-client push bookkeeping
-                npk = _count_le(dyn["kp_rank"],
-                                jnp.broadcast_to(k, (R,)))
-                t_k = jnp.take_along_axis(
-                    dyn["p_incl"], npk.astype(jnp.int64)[:, None],
-                    axis=1)[:, 0]
-                fl_tot = t_k - c["fls"]
-            else:
-                newly = c["waiting"] & (dyn["ready"] <= t + cyc)
-                qb = jnp.where(newly, c["rem"], c["qb"])
-                push_key = jnp.where(
-                    newly,
-                    k.astype(jnp.int64) * np.int64(U + 1)
-                    + dyn["list_pos"],
-                    c["push_key"])
-                push_time = jnp.where(
-                    newly, jnp.maximum(dyn["ready"], t),
-                    c["push_time"])
-                out.update(waiting=c["waiting"] & ~newly,
-                           push_key=push_key, push_time=push_time)
+            with jax.named_scope("push"):
+                if fast:
+                    # pushes are a host-precomputed prefix of the rank
+                    # order: the pushed-total boundary T_k replaces all
+                    # per-client push bookkeeping
+                    npk = _count_le(dyn["kp_rank"],
+                                    jnp.broadcast_to(k, (R,)))
+                    t_k = jnp.take_along_axis(
+                        dyn["p_incl"], npk.astype(jnp.int64)[:, None],
+                        axis=1)[:, 0]
+                    fl_tot = t_k - c["fls"]
+                else:
+                    newly = c["waiting"] & (dyn["ready"] <= t + cyc)
+                    qb = jnp.where(newly, c["rem"], c["qb"])
+                    push_key = jnp.where(
+                        newly,
+                        k.astype(jnp.int64) * np.int64(U + 1)
+                        + dyn["list_pos"],
+                        c["push_key"])
+                    push_time = jnp.where(
+                        newly, jnp.maximum(dyn["ready"], t),
+                        c["push_time"])
+                    out.update(waiting=c["waiting"] & ~newly,
+                               push_key=push_key, push_time=push_time)
 
             # ---- grants
-            backlog_onu = None if fast else _backlog_per_onu(qb)
-            if mode == "fcfs":
-                bg_sum = (out["backlog"].sum(axis=1) if has_bg else 0.0)
-                if has_cps:
-                    fl_want = (fl_tot if fast
-                               else backlog_onu.sum(axis=1))
-                    want = jnp.minimum(bg_sum + fl_want, cap_cyc)
-                    eff = _ref.cps_waterfill_ref(
-                        want.reshape(-1, P), dyn["cps_cap"]).reshape(-1)
-                else:
-                    eff = cap_cyc
-                if has_bg:
-                    # the numpy `_waterfill` lazy hard-row check, hoisted
-                    # to a scalar cond: when every row's demand sits at
-                    # least one bit under capacity the pour grants full
-                    # backlogs regardless of age order, so ordering work
-                    # is skipped entirely.  Under sub-unit load that is
-                    # the common cycle; only bursts take the hard branch.
-                    easy = jnp.all(bg_sum <= eff - 1.0)
-
-                    def _bg_easy(b, ptr, e):
-                        return b, jnp.bool_(False)
-
-                    if use_pallas:
-                        def _bg_hard(b, ptr, e):
-                            hol = jnp.where(b > 0.0, ptr.astype(f64),
-                                            jnp.inf)
-                            return (_waterfill_device(b, hol, e, True),
-                                    jnp.bool_(False))
+            with jax.named_scope("grants"):
+                backlog_onu = None if fast else _backlog_per_onu(qb)
+                if mode == "fcfs":
+                    bg_sum = (out["backlog"].sum(axis=1) if has_bg else 0.0)
+                    if has_cps:
+                        fl_want = (fl_tot if fast
+                                   else backlog_onu.sum(axis=1))
+                        want = jnp.minimum(bg_sum + fl_want, cap_cyc)
+                        eff = _ref.cps_waterfill_ref(
+                            want.reshape(-1, P), dyn["cps_cap"]).reshape(-1)
                     else:
-                        def _bg_hard(b, ptr, e):
-                            # bg head-of-line keys are arrival *cycles*,
-                            # so the stable argsort collapses to a
-                            # counting pour over `Wr` age buckets:
-                            # bucket-sum scatter + tiny suffix sums +
-                            # one column prefix for the single marginal
-                            # bucket — O(N) instead of O(N log N), and
-                            # ~20x cheaper than XLA's sort here.  Ages
-                            # clip at Wr-1; if the margin lands in that
-                            # clipped bucket with 2+ queues their column
-                            # order may differ from true arrival order,
-                            # so that (sustained-overload) case clears
-                            # `exact` and the host re-runs on numpy.
-                            has = b > 0.0
-                            age = jnp.clip(k - ptr, 0, Wr - 1)
-                            aidx = jnp.where(has, age, 0)
-                            bval = jnp.where(has, b, 0.0)
-                            rws = jnp.arange(R)[:, None]
-                            bs = jnp.zeros((R, Wr), b.dtype).at[
-                                rws, aidx].add(bval)
-                            flip = _ref.prefix_sum(bs[:, ::-1])
-                            csame = flip[:, ::-1]          # Σ age ≥ a
-                            colder = csame - bs            # Σ age > a
-                            tq = jnp.take_along_axis(colder, aidx, 1)
-                            cq = jnp.take_along_axis(csame, aidx, 1)
-                            capq = e[:, None]
-                            fullq = has & (cq <= capq)
-                            marg = has & (tq < capq) & (cq > capq)
-                            bm = jnp.where(marg, bval, 0.0)
-                            wq = _ref.prefix_sum(bm) - bm
-                            room = capq - (tq + wq)
-                            pour = jnp.where(room > CAP_EPS,
-                                             jnp.minimum(b, room), 0.0)
-                            g = jnp.where(fullq, b,
-                                          jnp.where(marg, pour, 0.0))
-                            nclip = (has & (age == Wr - 1)).sum(axis=1)
-                            amb = ((marg & (aidx == Wr - 1)).any(axis=1)
-                                   & (nclip >= 2)).any()
-                            return g, amb
-                    bg_grants, bg_amb = lax.cond(
-                        easy, _bg_easy, _bg_hard,
-                        out["backlog"], out["ptr"], eff)
-                    out["exact"] = out["exact"] & ~bg_amb
-                    cap_fl = eff - bg_grants.sum(axis=1)
+                        eff = cap_cyc
+                    if has_bg:
+                        # the numpy `_waterfill` lazy hard-row check, hoisted
+                        # to a scalar cond: when every row's demand sits at
+                        # least one bit under capacity the pour grants full
+                        # backlogs regardless of age order, so ordering work
+                        # is skipped entirely.  Under sub-unit load that is
+                        # the common cycle; only bursts take the hard branch.
+                        easy = jnp.all(bg_sum <= eff - 1.0)
+
+                        def _bg_easy(b, ptr, e):
+                            return b, jnp.bool_(False)
+
+                        if use_pallas:
+                            def _bg_hard(b, ptr, e):
+                                hol = jnp.where(b > 0.0, ptr.astype(f64),
+                                                jnp.inf)
+                                return (_waterfill_device(b, hol, e, True),
+                                        jnp.bool_(False))
+                        else:
+                            def _bg_hard(b, ptr, e):
+                                # bg head-of-line keys are arrival *cycles*,
+                                # so the stable argsort collapses to a
+                                # counting pour over `Wr` age buckets:
+                                # bucket-sum scatter + tiny suffix sums +
+                                # one column prefix for the single marginal
+                                # bucket — O(N) instead of O(N log N), and
+                                # ~20x cheaper than XLA's sort here.  Ages
+                                # clip at Wr-1; if the margin lands in that
+                                # clipped bucket with 2+ queues their column
+                                # order may differ from true arrival order,
+                                # so that (sustained-overload) case clears
+                                # `exact` and the host re-runs on numpy.
+                                has = b > 0.0
+                                age = jnp.clip(k - ptr, 0, Wr - 1)
+                                aidx = jnp.where(has, age, 0)
+                                bval = jnp.where(has, b, 0.0)
+                                rws = jnp.arange(R)[:, None]
+                                bs = jnp.zeros((R, Wr), b.dtype).at[
+                                    rws, aidx].add(bval)
+                                flip = _ref.prefix_sum(bs[:, ::-1])
+                                csame = flip[:, ::-1]          # Σ age ≥ a
+                                colder = csame - bs            # Σ age > a
+                                tq = jnp.take_along_axis(colder, aidx, 1)
+                                cq = jnp.take_along_axis(csame, aidx, 1)
+                                capq = e[:, None]
+                                fullq = has & (cq <= capq)
+                                marg = has & (tq < capq) & (cq > capq)
+                                bm = jnp.where(marg, bval, 0.0)
+                                wq = _ref.prefix_sum(bm) - bm
+                                room = capq - (tq + wq)
+                                pour = jnp.where(room > CAP_EPS,
+                                                 jnp.minimum(b, room), 0.0)
+                                g = jnp.where(fullq, b,
+                                              jnp.where(marg, pour, 0.0))
+                                nclip = (has & (age == Wr - 1)).sum(axis=1)
+                                amb = ((marg & (aidx == Wr - 1)).any(axis=1)
+                                       & (nclip >= 2)).any()
+                                return g, amb
+                        bg_grants, bg_amb = lax.cond(
+                            easy, _bg_easy, _bg_hard,
+                            out["backlog"], out["ptr"], eff)
+                        out["exact"] = out["exact"] & ~bg_amb
+                        cap_fl = eff - bg_grants.sum(axis=1)
+                    else:
+                        cap_fl = eff
+                    if not fast:
+                        fl_grants = _waterfill_device(
+                            backlog_onu,
+                            _hol_per_onu(qb, push_key, push_time),
+                            cap_fl, use_pallas)
                 else:
-                    cap_fl = eff
-                if not fast:
-                    fl_grants = _waterfill_device(
-                        backlog_onu,
-                        _hol_per_onu(qb, push_key, push_time),
-                        cap_fl, use_pallas)
-            else:
-                fl_grants = _slot_grants(backlog_onu, t, cap_cyc)
-                if has_cps:
-                    # recompute with the waterfilled shares is a bitwise
-                    # no-op for rows the CPS does not cut (the numpy
-                    # path's conditional recompute, branch-free)
-                    want = fl_grants.sum(axis=1)
-                    eff = _ref.cps_waterfill_ref(
-                        want.reshape(-1, P), dyn["cps_cap"]).reshape(-1)
-                    fl_grants = _slot_grants(backlog_onu, t, eff)
+                    fl_grants = _slot_grants(backlog_onu, t, cap_cyc)
+                    if has_cps:
+                        # recompute with the waterfilled shares is a bitwise
+                        # no-op for rows the CPS does not cut (the numpy
+                        # path's conditional recompute, branch-free)
+                        want = fl_grants.sum(axis=1)
+                        eff = _ref.cps_waterfill_ref(
+                            want.reshape(-1, P), dyn["cps_cap"]).reshape(-1)
+                        fl_grants = _slot_grants(backlog_onu, t, eff)
 
-            # ---- bg serve: full drains + the one marginal queue/row
-            if has_bg:
-                cum, drained = out["cum"], out["drained"]
-                backlog, ptr = out["backlog"], out["ptr"]
-                full = (bg_grants > 0.0) & (bg_grants == backlog)
-                budget = jnp.where(full, 0.0, bg_grants)
-                drained = jnp.where(full, cum, drained)
-                backlog = jnp.where(full, 0.0, backlog)
-                ptr = jnp.where(full, k + 1, ptr)
-                part_q = budget > CAP_EPS
-                has_part = part_q.any(axis=1)
-                jm = jnp.argmax(part_q, axis=1)     # ≤1 partial per row
-                rows = jnp.arange(R)
-                tgt = drained[rows, jm] + budget[rows, jm]
-                cum_q = cum[rows, jm]
-                # prefix values of the marginal queue over the last Wr
-                # cycles, ascending (pre-history ring slots hold 0 and
-                # never exceed a positive target)
-                cyc_idx = jnp.arange(Wr, dtype=jnp.int32) - (Wr - 1) + k
-                pref = jnp.take(out["ring"][rows, jm],
-                                cyc_idx & (Wr - 1), axis=1)
-                ex1 = pref > tgt[:, None]
-                j1rel = jnp.argmax(ex1, axis=1).astype(jnp.int32)
-                jstar = k - (Wr - 1) + j1rel
-                seg_end = jnp.take_along_axis(
-                    pref, j1rel[:, None], 1)[:, 0]
-                snap = seg_end - tgt <= SEG_EPS
-                dr1 = jnp.where(snap, seg_end, tgt)
-                bklg = cum_q - dr1
-                low = bklg < 0.5
-                # snap consumed through jstar; next head = first later
-                # cycle whose prefix exceeds the snapped drain (always
-                # in-window: prefix(k) = cum > drained when not low)
-                ex2 = ((pref > dr1[:, None])
-                       & (jnp.arange(Wr)[None, :] > j1rel[:, None]))
-                j2 = k - (Wr - 1) + jnp.argmax(ex2, axis=1).astype(
-                    jnp.int32)
-                new_dr = jnp.where(low, cum_q, dr1)
-                new_bk = jnp.where(low, 0.0, bklg)
-                new_pt = jnp.where(low, k + 1,
-                                   jnp.where(snap, j2, jstar))
-                # the walk is exact unless the head had already aged out
-                # of the ring AND the window start exceeds the target
-                stale = has_part & ex1[:, 0] & (
-                    ptr[rows, jm] < k - (Wr - 1))
-                drained = drained.at[rows, jm].set(
-                    jnp.where(has_part, new_dr, drained[rows, jm]))
-                backlog = backlog.at[rows, jm].set(
-                    jnp.where(has_part, new_bk, backlog[rows, jm]))
-                ptr = ptr.at[rows, jm].set(
-                    jnp.where(has_part, new_pt, ptr[rows, jm]))
-                out.update(cum=cum, drained=drained, backlog=backlog,
-                           ptr=ptr,
-                           exact=out["exact"] & ~stale.any())
+            # ---- bg serve: full drains + the one marginal queue/row,
+            # then FL serve + completion credit
+            with jax.named_scope("serve"):
+                if has_bg:
+                    cum, drained = out["cum"], out["drained"]
+                    backlog, ptr = out["backlog"], out["ptr"]
+                    full = (bg_grants > 0.0) & (bg_grants == backlog)
+                    budget = jnp.where(full, 0.0, bg_grants)
+                    drained = jnp.where(full, cum, drained)
+                    backlog = jnp.where(full, 0.0, backlog)
+                    ptr = jnp.where(full, k + 1, ptr)
+                    part_q = budget > CAP_EPS
+                    has_part = part_q.any(axis=1)
+                    jm = jnp.argmax(part_q, axis=1)     # ≤1 partial per row
+                    rows = jnp.arange(R)
+                    tgt = drained[rows, jm] + budget[rows, jm]
+                    cum_q = cum[rows, jm]
+                    # prefix values of the marginal queue over the last Wr
+                    # cycles, ascending (pre-history ring slots hold 0 and
+                    # never exceed a positive target)
+                    cyc_idx = jnp.arange(Wr, dtype=jnp.int32) - (Wr - 1) + k
+                    pref = jnp.take(out["ring"][rows, jm],
+                                    cyc_idx & (Wr - 1), axis=1)
+                    ex1 = pref > tgt[:, None]
+                    j1rel = jnp.argmax(ex1, axis=1).astype(jnp.int32)
+                    jstar = k - (Wr - 1) + j1rel
+                    seg_end = jnp.take_along_axis(
+                        pref, j1rel[:, None], 1)[:, 0]
+                    snap = seg_end - tgt <= SEG_EPS
+                    dr1 = jnp.where(snap, seg_end, tgt)
+                    bklg = cum_q - dr1
+                    low = bklg < 0.5
+                    # snap consumed through jstar; next head = first later
+                    # cycle whose prefix exceeds the snapped drain (always
+                    # in-window: prefix(k) = cum > drained when not low)
+                    ex2 = ((pref > dr1[:, None])
+                           & (jnp.arange(Wr)[None, :] > j1rel[:, None]))
+                    j2 = k - (Wr - 1) + jnp.argmax(ex2, axis=1).astype(
+                        jnp.int32)
+                    new_dr = jnp.where(low, cum_q, dr1)
+                    new_bk = jnp.where(low, 0.0, bklg)
+                    new_pt = jnp.where(low, k + 1,
+                                       jnp.where(snap, j2, jstar))
+                    # the walk is exact unless the head had already aged out
+                    # of the ring AND the window start exceeds the target
+                    stale = has_part & ex1[:, 0] & (
+                        ptr[rows, jm] < k - (Wr - 1))
+                    drained = drained.at[rows, jm].set(
+                        jnp.where(has_part, new_dr, drained[rows, jm]))
+                    backlog = backlog.at[rows, jm].set(
+                        jnp.where(has_part, new_bk, backlog[rows, jm]))
+                    ptr = ptr.at[rows, jm].set(
+                        jnp.where(has_part, new_pt, ptr[rows, jm]))
+                    out.update(cum=cum, drained=drained, backlog=backlog,
+                               ptr=ptr,
+                               exact=out["exact"] & ~stale.any())
 
-            # ---- FL serve + completion credit
-            if fast:
-                # Single-client queues keep their (push_time, column)
-                # key for the whole phase and pushes are ready-driven,
-                # so service is strictly prefix-contiguous in a
-                # priority order known before the loop: the waterfill
-                # pour over all queues reduces to advancing one
-                # cumulative-service scalar S per row against the
-                # host-precomputed demand boundaries Q_r.  A client's
-                # sub-SEG_EPS residual is discarded on its last serve
-                # (the numpy drop), which is exactly "snap S to the
-                # next boundary when it lands within SEG_EPS below it"
-                # — the drop and the EPS_BITS credit share the same
-                # threshold, so rank r is complete iff Q_r <= S.
-                s_pre = c["fls"]
-                capx = jnp.maximum(cap_fl, 0.0)
-                s1 = jnp.where(
-                    cap_fl > CAP_EPS,
-                    jnp.where(fl_tot <= capx, t_k, s_pre + capx),
-                    s_pre)
-                qpad = jnp.concatenate(
-                    [dyn["q_bound"], jnp.full((R, 1), jnp.inf, f64)],
-                    axis=1)
-                rkx = _first_ge(dyn["q_bound"], s1)
-                qv = jnp.take_along_axis(
-                    qpad, rkx.astype(jnp.int64)[:, None], axis=1)[:, 0]
-                bump = (s1 > s_pre) & (qv - s1 <= SEG_EPS)
-                s2 = jnp.where(bump, qv, s1)
-                c_new = _count_le(dyn["q_bound"], s2)
-                c_old = c["cdone"]
+                # FL serve + completion credit
+                if fast:
+                    # Single-client queues keep their (push_time, column)
+                    # key for the whole phase and pushes are ready-driven,
+                    # so service is strictly prefix-contiguous in a
+                    # priority order known before the loop: the waterfill
+                    # pour over all queues reduces to advancing one
+                    # cumulative-service scalar S per row against the
+                    # host-precomputed demand boundaries Q_r.  A client's
+                    # sub-SEG_EPS residual is discarded on its last serve
+                    # (the numpy drop), which is exactly "snap S to the
+                    # next boundary when it lands within SEG_EPS below it"
+                    # — the drop and the EPS_BITS credit share the same
+                    # threshold, so rank r is complete iff Q_r <= S.
+                    s_pre = c["fls"]
+                    capx = jnp.maximum(cap_fl, 0.0)
+                    s1 = jnp.where(
+                        cap_fl > CAP_EPS,
+                        jnp.where(fl_tot <= capx, t_k, s_pre + capx),
+                        s_pre)
+                    qpad = jnp.concatenate(
+                        [dyn["q_bound"], jnp.full((R, 1), jnp.inf, f64)],
+                        axis=1)
+                    rkx = _first_ge(dyn["q_bound"], s1)
+                    qv = jnp.take_along_axis(
+                        qpad, rkx.astype(jnp.int64)[:, None], axis=1)[:, 0]
+                    bump = (s1 > s_pre) & (qv - s1 <= SEG_EPS)
+                    s2 = jnp.where(bump, qv, s1)
+                    c_new = _count_le(dyn["q_bound"], s2)
+                    c_old = c["cdone"]
 
-                def _credit(dt):
-                    hit = ((dyn["rank_u"] >= c_old[:, None])
-                           & (dyn["rank_u"] < c_new[:, None]))
-                    return jnp.where(hit, t + cyc + prop, dt)
+                    def _credit(dt):
+                        hit = ((dyn["rank_u"] >= c_old[:, None])
+                               & (dyn["rank_u"] < c_new[:, None]))
+                        return jnp.where(hit, t + cyc + prop, dt)
 
+                    out.update(
+                        fls=s2,
+                        cdone=c_new,
+                        done_t=lax.cond((c_new > c_old).any(), _credit,
+                                        lambda dt: dt, c["done_t"]),
+                        k=k + 1,
+                        t=t + cyc,
+                    )
+                    return out
+                if single:
+                    fl_budget = (fl_grants if identity
+                                 else fl_grants[:, lay_onu])
+                    act = (fl_budget > CAP_EPS) & (qb > 0.0)
+                    take = jnp.where(act, jnp.minimum(fl_budget, qb), 0.0)
+                    drop = act & (qb - take <= SEG_EPS)
+                    qb2 = jnp.where(drop, 0.0, qb - take)
+                else:
+                    fullf = (fl_grants > 0.0) & (fl_grants == backlog_onu)
+                    qb1 = jnp.where(fullf[:, lay_onu], 0.0, qb)
+                    budget0 = jnp.where(fullf, 0.0, fl_grants)[:, seg_onus]
+                    rows2 = jnp.arange(R)[:, None]
+
+                    def serve_it(_, st):
+                        qb_c, budget_c = st
+                        has, pos = _heads(qb_c, push_key)
+                        srv = has & (budget_c > CAP_EPS)
+                        hq = jnp.take_along_axis(qb_c, pos, axis=1)
+                        take = jnp.where(srv, jnp.minimum(budget_c, hq),
+                                         0.0)
+                        resid = jnp.where(srv, hq - take, jnp.inf)
+                        drop = srv & (resid <= SEG_EPS)
+                        newq = jnp.where(drop, 0.0, hq - take)
+                        # scatter through a scratch column: non-served
+                        # segments park at index U instead of clobbering
+                        # column 0
+                        qb_ext = jnp.concatenate(
+                            [qb_c, jnp.zeros((R, 1), f64)], axis=1)
+                        qb_ext = qb_ext.at[
+                            rows2, jnp.where(srv, pos, U)].set(
+                            jnp.where(srv, newq, 0.0))
+                        charge = jnp.where(drop, resid, 0.0)
+                        return (qb_ext[:, :U],
+                                jnp.maximum(budget_c - take - charge, 0.0))
+
+                    qb2, _ = lax.fori_loop(0, max_slots, serve_it,
+                                           (qb1, budget0))
+                drained_fl = qb - qb2
+                new_rem = c["rem"] - drained_fl
+                newly_done = (~c["done"] & (drained_fl > 0.0)
+                              & (new_rem <= EPS_BITS))
                 out.update(
-                    fls=s2,
-                    cdone=c_new,
-                    done_t=lax.cond((c_new > c_old).any(), _credit,
-                                    lambda dt: dt, c["done_t"]),
+                    qb=qb2,
+                    rem=jnp.where(newly_done, 0.0,
+                                  jnp.maximum(new_rem, 0.0)),
+                    done=c["done"] | newly_done,
+                    done_t=jnp.where(newly_done, t + cyc + prop,
+                                     c["done_t"]),
                     k=k + 1,
                     t=t + cyc,
                 )
                 return out
-            if single:
-                fl_budget = (fl_grants if identity
-                             else fl_grants[:, lay_onu])
-                act = (fl_budget > CAP_EPS) & (qb > 0.0)
-                take = jnp.where(act, jnp.minimum(fl_budget, qb), 0.0)
-                drop = act & (qb - take <= SEG_EPS)
-                qb2 = jnp.where(drop, 0.0, qb - take)
-            else:
-                fullf = (fl_grants > 0.0) & (fl_grants == backlog_onu)
-                qb1 = jnp.where(fullf[:, lay_onu], 0.0, qb)
-                budget0 = jnp.where(fullf, 0.0, fl_grants)[:, seg_onus]
-                rows2 = jnp.arange(R)[:, None]
-
-                def serve_it(_, st):
-                    qb_c, budget_c = st
-                    has, pos = _heads(qb_c, push_key)
-                    srv = has & (budget_c > CAP_EPS)
-                    hq = jnp.take_along_axis(qb_c, pos, axis=1)
-                    take = jnp.where(srv, jnp.minimum(budget_c, hq),
-                                     0.0)
-                    resid = jnp.where(srv, hq - take, jnp.inf)
-                    drop = srv & (resid <= SEG_EPS)
-                    newq = jnp.where(drop, 0.0, hq - take)
-                    # scatter through a scratch column: non-served
-                    # segments park at index U instead of clobbering
-                    # column 0
-                    qb_ext = jnp.concatenate(
-                        [qb_c, jnp.zeros((R, 1), f64)], axis=1)
-                    qb_ext = qb_ext.at[
-                        rows2, jnp.where(srv, pos, U)].set(
-                        jnp.where(srv, newq, 0.0))
-                    charge = jnp.where(drop, resid, 0.0)
-                    return (qb_ext[:, :U],
-                            jnp.maximum(budget_c - take - charge, 0.0))
-
-                qb2, _ = lax.fori_loop(0, max_slots, serve_it,
-                                       (qb1, budget0))
-            drained_fl = qb - qb2
-            new_rem = c["rem"] - drained_fl
-            newly_done = (~c["done"] & (drained_fl > 0.0)
-                          & (new_rem <= EPS_BITS))
-            out.update(
-                qb=qb2,
-                rem=jnp.where(newly_done, 0.0,
-                              jnp.maximum(new_rem, 0.0)),
-                done=c["done"] | newly_done,
-                done_t=jnp.where(newly_done, t + cyc + prop,
-                                 c["done_t"]),
-                k=k + 1,
-                t=t + cyc,
-            )
-            return out
 
         final = lax.while_loop(cond, body, carry)
         done_t, t = final["done_t"], final["t"]
@@ -593,8 +738,9 @@ def _build_program(spec, lay, n_draws: int):
         elif fill_unfinished:
             left = part & ~done_f
             done_t = jnp.where(left, t + prop, done_t)
-        return done_t, rem_f, final["exact"]
+        return done_t, rem_f, final["exact"], final["k"]
 
+    program.__name__ = program_name(spec, single)
     return jax.jit(program)
 
 
@@ -721,16 +867,19 @@ def _phase_inputs(cfg, lay, rem_init, ready_t, mode: str, *,
     return spec, dyn, n_draws
 
 
-def phase_program(cfg, lay, rem_init, ready_t, mode: str, **kw):
+def phase_program(cfg, lay, rem_init, ready_t, mode: str, *,
+                  collector=None, phase_label: str = "", **kw):
     """The jitted (not yet compiled) program of one phase and its host
-    inputs ``dyn``; arguments as for :func:`run_phase_device`.  Lower it
-    under ``jax.enable_x64(True)`` — its queue state is float64."""
+    inputs ``dyn``; arguments as for :func:`run_phase_device` (the
+    collector and label have nothing to record here).  Lower it under
+    ``jax.enable_x64(True)`` — its queue state is float64."""
     spec, dyn, n_draws = _phase_inputs(cfg, lay, rem_init, ready_t, mode,
                                        **kw)
     return _build_program(spec, lay, n_draws), dyn
 
 
-def run_phase_device(cfg, lay, rem_init, ready_t, mode: str, **kw,
+def run_phase_device(cfg, lay, rem_init, ready_t, mode: str, *,
+                     collector=None, phase_label: str = "", **kw,
                      ) -> Optional[Tuple[np.ndarray, np.ndarray]]:
     """Run one phase on device.  Mirrors ``engine._run_phase``'s
     signature with the host ``_Stream`` replaced by its raw
@@ -740,17 +889,36 @@ def run_phase_device(cfg, lay, rem_init, ready_t, mode: str, **kw,
     ring walk lost exactness (sustained overload aged a marginal head
     out of the ``HISTORY_CYCLES`` ring) — the caller re-runs the phase
     on the numpy engine and counts it (:func:`host_fallbacks`).
+
+    The cycles the loop ran are added to :func:`loop_cycles`, and, with
+    a ``collector``, recorded as the phase ``phase_label``'s
+    ``DevicePhase``.  Host spans: ``ponsim.inputs`` (host tables),
+    ``ponsim.put`` (upload), ``ponsim.run`` (dispatch until the exact
+    flag and cycle count are back) and ``ponsim.fetch`` (results).
     """
-    spec, dyn, n_draws = _phase_inputs(cfg, lay, rem_init, ready_t, mode,
-                                       **kw)
+    with maybe_span(collector, "ponsim.inputs"):
+        spec, dyn, n_draws = _phase_inputs(cfg, lay, rem_init, ready_t,
+                                           mode, **kw)
     with jax.enable_x64(True):
-        dyn_dev = {key: jnp.asarray(val) for key, val in dyn.items()}
+        with maybe_span(collector, "ponsim.put"):
+            dyn_dev = {key: jnp.asarray(val) for key, val in dyn.items()}
         prog = _programs.get(spec)
         if prog is None:
             if len(_programs) > 64:
-                _programs.clear()
+                clear_cache()
             prog = _programs[spec] = _build_program(spec, lay, n_draws)
-        done_t, rem, exact = prog(dyn_dev)
-        if not bool(exact):
+            _arg_shapes[spec] = {key: jax.ShapeDtypeStruct(val.shape,
+                                                           val.dtype)
+                                 for key, val in dyn_dev.items()}
+        with maybe_span(collector, "ponsim.run"):
+            done_t, rem, exact, cycles = prog(dyn_dev)
+            exact, cycles = jax.device_get((exact, cycles))
+        name = prog.__name__
+        _LOOP_CYCLES[name] = _LOOP_CYCLES.get(name, 0) + int(cycles)
+        if not exact:
             return None
-        return np.asarray(done_t), np.asarray(rem)
+        if collector is not None:
+            collector.device_phase(phase_label or mode, spec[1],
+                                   int(cycles))
+        with maybe_span(collector, "ponsim.fetch"):
+            return np.asarray(done_t), np.asarray(rem)

@@ -301,7 +301,7 @@ def train(
                                       faults=faults,
                                       quorum_frac=quorum),
         )
-        with maybe_span(collector, "net:timeline", rounds=n_net_rounds):
+        with maybe_span(collector, "net.timeline", rounds=n_net_rounds):
             timeline = simulate(net_spec, collector=collector)[0]
         if job_specs is not None:
             # the pods' wall clock follows THEIR job's sync time; the

@@ -27,6 +27,7 @@ from repro.net.engine import SweepCase, _round_sweep, _sweep_topology
 from repro.net.jobs import FAIRNESS_POLICIES, validate_case_jobs
 from repro.net.sim import FLRoundWorkload, PONConfig
 from repro.net.timeline import TimelineSchedule, _timeline_sweep
+from repro.obs.trace import maybe_span
 
 __all__ = ["SweepSpec", "simulate"]
 
@@ -171,7 +172,9 @@ def simulate(spec: SweepSpec, cfg: Optional[PONConfig] = None,
     engine (returns ``List[RoundResult]``). ``cfg`` overrides
     ``spec.pon``; with neither, the default :class:`PONConfig` runs.
     ``collector`` is a ``repro.obs.Collector`` (run-time state, so it
-    rides outside the frozen spec).
+    rides outside the frozen spec).  The whole call is the host span
+    ``net.simulate``; it and the spans inside it show in any
+    ``jax.profiler`` trace of the call, collector or not.
     """
     if not isinstance(spec, SweepSpec):
         raise TypeError(
@@ -182,14 +185,16 @@ def simulate(spec: SweepSpec, cfg: Optional[PONConfig] = None,
         spec.pon if spec.pon is not None else PONConfig()
     )
     cases = list(spec.cases)
-    if spec.schedule is not None:
-        return _timeline_sweep(
-            pon, cases, spec.schedule, mode=spec.mode,
-            t_round_hint=spec.t_round_hint, max_t=spec.max_t,
+    with maybe_span(collector, "net.simulate", cases=len(cases),
+                    backend=spec.backend):
+        if spec.schedule is not None:
+            return _timeline_sweep(
+                pon, cases, spec.schedule, mode=spec.mode,
+                t_round_hint=spec.t_round_hint, max_t=spec.max_t,
+                collector=collector, backend=spec.backend,
+            )
+        return _round_sweep(
+            pon, cases, t_round_hint=spec.t_round_hint, max_t=spec.max_t,
+            ul_deadline_s=spec.ul_deadline_s, ul_outage_s=spec.ul_outage_s,
             collector=collector, backend=spec.backend,
         )
-    return _round_sweep(
-        pon, cases, t_round_hint=spec.t_round_hint, max_t=spec.max_t,
-        ul_deadline_s=spec.ul_deadline_s, ul_outage_s=spec.ul_outage_s,
-        collector=collector, backend=spec.backend,
-    )

@@ -1247,10 +1247,16 @@ def _round_sweep(cfg, cases: Sequence[SweepCase],
     exactly like the per-case deadline column. ``None`` (the default)
     is bitwise identical to all-``inf`` windows.
 
-    ``collector`` (``repro.obs.Collector``, optional) records per-phase
-    cycle metrics inside ``_run_phase`` plus per-case upload-completion
-    times keyed by (policy, load); ``collector=None`` (the default) is
-    bitwise identical to an uninstrumented run.
+    ``collector`` (``repro.obs.Collector``, optional) records the host
+    spans of the call (``net.layout``, ``net.phase``, ``net.slots``,
+    ``net.assemble``; ``ponsim.*`` inside a device phase) and per-case
+    upload-completion times keyed by (policy, load), on both backends.
+    Per phase, the numpy engine records per-cycle ``PhaseStats``; the
+    jit backend records only the phase's rows and the cycles its device
+    loop ran (``DevicePhase``): the per-cycle fields are absent there,
+    not zero.  Results with and without a collector are bitwise
+    identical.  The spans also reach an active ``jax.profiler`` trace
+    with no collector at all (``repro.obs.trace.maybe_span``).
 
     ``backend`` selects the phase engine: ``None``/``"numpy"`` is the
     host cycle loop (the default, bitwise-pinned); ``"jit"`` compiles
@@ -1259,8 +1265,7 @@ def _round_sweep(cfg, cases: Sequence[SweepCase],
     with a numpy re-run, counted in ``ops.host_fallbacks()``, for phases
     whose background state outgrows the device ring (see
     ``ops.run_phase_device``).  The jit
-    backend rejects injected arrival matrices and ``collector``
-    instrumentation.
+    backend rejects injected arrival matrices.
     """
     from repro.net.sim import RoundResult  # lazy: sim imports us lazily
     from repro.obs.trace import maybe_span
@@ -1269,18 +1274,12 @@ def _round_sweep(cfg, cases: Sequence[SweepCase],
     if backend not in (None, "numpy", "jit"):
         raise ValueError(f"unknown engine backend {backend!r}")
     use_jit = backend == "jit"
-    if use_jit:
-        if collector is not None:
-            raise ValueError(
-                "backend='jit' does not support collector "
-                "instrumentation; use the numpy backend"
-            )
-        if any(case.dl_arrivals is not None
-               or case.ul_arrivals is not None for case in cases):
-            raise ValueError(
-                "backend='jit' does not support injected arrival "
-                "matrices; use the numpy backend"
-            )
+    if use_jit and any(case.dl_arrivals is not None
+                       or case.ul_arrivals is not None for case in cases):
+        raise ValueError(
+            "backend='jit' does not support injected arrival "
+            "matrices; use the numpy backend"
+        )
     jobs_any = any(case.jobs is not None for case in cases)
     fairness = None
     if jobs_any:
@@ -1325,102 +1324,103 @@ def _round_sweep(cfg, cases: Sequence[SweepCase],
                     f"n_pons * n_onus = {total_onus} columns; "
                     f"got shape {np.shape(arr)}"
                 )
-    lay = _layout_for(cases, n_local, P)
-    B = len(cases)
-    R = B * P
-    row_case = np.repeat(np.arange(B), P)
-    row_pon = np.tile(np.arange(P), B)
-    rates_pon = topo.rates(cfg)
-    cap_row = np.tile(topo.capacity_bits(cfg), B)
-    cps_cap = topo.cps_capacity_bits(cfg)
-    per_onu_rate = np.stack([
-        pon_bg_rates(c.workload.clients, c.workload.model_bits, c.load,
-                     cfg, topo, t_round_hint,
-                     model_bits_by_client=(
-                         None if c.jobs is None else
-                         {cid: float(job.model_bits)
-                          for job in c.jobs for cid in job.clients}
-                     ))
-        for c in cases
-    ])                                                  # (B, n_pons)
-    per_case_dl = isinstance(ul_deadline_s, (list, tuple, np.ndarray))
-    if per_case_dl:
-        dl_case = np.array(
-            [np.inf if d is None else float(d) for d in ul_deadline_s],
-            np.float64,
-        )
-        if dl_case.shape != (B,):
-            raise ValueError(
-                f"per-case ul_deadline_s needs {B} entries; "
-                f"got shape {dl_case.shape}"
+    with maybe_span(collector, "net.layout", cases=len(cases)):
+        lay = _layout_for(cases, n_local, P)
+        B = len(cases)
+        R = B * P
+        row_case = np.repeat(np.arange(B), P)
+        row_pon = np.tile(np.arange(P), B)
+        rates_pon = topo.rates(cfg)
+        cap_row = np.tile(topo.capacity_bits(cfg), B)
+        cps_cap = topo.cps_capacity_bits(cfg)
+        per_onu_rate = np.stack([
+            pon_bg_rates(c.workload.clients, c.workload.model_bits, c.load,
+                         cfg, topo, t_round_hint,
+                         model_bits_by_client=(
+                             None if c.jobs is None else
+                             {cid: float(job.model_bits)
+                              for job in c.jobs for cid in job.clients}
+                         ))
+            for c in cases
+        ])                                                  # (B, n_pons)
+        per_case_dl = isinstance(ul_deadline_s, (list, tuple, np.ndarray))
+        if per_case_dl:
+            dl_case = np.array(
+                [np.inf if d is None else float(d) for d in ul_deadline_s],
+                np.float64,
             )
-        dl_row = np.repeat(dl_case, P)
-        ul_max_t = max_t
-    else:
-        dl_case = dl_row = None
-        ul_max_t = max_t if ul_deadline_s is None else ul_deadline_s
-    if ul_outage_s is not None:
-        if len(ul_outage_s) != B:
-            raise ValueError(
-                f"per-case ul_outage_s needs {B} entries; "
-                f"got {len(ul_outage_s)}"
-            )
-        outage_row = np.full((B, P, 2), np.inf)
-        for b, win in enumerate(ul_outage_s):
-            if win is None:
-                continue
-            arr = np.asarray(win, np.float64)
-            if arr.shape == (2,):
-                arr = np.broadcast_to(arr, (P, 2))
-            if arr.shape != (P, 2):
+            if dl_case.shape != (B,):
                 raise ValueError(
-                    f"ul_outage_s[{b}] must be (2,) or ({P}, 2); "
-                    f"got shape {arr.shape}"
+                    f"per-case ul_deadline_s needs {B} entries; "
+                    f"got shape {dl_case.shape}"
                 )
-            outage_row[b] = arr
-        outage_row = outage_row.reshape(R, 2)
-        if not np.isfinite(outage_row[:, 0]).any():
-            outage_row = None       # all-inf: keep the bitwise-off path
-    else:
-        outage_row = None
-    no_dl = np.zeros((R, lay.n_clients), bool)
-    for b, case in enumerate(cases):
-        if case.no_dl_ids:
-            skip = list(case.no_dl_ids)
-            for p in range(P):
-                no_dl[b * P + p] = np.isin(lay.cid_of[p], skip)
-    no_dl &= lay.part
-
-    # multi-tenant jobs: the per-row job axis next to the slot layout —
-    # every live column binds to its owning job (jcol), carries its
-    # job's model bits (mb), and every row knows its jobs' weights and
-    # soft deadlines for the fairness split. Sweeps mixing job counts
-    # pad to the max J with zero-demand phantom jobs, which every
-    # fairness policy grants nothing.
-    jobs_info = None
-    if jobs_any:
-        J = max(len(case.jobs) for case in cases)
-        jcol = np.full((R, lay.n_clients), -1, np.int64)
-        mb_col = np.zeros((R, lay.n_clients))
-        w_row = np.ones((R, J))
-        dl_jrow = np.full((R, J), np.inf)
+            dl_row = np.repeat(dl_case, P)
+            ul_max_t = max_t
+        else:
+            dl_case = dl_row = None
+            ul_max_t = max_t if ul_deadline_s is None else ul_deadline_s
+        if ul_outage_s is not None:
+            if len(ul_outage_s) != B:
+                raise ValueError(
+                    f"per-case ul_outage_s needs {B} entries; "
+                    f"got {len(ul_outage_s)}"
+                )
+            outage_row = np.full((B, P, 2), np.inf)
+            for b, win in enumerate(ul_outage_s):
+                if win is None:
+                    continue
+                arr = np.asarray(win, np.float64)
+                if arr.shape == (2,):
+                    arr = np.broadcast_to(arr, (P, 2))
+                if arr.shape != (P, 2):
+                    raise ValueError(
+                        f"ul_outage_s[{b}] must be (2,) or ({P}, 2); "
+                        f"got shape {arr.shape}"
+                    )
+                outage_row[b] = arr
+            outage_row = outage_row.reshape(R, 2)
+            if not np.isfinite(outage_row[:, 0]).any():
+                outage_row = None       # all-inf: keep the bitwise-off path
+        else:
+            outage_row = None
+        no_dl = np.zeros((R, lay.n_clients), bool)
         for b, case in enumerate(cases):
-            jidx_of = {cid: j for j, job in enumerate(case.jobs)
-                       for cid in job.clients}
-            mb_of = {cid: float(job.model_bits) for job in case.jobs
-                     for cid in job.clients}
-            for p in range(P):
-                r = b * P + p
-                for col in np.nonzero(lay.part[r])[0]:
-                    cid = int(lay.cid_of[p, col])
-                    jcol[r, col] = jidx_of[cid]
-                    mb_col[r, col] = mb_of[cid]
-            for j, job in enumerate(case.jobs):
-                w_row[b * P:(b + 1) * P, j] = float(job.weight)
-                if job.deadline_s is not None:
-                    dl_jrow[b * P:(b + 1) * P, j] = float(job.deadline_s)
-        jobs_info = {"J": J, "jcol": jcol, "mb": mb_col, "w": w_row,
-                     "dl": dl_jrow, "fairness": fairness}
+            if case.no_dl_ids:
+                skip = list(case.no_dl_ids)
+                for p in range(P):
+                    no_dl[b * P + p] = np.isin(lay.cid_of[p], skip)
+        no_dl &= lay.part
+
+        # multi-tenant jobs: the per-row job axis next to the slot layout —
+        # every live column binds to its owning job (jcol), carries its
+        # job's model bits (mb), and every row knows its jobs' weights and
+        # soft deadlines for the fairness split. Sweeps mixing job counts
+        # pad to the max J with zero-demand phantom jobs, which every
+        # fairness policy grants nothing.
+        jobs_info = None
+        if jobs_any:
+            J = max(len(case.jobs) for case in cases)
+            jcol = np.full((R, lay.n_clients), -1, np.int64)
+            mb_col = np.zeros((R, lay.n_clients))
+            w_row = np.ones((R, J))
+            dl_jrow = np.full((R, J), np.inf)
+            for b, case in enumerate(cases):
+                jidx_of = {cid: j for j, job in enumerate(case.jobs)
+                           for cid in job.clients}
+                mb_of = {cid: float(job.model_bits) for job in case.jobs
+                         for cid in job.clients}
+                for p in range(P):
+                    r = b * P + p
+                    for col in np.nonzero(lay.part[r])[0]:
+                        cid = int(lay.cid_of[p, col])
+                        jcol[r, col] = jidx_of[cid]
+                        mb_col[r, col] = mb_of[cid]
+                for j, job in enumerate(case.jobs):
+                    w_row[b * P:(b + 1) * P, j] = float(job.weight)
+                    if job.deadline_s is not None:
+                        dl_jrow[b * P:(b + 1) * P, j] = float(job.deadline_s)
+            jobs_info = {"J": J, "jcol": jcol, "mb": mb_col, "w": w_row,
+                         "dl": dl_jrow, "fairness": fairness}
 
     def jobs_ctx_for(sel):
         """Row-sliced per-job phase context (None when single-tenant)."""
@@ -1497,6 +1497,8 @@ def _round_sweep(cfg, cases: Sequence[SweepCase],
                 n_pons=kw.get("n_pons", 1),
                 deadline_row=kw.get("deadline_row"),
                 outage_row=kw.get("outage_row"),
+                collector=kw.get("collector"),
+                phase_label=kw.get("phase_label", ""),
             )
             if out is not None:
                 return out
@@ -1523,19 +1525,24 @@ def _round_sweep(cfg, cases: Sequence[SweepCase],
             bits = jobs_info["mb"][fcfs_rows]
         rem0 = np.where(sub.part & ~no_dl[fcfs_rows], bits, 0.0)
         ready0 = np.zeros_like(rem0)
-        with maybe_span(collector, "phase:dl:fcfs", rows=len(fcfs_rows)):
+        with maybe_span(collector, "net.phase", phase="dl", mode="fcfs",
+                        rows=len(fcfs_rows)):
             dl_done[fcfs_rows], _ = run_phase(
                 sub, rem0, ready0, fcfs_rows, "dl", "fcfs",
                 max_t=max_t, cap_row=cap_row[fcfs_rows], cps_cap=cps_cap,
                 n_pons=P, collector=collector, phase_label="dl:fcfs",
                 jobs_ctx=jobs_ctx_for(fcfs_rows),
             )
-    for r in bs_rows:
-        b, p = int(row_case[r]), int(row_pon[r])
-        mb = (cases[b].workload.model_bits if jobs_info is None
-              else jobs_info["mb"][r])
-        t_bcast = mb / (rates_pon[p] * cfg.efficiency) + cfg.propagation_s
-        dl_done[r] = np.where(lay.part[r], t_bcast, np.nan)
+    if len(bs_rows):
+        with maybe_span(collector, "net.phase", phase="dl", mode="bs",
+                        rows=len(bs_rows)):
+            for r in bs_rows:
+                b, p = int(row_case[r]), int(row_pon[r])
+                mb = (cases[b].workload.model_bits if jobs_info is None
+                      else jobs_info["mb"][r])
+                t_bcast = (mb / (rates_pon[p] * cfg.efficiency)
+                           + cfg.propagation_s)
+                dl_done[r] = np.where(lay.part[r], t_bcast, np.nan)
     dl_done = np.where(no_dl, 0.0, dl_done)
 
     ready_t = dl_done + lay.t_ud
@@ -1548,7 +1555,8 @@ def _round_sweep(cfg, cases: Sequence[SweepCase],
         sub = lay.rows(fcfs_rows)
         rem0 = np.where(sub.part, sub.m_ud, 0.0)
         ready = np.where(sub.part, ready_t[fcfs_rows], np.inf)
-        with maybe_span(collector, "phase:ul:fcfs", rows=len(fcfs_rows)):
+        with maybe_span(collector, "net.phase", phase="ul", mode="fcfs",
+                        rows=len(fcfs_rows)):
             ul_done[fcfs_rows], ul_rem[fcfs_rows] = run_phase(
                 sub, rem0, ready, fcfs_rows, "ul", "fcfs",
                 max_t=ul_max_t, fill_unfinished=ul_deadline_s is None,
@@ -1560,38 +1568,16 @@ def _round_sweep(cfg, cases: Sequence[SweepCase],
                 jobs_ctx=jobs_ctx_for(fcfs_rows),
             )
     if len(bs_rows):
-        per_row = []
-        per_row_jobs = []
-        for r in bs_rows:
-            b, p = int(row_case[r]), int(row_pon[r])
-            dl_map = {
-                int(lay.cid_of[p, j]): float(dl_done[r, j])
-                for j in range(lay.n_clients) if lay.part[r, j]
-            }
-            if jobs_info is None:
-                profiles = [
-                    ClientProfile(
-                        client_id=c.client_id,
-                        t_ud=c.t_ud,
-                        t_dl=dl_map[c.client_id],
-                        m_ud_bits=c.m_ud_bits,
-                        distance_m=c.distance_m,
-                    )
-                    for c in cases[b].workload.clients
-                    if c.client_id in dl_map
-                ]
-                spec, arrays = _bs_slice(
-                    profiles, float(rates_pon[p] * cfg.efficiency)
-                )
-                if P == 1:
-                    specs[b] = spec
-                per_row.append((spec, arrays))
-            else:
-                # each job carves its own slice over its own clients;
-                # slots stay grouped job-major, matching the oracle
-                row_slots = []
-                for j, job in enumerate(cases[b].jobs):
-                    jset = set(job.clients)
+        with maybe_span(collector, "net.slots", rows=len(bs_rows)):
+            per_row = []
+            per_row_jobs = []
+            for r in bs_rows:
+                b, p = int(row_case[r]), int(row_pon[r])
+                dl_map = {
+                    int(lay.cid_of[p, j]): float(dl_done[r, j])
+                    for j in range(lay.n_clients) if lay.part[r, j]
+                }
+                if jobs_info is None:
                     profiles = [
                         ClientProfile(
                             client_id=c.client_id,
@@ -1601,20 +1587,44 @@ def _round_sweep(cfg, cases: Sequence[SweepCase],
                             distance_m=c.distance_m,
                         )
                         for c in cases[b].workload.clients
-                        if c.client_id in dl_map and c.client_id in jset
+                        if c.client_id in dl_map
                     ]
                     spec, arrays = _bs_slice(
                         profiles, float(rates_pon[p] * cfg.efficiency)
                     )
-                    row_slots.append((j, spec, arrays))
-                per_row_jobs.append(row_slots)
-        slot_arrays = (_stack_slots(per_row, n_local)
-                       if jobs_info is None
-                       else _stack_slots_jobs(per_row_jobs, n_local))
+                    if P == 1:
+                        specs[b] = spec
+                    per_row.append((spec, arrays))
+                else:
+                    # each job carves its own slice over its own clients;
+                    # slots stay grouped job-major, matching the oracle
+                    row_slots = []
+                    for j, job in enumerate(cases[b].jobs):
+                        jset = set(job.clients)
+                        profiles = [
+                            ClientProfile(
+                                client_id=c.client_id,
+                                t_ud=c.t_ud,
+                                t_dl=dl_map[c.client_id],
+                                m_ud_bits=c.m_ud_bits,
+                                distance_m=c.distance_m,
+                            )
+                            for c in cases[b].workload.clients
+                            if c.client_id in dl_map and c.client_id in jset
+                        ]
+                        spec, arrays = _bs_slice(
+                            profiles, float(rates_pon[p] * cfg.efficiency)
+                        )
+                        row_slots.append((j, spec, arrays))
+                    per_row_jobs.append(row_slots)
+            slot_arrays = (_stack_slots(per_row, n_local)
+                           if jobs_info is None
+                           else _stack_slots_jobs(per_row_jobs, n_local))
         sub = lay.rows(bs_rows)
         rem0 = np.where(sub.part, sub.m_ud, 0.0)
         ready = np.where(sub.part, ready_t[bs_rows], np.inf)
-        with maybe_span(collector, "phase:ul:bs", rows=len(bs_rows)):
+        with maybe_span(collector, "net.phase", phase="ul", mode="bs",
+                        rows=len(bs_rows)):
             ul_done[bs_rows], ul_rem[bs_rows] = run_phase(
                 sub, rem0, ready, bs_rows, "ul", "bs",
                 slot_arrays=slot_arrays, max_t=ul_max_t,
@@ -1628,61 +1638,62 @@ def _round_sweep(cfg, cases: Sequence[SweepCase],
             )
 
     # ---- assemble --------------------------------------------------------
-    results = []
-    for b, case in enumerate(cases):
-        dl: Dict[int, float] = {}
-        rd: Dict[int, float] = {}
-        ul: Dict[int, float] = {}
-        remaining: Dict[int, float] = {}
-        for p in range(P):
-            r = b * P + p
-            sel = lay.part[r]
-            if not sel.any():
-                continue
-            ids = lay.cid_of[p][sel]
-            dl.update(
-                (int(i), float(v)) for i, v in zip(ids, dl_done[r, sel])
-            )
-            rd.update(
-                (int(i), float(v)) for i, v in zip(ids, ready_t[r, sel])
-            )
-            ul.update(
-                (int(i), float(v)) for i, v in zip(ids, ul_done[r, sel])
-            )
-            remaining.update(
-                (int(i), float(v))
-                for i, v in zip(ids, ul_rem[r, sel]) if v > 0.0
-            )
-        if per_case_dl:
-            dlb = float(dl_case[b])
-            has_dl = bool(np.isfinite(dl_case[b]))
-        else:
-            dlb = ul_deadline_s
-            has_dl = ul_deadline_s is not None
-        if remaining and has_dl:
-            sync = dlb + case.workload.t_aggregate
-        else:
-            sync = max(ul.values()) + case.workload.t_aggregate
-        if collector is not None:
-            ul_times = [v for v in ul.values() if np.isfinite(v)]
-            if ul_times:
-                collector.record_upload_times(case.policy, case.load,
-                                              ul_times)
-        results.append(RoundResult(
-            policy=case.policy,
-            sync_time=sync,
-            dl_done=dl,
-            ready=rd,
-            ul_done=ul,
-            compute_bound=max(rd.values()),
-            load=case.load,
-            slice_spec=specs.get(b),
-            ul_remaining=remaining if has_dl else None,
-            job_stats=(None if case.jobs is None else
-                       compute_job_stats(case.jobs, ul, n_local, P)),
-        ))
-        if case.jobs is not None:
-            _record_job_uploads(collector, case, results[-1])
+    with maybe_span(collector, "net.assemble", cases=len(cases)):
+        results = []
+        for b, case in enumerate(cases):
+            dl: Dict[int, float] = {}
+            rd: Dict[int, float] = {}
+            ul: Dict[int, float] = {}
+            remaining: Dict[int, float] = {}
+            for p in range(P):
+                r = b * P + p
+                sel = lay.part[r]
+                if not sel.any():
+                    continue
+                ids = lay.cid_of[p][sel]
+                dl.update(
+                    (int(i), float(v)) for i, v in zip(ids, dl_done[r, sel])
+                )
+                rd.update(
+                    (int(i), float(v)) for i, v in zip(ids, ready_t[r, sel])
+                )
+                ul.update(
+                    (int(i), float(v)) for i, v in zip(ids, ul_done[r, sel])
+                )
+                remaining.update(
+                    (int(i), float(v))
+                    for i, v in zip(ids, ul_rem[r, sel]) if v > 0.0
+                )
+            if per_case_dl:
+                dlb = float(dl_case[b])
+                has_dl = bool(np.isfinite(dl_case[b]))
+            else:
+                dlb = ul_deadline_s
+                has_dl = ul_deadline_s is not None
+            if remaining and has_dl:
+                sync = dlb + case.workload.t_aggregate
+            else:
+                sync = max(ul.values()) + case.workload.t_aggregate
+            if collector is not None:
+                ul_times = [v for v in ul.values() if np.isfinite(v)]
+                if ul_times:
+                    collector.record_upload_times(case.policy, case.load,
+                                                  ul_times)
+            results.append(RoundResult(
+                policy=case.policy,
+                sync_time=sync,
+                dl_done=dl,
+                ready=rd,
+                ul_done=ul,
+                compute_bound=max(rd.values()),
+                load=case.load,
+                slice_spec=specs.get(b),
+                ul_remaining=remaining if has_dl else None,
+                job_stats=(None if case.jobs is None else
+                           compute_job_stats(case.jobs, ul, n_local, P)),
+            ))
+            if case.jobs is not None:
+                _record_job_uploads(collector, case, results[-1])
     return results
 
 

@@ -713,15 +713,16 @@ def _advance_rounds(cfg, cases, schedule, t_round_hint, max_t, policy,
                           rounds=[]) for c in cases]
     quorum = schedule.quorum_frac
     for r in range(schedule.n_rounds):
-        row_cases, row_meta = _build_rows(
-            cases, schedule, r, carries, fstates
-        )
+        with maybe_span(collector, "timeline.setup", round=r):
+            row_cases, row_meta = _build_rows(
+                cases, schedule, r, carries, fstates
+            )
         for b, _, rem_start, _ in row_meta:
             for cid in rem_start:
                 entries[b].setdefault(cid, r)
         outages = _round_outages(cases, schedule, r, row_meta)
         deadlines = deadline_fn(r, row_cases, row_meta, outages)
-        with maybe_span(collector, f"timeline:round[{r}]",
+        with maybe_span(collector, "timeline.round", round=r,
                         rows=len(row_cases)):
             results = _round_sweep(
                 cfg, row_cases, t_round_hint=t_round_hint, max_t=max_t,
@@ -781,31 +782,32 @@ def _advance_rounds(cfg, cases, schedule, t_round_hint, max_t, policy,
                 _unmet()        # final verdicts after the last extend
             deadlines = dls
         per_row_dl = isinstance(deadlines, (list, tuple, np.ndarray))
-        for b, ridx, rem_start, drops in row_meta:
-            res = results[ridx] if ridx is not None else None
-            rnd, carry = _round_view(
-                r, float(t_now[b]), res, rem_start,
-                cases[b].workload.t_aggregate, policy, entries[b],
-            )
-            if ridx is not None and ridx in met:
-                rnd.quorum_met = met[ridx]
-                rnd.deadline_extensions = ext_counts.get(ridx, 0)
-            carry = _apply_round_faults(
-                schedule, cases[b], r, rnd, rem_start, carry, drops,
-                fstates[b], collector,
-            )
-            out[b].rounds.append(rnd)
-            carries[b] = carry
-            entries[b] = {
-                cid: ent for cid, ent in entries[b].items()
-                if cid in carry or cid in fstates[b].retries
-            }
-            t_now[b] += rnd.sync_time
-            if collector is not None:
-                dl = (deadlines[ridx]
-                      if per_row_dl and ridx is not None else
-                      None if per_row_dl else deadlines)
-                _observe_round(collector, cases[b], rnd, dl)
+        with maybe_span(collector, "timeline.views", round=r):
+            for b, ridx, rem_start, drops in row_meta:
+                res = results[ridx] if ridx is not None else None
+                rnd, carry = _round_view(
+                    r, float(t_now[b]), res, rem_start,
+                    cases[b].workload.t_aggregate, policy, entries[b],
+                )
+                if ridx is not None and ridx in met:
+                    rnd.quorum_met = met[ridx]
+                    rnd.deadline_extensions = ext_counts.get(ridx, 0)
+                carry = _apply_round_faults(
+                    schedule, cases[b], r, rnd, rem_start, carry, drops,
+                    fstates[b], collector,
+                )
+                out[b].rounds.append(rnd)
+                carries[b] = carry
+                entries[b] = {
+                    cid: ent for cid, ent in entries[b].items()
+                    if cid in carry or cid in fstates[b].retries
+                }
+                t_now[b] += rnd.sync_time
+                if collector is not None:
+                    dl = (deadlines[ridx]
+                          if per_row_dl and ridx is not None else
+                          None if per_row_dl else deadlines)
+                    _observe_round(collector, cases[b], rnd, dl)
     return out
 
 
@@ -871,38 +873,41 @@ def _folded(cfg, cases, schedule, t_round_hint, max_t, collector=None,
     row_deadlines: List[Optional[float]] = []
     row_outages: List[Optional[np.ndarray]] = []
     meta = []            # (b, r, rem_start, row_index or None)
-    for b, case in enumerate(cases):
-        for r in range(schedule.n_rounds):
-            clients_r, _, rem_start, _ = _round_setup(
-                case, schedule, r, {}
-            )
-            if not clients_r:
-                meta.append((b, r, rem_start, None))
-                continue
-            wl = FLRoundWorkload(
-                clients=clients_r,
-                model_bits=case.workload.model_bits,
-                t_aggregate=case.workload.t_aggregate,
-            )
-            meta.append((b, r, rem_start, len(rows)))
-            rows.append(SweepCase(
-                workload=wl, load=case.load, policy=case.policy,
-                seed=case.seed, stream_round=r,
-                topology=case.topology,
-            ))
-            row_deadlines.append(schedule.deadline(r))
-            if has_outage:
-                # outage injection never couples rounds (dark cycles
-                # just delay the round's own uploads), so it folds as
-                # one more per-row axis: each row carries its round's
-                # counter-keyed window
-                row_outages.append(faults.outage_windows(
-                    r, _case_n_pons(case), case.seed
-                ))
     from repro.obs.trace import maybe_span
 
+    with maybe_span(collector, "timeline.setup", cases=len(cases),
+                    rounds=schedule.n_rounds):
+        for b, case in enumerate(cases):
+            for r in range(schedule.n_rounds):
+                clients_r, _, rem_start, _ = _round_setup(
+                    case, schedule, r, {}
+                )
+                if not clients_r:
+                    meta.append((b, r, rem_start, None))
+                    continue
+                wl = FLRoundWorkload(
+                    clients=clients_r,
+                    model_bits=case.workload.model_bits,
+                    t_aggregate=case.workload.t_aggregate,
+                )
+                meta.append((b, r, rem_start, len(rows)))
+                rows.append(SweepCase(
+                    workload=wl, load=case.load, policy=case.policy,
+                    seed=case.seed, stream_round=r,
+                    topology=case.topology,
+                ))
+                row_deadlines.append(schedule.deadline(r))
+                if has_outage:
+                    # outage injection never couples rounds (dark cycles
+                    # just delay the round's own uploads), so it folds as
+                    # one more per-row axis: each row carries its round's
+                    # counter-keyed window
+                    row_outages.append(faults.outage_windows(
+                        r, _case_n_pons(case), case.seed
+                    ))
+
     has_deadline = schedule.deadline_s is not None
-    with maybe_span(collector, "timeline:folded", rows=len(rows),
+    with maybe_span(collector, "timeline.folded", rows=len(rows),
                     rounds=schedule.n_rounds):
         results = _round_sweep(
             cfg, rows, t_round_hint=t_round_hint, max_t=max_t,
@@ -913,17 +918,18 @@ def _folded(cfg, cases, schedule, t_round_hint, max_t, collector=None,
     out = [TimelineResult(policy=c.policy, load=c.load, seed=c.seed,
                           rounds=[]) for c in cases]
     t_now = np.zeros(len(cases))
-    for b, r, rem_start, ridx in meta:
-        res = results[ridx] if ridx is not None else None
-        rnd, _ = _round_view(
-            r, float(t_now[b]), res, rem_start,
-            cases[b].workload.t_aggregate, schedule.deadline_policy,
-        )
-        out[b].rounds.append(rnd)
-        t_now[b] += rnd.sync_time
-        if collector is not None:
-            _observe_round(collector, cases[b], rnd,
-                           schedule.deadline(r))
+    with maybe_span(collector, "timeline.views", rows=len(meta)):
+        for b, r, rem_start, ridx in meta:
+            res = results[ridx] if ridx is not None else None
+            rnd, _ = _round_view(
+                r, float(t_now[b]), res, rem_start,
+                cases[b].workload.t_aggregate, schedule.deadline_policy,
+            )
+            out[b].rounds.append(rnd)
+            t_now[b] += rnd.sync_time
+            if collector is not None:
+                _observe_round(collector, cases[b], rnd,
+                               schedule.deadline(r))
     return out
 
 
@@ -986,7 +992,7 @@ def _folded_jobs(cfg, cases, schedule, mode, t_round_hint, max_t,
                                 jobs=active))
     from repro.obs.trace import maybe_span
 
-    with maybe_span(collector, "timeline:folded-jobs", rows=len(rows),
+    with maybe_span(collector, "timeline.folded_jobs", rows=len(rows),
                     rounds=schedule.n_rounds):
         results = _round_sweep(
             cfg, rows, t_round_hint=t_round_hint, max_t=max_t,

@@ -24,7 +24,6 @@ from repro.obs.metrics import (  # noqa: F401
     StreamingHistogram,
 )
 from repro.obs.trace import (  # noqa: F401
-    NULL_TRACER,
     SpanTracer,
     load_trace,
     maybe_span,

@@ -182,11 +182,11 @@ class MetricsReport:
                 "phase": p.get("label", ""),
                 "rows": p.get("rows", 0),
                 "cycles": p.get("cycles", 0),
-                "cap_bits": p.get("cap_bits", 0.0),
-                "bg_grant_bits": p.get("bg_grant_bits", 0.0),
-                "fl_grant_bits": p.get("fl_grant_bits", 0.0),
-                "residual_bits": p.get("residual_bits", 0.0),
-                "grant_utilization": p.get("grant_utilization", 0.0),
+                "cap_bits": p.get("cap_bits", ""),
+                "bg_grant_bits": p.get("bg_grant_bits", ""),
+                "fl_grant_bits": p.get("fl_grant_bits", ""),
+                "residual_bits": p.get("residual_bits", ""),
+                "grant_utilization": p.get("grant_utilization", ""),
                 "cps_utilization": p.get("cps_utilization", ""),
             })
         return rows

@@ -386,6 +386,22 @@ class PhaseStats:
         return out
 
 
+class DevicePhase:
+    """A phase the device engine ran (``backend="jit"``): its rows and
+    the cycles its loop ran.  The per-cycle fields of
+    :class:`PhaseStats` exist on the numpy engine only, so a device
+    phase's summary leaves them out rather than reading zero."""
+
+    def __init__(self, label: str, n_rows: int, cycles: int):
+        self.label = label
+        self.n_rows = n_rows
+        self.cycles = int(cycles)
+
+    def summary(self) -> dict:
+        return {"label": self.label, "rows": self.n_rows,
+                "cycles": self.cycles}
+
+
 class Collector:
     """The observability hub threaded through the co-sim stack.
 
@@ -399,7 +415,8 @@ class Collector:
 
     * ``phases`` — per-``_run_phase`` ``PhaseStats`` (cycle counts,
       backlog depths, grant utilization, waterfill residuals, CPS
-      want/eff per row);
+      want/eff per row); a phase on the device engine records a
+      ``DevicePhase`` (rows and loop cycles only);
     * ``delay_hist[(policy, load)]`` — FL upload completion-time
       histograms (round-relative seconds);
     * ``slack_hist[(policy, load)]`` — deadline slack (deadline −
@@ -445,6 +462,10 @@ class Collector:
         if self.keep_phases:
             self.phases.append(st)
         return st
+
+    def device_phase(self, label: str, n_rows: int, cycles: int) -> None:
+        if self.keep_phases:
+            self.phases.append(DevicePhase(label, n_rows, cycles))
 
     def record_upload_times(self, policy: str, load: float,
                             times) -> None:

@@ -7,6 +7,10 @@ as Chrome trace-event "X" (complete) events viewable in Perfetto /
 yields immediately with no timestamping, so instrumented code paths
 cost one attribute check when tracing is off.
 
+Instrumented call sites go through :func:`maybe_span`, which also
+writes every span into the JAX profiler's own trace, on the clock of
+the device events, whether or not a collector is given.
+
 Format (Chrome trace-event spec,
 https://docs.google.com/document/d/1CvAClvFfyA5R-PhYUmn5OOQtYMH4h6I0nSsKchNAySU):
 ``{"traceEvents": [{"name", "ph": "X", "ts", "dur", "pid", "tid",
@@ -22,8 +26,7 @@ import time
 from contextlib import contextmanager
 from typing import List
 
-__all__ = ["SpanTracer", "NULL_TRACER", "load_trace", "validate_trace",
-           "maybe_span"]
+__all__ = ["SpanTracer", "load_trace", "validate_trace", "maybe_span"]
 
 _REQUIRED_KEYS = ("name", "ph", "ts", "dur", "pid", "tid")
 
@@ -65,21 +68,6 @@ class SpanTracer:
                 "cat": cat,
                 "args": {k: _jsonable(v) for k, v in args.items()},
             })
-
-    def instant(self, name: str, cat: str = "repro", **args) -> None:
-        if not self.enabled:
-            return
-        self.events.append({
-            "name": name,
-            "ph": "i",
-            "ts": self._now_us(),
-            "dur": 0.0,
-            "pid": self._pid,
-            "tid": self._tid,
-            "cat": cat,
-            "s": "t",
-            "args": {k: _jsonable(v) for k, v in args.items()},
-        })
 
     def to_chrome(self) -> dict:
         return {
@@ -123,15 +111,24 @@ def validate_trace(payload: dict) -> List[dict]:
     return events
 
 
-NULL_TRACER = SpanTracer(enabled=False)
-
-
 def maybe_span(collector, name: str, **args):
-    """``collector.tracer.span(...)`` or a no-op context when
-    ``collector`` is None — the one-liner instrumented call sites use
-    so the disabled path stays a single identity check."""
-    if collector is None:
-        from contextlib import nullcontext
+    """A host span named ``name`` at an instrumented call site.
 
-        return nullcontext()
-    return collector.tracer.span(name, **args)
+    The span always enters ``jax.profiler.TraceAnnotation(name)``, which
+    puts it in an active profiler trace beside the device events and is
+    close to free when no profiler session is active.  With a
+    ``collector`` it is also recorded, with ``args``, into
+    ``collector.tracer``.  ``name`` is a stable ``<layer>.<step>``
+    string; per-call values (a round index, a row count) go in ``args``
+    and reach only the collector."""
+    from jax.profiler import TraceAnnotation   # lazy: numpy-only users
+
+    if collector is None:
+        return TraceAnnotation(name)
+    return _both(TraceAnnotation(name), collector.tracer.span(name, **args))
+
+
+@contextmanager
+def _both(outer, inner):
+    with outer, inner:
+        yield

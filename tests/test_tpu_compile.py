@@ -108,8 +108,11 @@ def test_phase_program_compiles(one_chip, phase, use_pallas):
                                           sharding=one_chip)
                   for k, v in dyn.items()}
         text = prog.lower(shapes).compile().as_text()
-    # the waterfill kernel sits in every fcfs program with bg traffic
+    # the waterfill kernel sits in every fcfs program with bg traffic,
+    # under its own name, in a module named for the phase program
     assert ("tpu_custom_call" in text) == (use_pallas and mode == "fcfs")
+    assert ("%ponsim_waterfill" in text) == (use_pallas and mode == "fcfs")
+    assert text.startswith(f"HloModule jit_ponsim_{mode}")
 
 
 @pytest.mark.parametrize("x64", [True, False], ids=["x64", "x32"])
