@@ -6,8 +6,8 @@ program: deadline/outage capacity masking, the fused counter-based
 traffic sampler (arrival bits are generated on-device in 64-cycle
 windows and never touch the host), background FIFO push/serve over a
 prefix-sum ring, the stable-argsort waterfill grants (Pallas rank-sum
-kernel on TPU, the jnp oracle elsewhere), the CPS max-min split, FL
-queue serves and completion credit.  The numpy engine
+kernel on TPU, the jnp oracle elsewhere) or the bs slot grants, the CPS
+max-min split, FL queue serves and completion credit.  The numpy engine
 (``repro.net.engine._run_phase``) is the parity oracle at rtol 1e-6.
 
 Carry layout (all fixed-shape; ``R`` rows, ``U`` client columns, ``N``
@@ -94,6 +94,9 @@ _PALLAS_INTERPRET = False            # tests flip to run the kernel on CPU
 _HOST_FALLBACKS = {"ring_overflow": 0, "multi_job": 0}
 # cycles the phase programs' loops ran, by program name
 _LOOP_CYCLES: dict = {}
+# bs phases run, by slot fan-in K (the most valid slots one ONU holds in
+# one row of the phase: the levels of the slot-to-ONU grant map)
+_SLOT_FANIN: dict = {}
 
 
 def compile_count() -> int:
@@ -113,6 +116,12 @@ def loop_cycles() -> dict:
     """Cycles the device loop ran, summed per program name
     (:func:`program_name`) over every phase this process ran."""
     return dict(_LOOP_CYCLES)
+
+
+def slot_fanin() -> dict:
+    """Bs phases run, by slot fan-in ``K`` (:func:`slot_of_onu`), over
+    every phase this process ran."""
+    return dict(_SLOT_FANIN)
 
 
 def clear_cache() -> None:
@@ -254,8 +263,8 @@ def program_name(spec, single: bool) -> str:
     [_outage]``: its module in a profiler trace is ``jit_<name>``.  The
     mode is ``fcfs`` or ``bs``; ``fast`` marks the scalar-S fcfs carry
     (one client per ONU)."""
-    mode, has_cps, has_deadline, has_outage = (spec[0], spec[7], spec[8],
-                                               spec[9])
+    mode, has_cps, has_deadline, has_outage = (spec[0], spec[8], spec[9],
+                                               spec[10])
     flags = (("fast", mode == "fcfs" and single), ("cps", has_cps),
              ("deadline", has_deadline), ("outage", has_outage))
     return "_".join(["ponsim", mode] + [f for f, on in flags if on])
@@ -271,7 +280,7 @@ def _build_program(spec, lay, n_draws: int):
     profiler trace.  Returns the program's outputs plus its loop's
     cycle count."""
     global _COMPILE_COUNT
-    (mode, R, U, N, S, P, has_bg, has_cps, has_deadline, has_outage,
+    (mode, R, U, N, _S, _K, P, has_bg, has_cps, has_deadline, has_outage,
      fill_unfinished, use_pallas, max_slots, _ndraws, _nlevels,
      _onu_sig) = spec
     single, identity = lay.single, lay.identity
@@ -339,7 +348,8 @@ def _build_program(spec, lay, n_draws: int):
         rem0 = dyn["rem0"]
         f64 = rem0.dtype
 
-        def _slot_grants(backlog_onu, t, cap):
+        def _slot_wants(backlog_onu, t):
+            """Per-slot wants and their running sum, ``(R, S)`` each."""
             te_g = dyn["te"] + cyc
             active = dyn["svalid"] & (dyn["ts"] < t + cyc) & (te_g > t)
             overlap = (jnp.minimum(te_g, t + cyc)
@@ -348,12 +358,13 @@ def _build_program(spec, lay, n_draws: int):
             want = jnp.minimum(
                 want, jnp.take_along_axis(backlog_onu, dyn["sonu"], 1))
             want = jnp.where(active & (want > 0.0), want, 0.0)
-            prefix = _ref.prefix_sum(want)
+            return want, _ref.prefix_sum(want)
+
+        def _slot_grants(want, prefix, cap):
+            """Slot grants under the per-row capacity ``cap``, per ONU."""
             grants = jnp.minimum(
                 want, jnp.maximum(cap[:, None] - (prefix - want), 0.0))
-            rows = jnp.broadcast_to(jnp.arange(R)[:, None], (R, S))
-            return jnp.zeros((R, N), f64).at[rows, dyn["sonu"]].add(
-                grants)
+            return grants_per_onu(grants, dyn["slot_of_onu"])
 
         done0 = ~part | (rem0 <= 0.0)
         carry = {
@@ -552,15 +563,17 @@ def _build_program(spec, lay, n_draws: int):
                             _hol_per_onu(qb, push_key, push_time),
                             cap_fl, use_pallas)
                 else:
-                    fl_grants = _slot_grants(backlog_onu, t, cap_cyc)
+                    s_want, s_prefix = _slot_wants(backlog_onu, t)
+                    fl_grants = _slot_grants(s_want, s_prefix, cap_cyc)
                     if has_cps:
                         # recompute with the waterfilled shares is a bitwise
                         # no-op for rows the CPS does not cut (the numpy
-                        # path's conditional recompute, branch-free)
+                        # path's conditional recompute, branch-free); the
+                        # wants do not depend on the capacity
                         want = fl_grants.sum(axis=1)
                         eff = _ref.cps_waterfill_ref(
                             want.reshape(-1, P), dyn["cps_cap"]).reshape(-1)
-                        fl_grants = _slot_grants(backlog_onu, t, eff)
+                        fl_grants = _slot_grants(s_want, s_prefix, eff)
 
             # ---- bg serve: full drains + the one marginal queue/row,
             # then FL serve + completion credit
@@ -744,6 +757,48 @@ def _build_program(spec, lay, n_draws: int):
     return jax.jit(program)
 
 
+def slot_of_onu(sonu, svalid, n_onus: int) -> np.ndarray:
+    """``(R, N, K)`` int32 table of the slots each ONU holds in each row:
+    the indices of its valid slots in increasing slot order, padded with
+    ``S``, which :func:`grants_per_onu` reads as 0.0.  ``K`` is the most
+    valid slots any ONU holds in any row, and at least 1."""
+    sonu = np.asarray(sonu, np.int64)
+    R, S = sonu.shape
+    onu = np.where(np.asarray(svalid, bool), sonu, n_onus)  # N: invalid
+    # a stable argsort of each row's ONUs, as one sort of unique keys
+    onu_sorted, order = np.divmod(
+        np.sort(onu * S + np.arange(S), axis=1), S)
+    rows = np.arange(R)[:, None]
+    counts = np.bincount((onu + rows * (n_onus + 1)).ravel(),
+                         minlength=R * (n_onus + 1)).reshape(R, -1)
+    K = max(1, int(counts[:, :n_onus].max(initial=0)))
+    first = np.cumsum(counts, axis=1) - counts      # first sorted position
+    level = np.arange(S) - np.take_along_axis(first, onu_sorted, axis=1)
+    valid = onu_sorted < n_onus
+    # invalid slots all land on level 0 of the dropped ONU row N
+    table = np.full((R, n_onus + 1, K), S, np.int32)
+    table[rows, onu_sorted, np.where(valid, level, 0)] = np.where(
+        valid, order, S)
+    return table[:, :n_onus]
+
+
+def grants_per_onu(grants, table):
+    """Slot grants ``(R, S)`` summed per ONU, ``(R, N)``, without a
+    scatter: level ``k`` of ``table`` (:func:`slot_of_onu`) gathers each
+    ONU's ``k``-th valid slot, padding (index ``S``) reads +0.0, and the
+    levels add in slot order.  ``np.add.at`` adds in that order from
+    +0.0, and invalid slots carry exactly 0.0, so the sums are bitwise
+    the numpy engine's."""
+    def level(lvl):
+        return jnp.take_along_axis(grants, table[:, :, lvl], axis=1,
+                                   mode="fill", fill_value=0.0)
+
+    acc = level(0)
+    for lvl in range(1, table.shape[2]):
+        acc = acc + level(lvl)
+    return acc
+
+
 def _phase_inputs(cfg, lay, rem_init, ready_t, mode: str, *,
                   keys=None, lams=None, slot_arrays=None,
                   max_t: float = 600.0, fill_unfinished: bool = True,
@@ -847,7 +902,7 @@ def _phase_inputs(cfg, lay, rem_init, ready_t, mode: str, *,
         dyn["thr"] = _poisson_thresholds(lam_w, n_draws)
         dyn["burst_thr"] = geometric_tables(1.0 / cfg.bg_burst_packets)[1]
         dyn["packet_bits"] = np.float32(PACKET_BITS)
-    S = 1
+    S = K = 1
     if mode == "bs":
         ts, te, sonu, srate, svalid = slot_arrays
         S = ts.shape[1]
@@ -856,9 +911,11 @@ def _phase_inputs(cfg, lay, rem_init, ready_t, mode: str, *,
                    sonu=np.asarray(sonu, np.int64),
                    srate=np.asarray(srate, np.float64),
                    svalid=np.asarray(svalid, bool))
+        dyn["slot_of_onu"] = slot_of_onu(dyn["sonu"], dyn["svalid"], N)
+        K = dyn["slot_of_onu"].shape[2]
 
     max_slots = int(np.asarray(lay.seg_len).max())
-    spec = (mode, R, U, N, S, int(n_pons), has_bg, has_cps,
+    spec = (mode, R, U, N, S, K, int(n_pons), has_bg, has_cps,
             has_deadline, has_outage, bool(fill_unfinished),
             bool(use_pallas), max_slots, n_draws,
             len(dyn.get("burst_thr", ())),
@@ -915,6 +972,9 @@ def run_phase_device(cfg, lay, rem_init, ready_t, mode: str, *,
             exact, cycles = jax.device_get((exact, cycles))
         name = prog.__name__
         _LOOP_CYCLES[name] = _LOOP_CYCLES.get(name, 0) + int(cycles)
+        if mode == "bs":
+            K = spec[5]
+            _SLOT_FANIN[K] = _SLOT_FANIN.get(K, 0) + 1
         if not exact:
             return None
         if collector is not None:

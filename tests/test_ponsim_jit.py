@@ -38,6 +38,8 @@ from repro.net import (
     PONConfig,
     PrecomputedSource,
     SweepCase,
+    SweepSpec,
+    simulate,
     simulate_round,
     simulate_round_sweep,
 )
@@ -355,3 +357,148 @@ class TestPallasWaterfillKernel:
             backlog.astype(np.float32), key.astype(np.float32),
             cap.astype(np.float32), interpret=True))
         assert np.array_equal(g32, backlog.astype(np.float32))
+
+
+def _phase_digest(monkeypatch, run):
+    """sha256 of the ``done_t`` and ``rem`` bytes of every phase the
+    device engine returns while ``run()`` runs."""
+    h = hashlib.sha256()
+    real = ponsim_ops.run_phase_device
+
+    def record(*args, **kw):
+        out = real(*args, **kw)
+        for a in out:
+            h.update(np.ascontiguousarray(a).tobytes())
+        return out
+
+    monkeypatch.setattr(ponsim_ops, "run_phase_device", record)
+    run()
+    return h.hexdigest()
+
+
+class TestBsPinned:
+    """Bitwise pins of bs phase outputs, taken while the slot grants
+    still reached the ONUs through a scatter-add.  In both runs the CPS
+    uplink cuts the slot grants, so the regrant is pinned too."""
+
+    def test_multi_pon_cps_sweep(self, monkeypatch):
+        topo = MultiPonTopology(n_pons=3, cps_rate_bps=0.6e9)
+        cases = [SweepCase(workload=_workload([0, 2, 5, 7, 10], seed=2),
+                           load=0.3, policy="bs", seed=5, topology=topo)]
+        spec = SweepSpec(cases=tuple(cases), pon=CFG, backend="jit")
+        digest = _phase_digest(monkeypatch, lambda: simulate(spec))
+        assert digest == ("e5b3fce6dd9606cf10b32857549ac7fb"
+                          "dda59298ab1dde827b32fc93e3e472c1")
+
+    def test_folded_timeline(self, monkeypatch):
+        topo = MultiPonTopology(n_pons=2, cps_rate_bps=0.5e9)
+        cases = [SweepCase(workload=_workload(range(8), seed=4), load=0.4,
+                           policy="bs", seed=11, topology=topo)]
+        spec = SweepSpec(cases=tuple(cases), pon=CFG, backend="jit",
+                         schedule=TimelineSchedule(n_rounds=4),
+                         mode="folded")
+        digest = _phase_digest(monkeypatch, lambda: simulate(spec))
+        assert digest == ("90fba85cfb495dfea39fa6b8d73883da"
+                          "f56bfc9e452ca0a49798a7b0a705f786")
+
+
+# slot layouts (ONU of each slot, valid) of slot fan-in K = 1, 2, 3, on
+# 6 ONUs: padding slots are invalid and name ONU 0, and some rows hold
+# no valid slot at all
+SLOT_LAYOUTS = {
+    1: ([[3, 0, 5, 1, 0, 0], [0, 0, 0, 0, 0, 0], [2, 4, 0, 1, 0, 0]],
+        [[1, 1, 1, 1, 0, 0], [0, 0, 0, 0, 0, 0], [1, 1, 1, 0, 0, 0]]),
+    2: ([[1, 3, 1, 0, 0, 0], [0, 5, 0, 2, 4, 0], [0, 0, 0, 0, 0, 0]],
+        [[1, 1, 1, 0, 0, 0], [1, 1, 1, 1, 1, 0], [0, 0, 0, 0, 0, 0]]),
+    3: ([[4, 4, 2, 4, 0, 0], [0, 1, 0, 1, 0, 3], [0, 0, 0, 0, 0, 0]],
+        [[1, 1, 1, 1, 0, 0], [1, 1, 1, 1, 0, 1], [0, 0, 0, 0, 0, 0]]),
+}
+N_SLOT_ONUS = 6
+
+
+class TestSlotGrantMap:
+    """The bs slot-to-ONU grant map is ``np.add.at`` to the bit."""
+
+    @pytest.mark.parametrize("fanin", sorted(SLOT_LAYOUTS))
+    def test_table(self, fanin):
+        sonu, valid = (np.asarray(a) for a in SLOT_LAYOUTS[fanin])
+        table = ponsim_ops.slot_of_onu(sonu, valid.astype(bool),
+                                       N_SLOT_ONUS)
+        R, S = sonu.shape
+        assert table.dtype == np.int32
+        assert table.shape == (R, N_SLOT_ONUS, fanin)
+        for r in range(R):
+            for o in range(N_SLOT_ONUS):
+                mine = [s for s in range(S) if valid[r, s] and sonu[r, s] == o]
+                assert list(table[r, o]) == mine + [S] * (fanin - len(mine))
+
+    @pytest.mark.parametrize("fanin", sorted(SLOT_LAYOUTS))
+    def test_map_bitwise_equals_add_at(self, fanin):
+        sonu, valid = (np.asarray(a) for a in SLOT_LAYOUTS[fanin])
+        valid = valid.astype(bool)
+        table = ponsim_ops.slot_of_onu(sonu, valid, N_SLOT_ONUS)
+        rows = np.broadcast_to(np.arange(sonu.shape[0])[:, None], sonu.shape)
+        mapped = jax.jit(ponsim_ops.grants_per_onu)
+        rng = np.random.default_rng(fanin)
+        for _ in range(50):
+            # magnitudes 1e-3..1e17, so the order of the adds shows; some
+            # valid slots grant exactly 0.0, as inactive slots do
+            g = 10.0 ** rng.uniform(-3.0, 17.0, sonu.shape)
+            g = np.where(valid & (rng.random(sonu.shape) < 0.85), g, 0.0)
+            want = np.zeros((sonu.shape[0], N_SLOT_ONUS))
+            np.add.at(want, (rows, sonu), g)
+            with jax.enable_x64(True):
+                got = np.asarray(mapped(g, table))
+            assert got.dtype == np.float64
+            assert np.array_equal(got.view(np.int64), want.view(np.int64))
+
+
+class TestSharedOnuBs:
+    """bs with several clients per ONU (slot fan-in K >= 2) on the device
+    engine against the numpy engine.  The public entry points refuse bs
+    with a client id of n_onus * n_pons or more, so the phase is built
+    from the engine's own layout and slot tables."""
+
+    @pytest.mark.parametrize("n_pons,cps_rate,fanin", [
+        (1, None, 3),       # ONU 1 holds clients 1, 5 and 9
+        (2, 0.6e9, 2),      # PON 0's ONU 1 holds clients 1 and 9
+    ], ids=["single_pon", "multi_pon_cps"])
+    def test_phase_parity(self, n_pons, cps_rate, fanin):
+        from repro.net.engine import (_bs_slice, _Layout, _run_phase,
+                                      _stack_slots)
+
+        n, cyc = CFG.n_onus, CFG.cycle_time_s
+        lay = _Layout([SweepCase(workload=WL_MULTI, load=0.0, policy="bs",
+                                 seed=0)], n, n_pons)
+        rate = CFG.line_rate_bps * CFG.efficiency
+        per_row = [_bs_slice([c for c in WL_MULTI.clients
+                              if c.client_id % (n * n_pons) // n == p],
+                             rate) for p in range(n_pons)]
+        kw = dict(slot_arrays=_stack_slots(per_row, n), max_t=600.0,
+                  cap_row=np.full(n_pons, rate * cyc), n_pons=n_pons,
+                  cps_cap=None if cps_rate is None else cps_rate * cyc)
+        rem0 = np.where(lay.part, lay.m_ud, 0.0)
+        ready = np.where(lay.part, lay.t_ud, np.inf)
+        before = ponsim_ops.slot_fanin()
+        dev = ponsim_ops.run_phase_device(CFG, lay, rem0, ready, "bs", **kw)
+        after = ponsim_ops.slot_fanin()
+        host = _run_phase(CFG, lay, rem0, ready, None, "bs", **kw)
+        assert {k: v - before.get(k, 0) for k, v in after.items()
+                if v != before.get(k, 0)} == {fanin: 1}
+        for d, h in zip(dev, host):
+            assert np.allclose(d, h, rtol=1e-6, equal_nan=True)
+        if cps_rate is not None:
+            # the CPS cuts: without it the phase ends otherwise
+            free = _run_phase(CFG, lay, rem0, ready, None, "bs",
+                              **{**kw, "cps_cap": None})
+            assert not np.allclose(free[0], host[0], rtol=1e-6,
+                                   equal_nan=True)
+
+    def test_one_client_per_onu_is_fanin_1(self):
+        before = ponsim_ops.slot_fanin()
+        simulate(SweepSpec(cases=(SweepCase(workload=WL, load=0.4,
+                                            policy="bs", seed=1),),
+                           pon=CFG, backend="jit"))
+        after = ponsim_ops.slot_fanin()
+        assert {k: v - before.get(k, 0) for k, v in after.items()
+                if v != before.get(k, 0)} == {1: 1}

@@ -6,9 +6,11 @@
   records the ``net.*``, ``ponsim.*`` and ``timeline.*`` spans;
 * the loop-cycle counter equals the numpy engine's per-phase cycles;
 * the spans reach a ``jax.profiler`` trace with no collector at all;
-* ``ops.scope_map`` names the scope of the compiled programs' ops.
+* ``ops.scope_map`` names the scope of the compiled programs' ops;
+* the bs programs' slot grants reach the ONUs without a scatter.
 """
 import glob
+import re
 
 import jax
 import numpy as np
@@ -250,3 +252,31 @@ def test_hlo_scopes_fill_in_what_xla_left_without_metadata():
     assert scopes["copy.3"] == "sampler"        # inside the branch it runs
     assert "copy.9" not in scopes               # loop glue stays unscoped
     assert "while.6" not in scopes
+
+
+# an HLO instruction whose opcode is scatter (its type may be a tuple)
+_SCATTER = re.compile(
+    r"^\s+(?:ROOT )?%?([\w.-]+) = (?:\([^)]*\)|\S+) scatter\(", re.M)
+OUTAGE = SweepSpec(cases=(SweepCase(workload=WL, load=0.8, policy="bs",
+                                    seed=3),),
+                   pon=CFG, backend="jit", ul_outage_s=[(0.1, 0.3)])
+
+
+@pytest.mark.parametrize("name", ["bs", "cps_both", "deadline", "outage"])
+def test_bs_slot_grants_compile_without_a_scatter(name):
+    spec = OUTAGE if name == "outage" else _spec(name)
+    programs = set()
+    for args, kw in _phase_calls(spec):
+        if args[4] != "bs":
+            continue
+        prog, dyn = ops.phase_program(*args, **kw)
+        programs.add(prog.__name__)
+        with jax.enable_x64(True):
+            text = prog.lower(dyn).compile().as_text()
+        scopes = ops.hlo_scopes(text)
+        assert "grants" in scopes.values()
+        assert [op for op in _SCATTER.findall(text)
+                if scopes.get(op) == "grants"] == [], prog.__name__
+    assert programs == {"bs": {"ponsim_bs"}, "cps_both": {"ponsim_bs_cps"},
+                        "deadline": {"ponsim_bs_deadline"},
+                        "outage": {"ponsim_bs_outage"}}[name]
