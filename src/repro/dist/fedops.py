@@ -125,23 +125,29 @@ def fedavg_pods(params, weights: jnp.ndarray, scheme: str = "none",
     With ``residuals`` (a pytree from ``init_residuals``), applies
     error-feedback compression and returns ``(avg_params,
     new_residuals)``; without, returns ``avg_params`` (unchanged
-    behaviour).
+    behaviour).  Each leaf's upload compression runs under the named
+    scope ``fed.quantise`` and its cross-pod mean under
+    ``fed.allreduce``.
     """
     w = weights.astype(jnp.float32)
     w_norm = w / jnp.sum(w)
 
     if residuals is None:
         def avg(leaf):
-            decoded = compress_pod_updates(leaf, scheme, topk_frac)
-            return pod_weighted_mean(decoded, w_norm)
+            with jax.named_scope("fed.quantise"):
+                decoded = compress_pod_updates(leaf, scheme, topk_frac)
+            with jax.named_scope("fed.allreduce"):
+                return pod_weighted_mean(decoded, w_norm)
 
         return jax.tree.map(avg, params)
 
     def avg_ef(leaf, res):
-        decoded, new_res = compress_pod_updates(
-            leaf, scheme, topk_frac, residual=res
-        )
-        return pod_weighted_mean(decoded, w_norm), new_res
+        with jax.named_scope("fed.quantise"):
+            decoded, new_res = compress_pod_updates(
+                leaf, scheme, topk_frac, residual=res
+            )
+        with jax.named_scope("fed.allreduce"):
+            return pod_weighted_mean(decoded, w_norm), new_res
 
     pairs = jax.tree.map(avg_ef, params, residuals)
     avg_params = jax.tree.map(

@@ -97,6 +97,14 @@ def make_train_step(
         return lm.loss_fn(params, cfg, batch)
 
     def step(state: TrainState, batch):
+        if cfg.dtype != "float32":
+            return _step(state, batch)
+        # a TPU's default precision rounds float32 matmul operands to
+        # bfloat16, so a float32 model asks for float32 products
+        with jax.default_matmul_precision("highest"):
+            return _step(state, batch)
+
+    def _step(state: TrainState, batch):
         if accum > 1:
             micro = jax.tree.map(
                 lambda x: x.reshape(
@@ -133,9 +141,11 @@ def make_train_step(
             schedule(state.opt.step) if schedule is not None else opt_cfg.lr,
             jnp.float32,
         )
-        params, opt, gnorm = apply_updates(
-            state.params, grads, state.opt, opt_cfg, lr=lr
-        )
+        with jax.named_scope("optimizer"):
+            params, opt, gnorm = apply_updates(
+                state.params, grads, state.opt, opt_cfg, lr=lr,
+                decay_mask=lm.weight_decay_mask(state.params),
+            )
         metrics = {"loss": loss, "grad_norm": gnorm, "lr": lr}
         return TrainState(params=params, opt=opt), metrics
 
@@ -163,10 +173,10 @@ def make_fed_train_step(
     base = make_train_step(cfg, opt_cfg, schedule,
                            grad_shardings=grad_shardings)
 
-    def step(state: TrainState, batch):
+    def fed_step(state: TrainState, batch):
         return jax.vmap(base, spmd_axis_name=spmd_axis_name)(state, batch)
 
-    return step
+    return fed_step
 
 
 # ---------------------------------------------------------------------------
@@ -202,21 +212,23 @@ def make_fed_round_step(cfg: ModelConfig, compress: Optional[str] = None,
 
     if error_feedback:
         def round_step_ef(state: TrainState, weights, residuals):
-            params, new_res = fedops.fedavg_pods(
-                state.params, weights, scheme=scheme,
-                topk_frac=topk_frac, residuals=residuals,
-            )
+            with jax.named_scope("fedavg"):
+                params, new_res = fedops.fedavg_pods(
+                    state.params, weights, scheme=scheme,
+                    topk_frac=topk_frac, residuals=residuals,
+                )
             return TrainState(params=params, opt=state.opt), new_res
 
         return round_step_ef
 
-    def round_step(state: TrainState, weights) -> TrainState:
-        params = fedops.fedavg_pods(
-            state.params, weights, scheme=scheme, topk_frac=topk_frac
-        )
+    def fed_round(state: TrainState, weights) -> TrainState:
+        with jax.named_scope("fedavg"):
+            params = fedops.fedavg_pods(
+                state.params, weights, scheme=scheme, topk_frac=topk_frac
+            )
         return TrainState(params=params, opt=state.opt)
 
-    return round_step
+    return fed_round
 
 
 def init_round_residuals(state: TrainState):

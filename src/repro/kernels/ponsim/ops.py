@@ -61,7 +61,6 @@ backends.
 """
 from __future__ import annotations
 
-import re
 from typing import Optional, Tuple
 
 import jax
@@ -72,6 +71,7 @@ from jax import lax
 from repro.kernels.ponsim import ref as _ref
 from repro.kernels.ponsim.kernel import waterfill_grants_pallas
 from repro.kernels.traffic.ref import _WIN_SHIFT, WINDOW
+from repro.obs.scopes import hlo_scopes as _hlo_scopes
 from repro.obs.trace import maybe_span
 
 CAP_EPS = 1e-9                       # repro.net.engine constants
@@ -129,82 +129,10 @@ def clear_cache() -> None:
     _arg_shapes.clear()
 
 
-_HLO_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.-]+) \(")
-_HLO_INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%?([\w.-]+) = ")
-_HLO_OP_NAME = re.compile(r'op_name="([^"]*)"')
-_HLO_CALLEE = re.compile(
-    r"(?:calls|body|condition|to_apply)=%?([\w.-]+)"
-    r"|(?:branch|called)_computations=\{([^}]*)\}")
-
-
 def hlo_scopes(text: str) -> dict:
-    """``{instruction: scope}`` of a compiled HLO module's text: the
-    first :data:`SCOPES` component of each instruction's ``op_name``.
-
-    XLA leaves some instructions it builds without metadata (a fusion
-    holding the f64 halves of a scatter, the ops of a conditional's
-    branch).  Such an instruction takes the one scope named inside the
-    computations it calls, else the scope of the instruction that calls
-    its own computation."""
-    own, comp_of, callees, members = {}, {}, {}, {}
-    comp = None
-    for line in text.splitlines():
-        head = _HLO_COMPUTATION.match(line)
-        if head:
-            comp = head.group(1)
-            continue
-        inst = _HLO_INSTRUCTION.match(line)
-        if inst is None:
-            continue
-        name = inst.group(1)
-        comp_of[name] = comp
-        members.setdefault(comp, []).append(name)
-        path = _HLO_OP_NAME.search(line)
-        scope = next((p for p in path.group(1).split("/") if p in SCOPES),
-                     None) if path else None
-        if scope is not None:
-            own[name] = scope
-        called = []
-        for single, many in _HLO_CALLEE.findall(line):
-            called += [single] if single else [
-                c.strip().lstrip("%") for c in many.split(",")]
-        if called:
-            callees[name] = called
-
-    inner: dict = {}
-
-    def named_inside(c):
-        """Scopes named in computation ``c`` and everything it calls."""
-        if c not in inner:
-            inner[c] = set()
-            found = set()
-            for n in members.get(c, ()):
-                if n in own:
-                    found.add(own[n])
-                for cc in callees.get(n, ()):
-                    found |= named_inside(cc)
-            inner[c] = found
-        return inner[c]
-
-    for name, called in callees.items():
-        if name not in own:
-            found = set().union(*(named_inside(c) for c in called))
-            if len(found) == 1:
-                own[name] = found.pop()
-    comp_scope: dict = {}
-    changed = True
-    while changed:
-        changed = False
-        for name, called in callees.items():
-            for c in called:
-                if name in own and c not in comp_scope:
-                    comp_scope[c] = own[name]
-                    changed = True
-        for name, c in comp_of.items():
-            if name not in own and c in comp_scope:
-                own[name] = comp_scope[c]
-                changed = True
-    return own
+    """``{instruction: scope}`` of a compiled phase program's HLO text,
+    over the loop body's :data:`SCOPES` (``repro.obs.scopes``)."""
+    return _hlo_scopes(text, SCOPES)
 
 
 def scope_map() -> dict:

@@ -43,6 +43,7 @@ from repro.net.jobs import JobSpec, make_competing_jobs
 from repro.net.multi_pon import MultiPonTopology
 from repro.net.sim import FLRoundWorkload, PONConfig
 from repro.net.timeline import TimelineSchedule
+from repro.obs.trace import maybe_span
 from repro.optim.optimizers import OptimizerConfig
 from repro.optim.schedules import warmup_cosine
 
@@ -55,10 +56,21 @@ class FedPods:
     live on its own devices; the jitted programs keep that layout
     (``spmd_axis_name="pod"`` inside the vmapped local step, pod-sharded
     outputs from the round steps).
+
+    :meth:`local_step` and the plain rounds of :meth:`round_step` run
+    under the host spans ``fed.step`` and ``fed.round`` (recorded into
+    ``collector`` when one is given) and are counted in
+    :meth:`counters`.  The programs are the modules ``jit_fed_step`` and
+    ``jit_fed_round``; their ops carry the named scopes ``ssd_proj``,
+    ``ssd_scan`` and ``optimizer``, and ``fedavg`` with
+    ``fed.quantise`` and ``fed.allreduce`` inside it.
     """
 
-    def __init__(self, cfg, opt_cfg, mesh, n_pods: int, schedule=None):
+    def __init__(self, cfg, opt_cfg, mesh, n_pods: int, schedule=None,
+                 collector=None):
         self.cfg, self.opt_cfg, self.n_pods = cfg, opt_cfg, n_pods
+        self.collector = collector
+        self._counts = {"fed_local_steps": 0, "fed_rounds": 0}
         specs = state_spec_tree(state_shapes(cfg, opt_cfg, n_pods), cfg,
                                 mesh, fed=True)
         self.state_sharding = jax.tree.map(
@@ -82,10 +94,38 @@ class FedPods:
                                   self.batch_sharding)
                 for k in parts[0]}
 
+    def counters(self) -> dict:
+        """Local steps and FedAvg rounds run so far, summed over pods'
+        calls (one step of all pods counts once)."""
+        return dict(self._counts)
+
+    def local_step(self, state, batch):
+        """One local step of every pod (:attr:`step`), under the span
+        ``fed.step``."""
+        with maybe_span(self.collector, "fed.step",
+                        step=self._counts["fed_local_steps"]):
+            out = self.step(state, batch)
+        self._counts["fed_local_steps"] += 1
+        return out
+
     def round_step(self, compress: Optional[str] = None):
-        return jax.jit(stepfns.make_fed_round_step(self.cfg,
-                                                   compress=compress),
-                       out_shardings=self.state_sharding)
+        """``round(state, weights) -> state``: the weighted FedAvg round
+        over the pod axis (``stepfns.make_fed_round_step``), one program
+        run under the span ``fed.round``; the jitted program is the
+        callable's ``program`` attribute."""
+        program = jax.jit(stepfns.make_fed_round_step(self.cfg,
+                                                      compress=compress),
+                          out_shardings=self.state_sharding)
+
+        def fed_round(state, weights):
+            with maybe_span(self.collector, "fed.round",
+                            round=self._counts["fed_rounds"]):
+                state = program(state, weights)
+            self._counts["fed_rounds"] += 1
+            return state
+
+        fed_round.program = program
+        return fed_round
 
     def _async_sharding(self):
         p = self.state_sharding.params
@@ -134,7 +174,6 @@ def train(
     fairness: str = "maxmin",
 ):
     from repro.obs import Collector, EventLog, SpanTracer
-    from repro.obs.trace import maybe_span
 
     if jobs > 0 and (deadline_s is not None or async_buffer is not None
                      or quorum is not None or dropout_rate > 0.0
@@ -178,9 +217,10 @@ def train(
     with mesh:
         fed = pods > 1
         if fed:
-            fed_pods = FedPods(cfg, opt_cfg, mesh, pods, schedule)
+            fed_pods = FedPods(cfg, opt_cfg, mesh, pods, schedule,
+                               collector=collector)
             state = fed_pods.init_state(jax.random.PRNGKey(0))
-            step = fed_pods.step
+            step = fed_pods.local_step
             round_step = fed_pods.round_step(compress)
         else:
             state = stepfns.init_train_state(

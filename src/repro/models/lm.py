@@ -163,6 +163,18 @@ def init_params(key, cfg: ModelConfig):
     return params
 
 
+def weight_decay_mask(params):
+    """The leaves AdamW decays: the matrices (rank 2 or more per layer).
+    A leaf under ``units`` is stacked over the pattern units on its
+    leading axis, so that axis does not count; norm scales, biases and
+    the SSD's per-head ``a_log``, ``dt_bias`` and ``d_skip`` are not
+    decayed (arXiv:2405.21060 leaves A, D and dt out of weight decay)."""
+    def mask(path, leaf):
+        return leaf.ndim - (path[0].key == "units") >= 2
+
+    return jax.tree_util.tree_map_with_path(mask, params)
+
+
 def _embed(params, cfg, tokens, extra_embeds):
     x = jnp.take(params["embed"], tokens, axis=0).astype(cfg.dtype)
     if cfg.embed_scale:

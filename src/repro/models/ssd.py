@@ -159,20 +159,24 @@ def _ssd_core(params, x, cfg, conv_state=None, h0=None):
     s = cfg.ssm
     d_inner, n_heads, _ = ssd_dims(cfg)
     Bsz, S, _ = x.shape
-    z, xbc, dt_raw = _split_proj(params, x, cfg)
-    xs, B_mat, C_mat, new_conv = _conv_split(params, xbc, cfg, conv_state)
-    dt = jax.nn.softplus(
-        dt_raw.astype(jnp.float32) + params["dt_bias"].astype(jnp.float32)
-    )
-    a = -jnp.exp(params["a_log"].astype(jnp.float32))
-    xh = xs.reshape(Bsz, S, n_heads, s.d_head)
-    y, h_last = ssd_chunked(xh, B_mat, C_mat, dt, a, s.chunk, h0)
-    y = y + xh.astype(jnp.float32) * params["d_skip"].astype(jnp.float32)[
-        None, None, :, None
-    ]
-    y = y.reshape(Bsz, S, d_inner).astype(x.dtype)
-    y = _gated_norm(y, z, params["gate_norm_scale"])
-    return y @ params["out_proj"].astype(x.dtype), new_conv, h_last
+    with jax.named_scope("ssd_proj"):
+        z, xbc, dt_raw = _split_proj(params, x, cfg)
+        xs, B_mat, C_mat, new_conv = _conv_split(params, xbc, cfg,
+                                                 conv_state)
+        dt = jax.nn.softplus(
+            dt_raw.astype(jnp.float32)
+            + params["dt_bias"].astype(jnp.float32)
+        )
+    with jax.named_scope("ssd_scan"):
+        a = -jnp.exp(params["a_log"].astype(jnp.float32))
+        xh = xs.reshape(Bsz, S, n_heads, s.d_head)
+        y, h_last = ssd_chunked(xh, B_mat, C_mat, dt, a, s.chunk, h0)
+        y = y + xh.astype(jnp.float32) * params["d_skip"].astype(
+            jnp.float32)[None, None, :, None]
+    with jax.named_scope("ssd_proj"):
+        y = y.reshape(Bsz, S, d_inner).astype(x.dtype)
+        y = _gated_norm(y, z, params["gate_norm_scale"])
+        return y @ params["out_proj"].astype(x.dtype), new_conv, h_last
 
 
 def ssd_full(params, x, cfg: ModelConfig, spec=None, positions=None):

@@ -57,8 +57,13 @@ def _clip_by_global_norm(grads, max_norm: float):
 
 
 def apply_updates(params, grads, state: OptState, cfg: OptimizerConfig,
-                  lr: Optional[jnp.ndarray] = None):
-    """Returns (new_params, new_state, grad_norm)."""
+                  lr: Optional[jnp.ndarray] = None, decay_mask=None):
+    """Returns (new_params, new_state, grad_norm).
+
+    AdamW's weight decay applies to the leaves that ``decay_mask`` (a
+    pytree of bools like ``params``) marks, by default those of rank 2
+    or more; a model whose layers are stacked on a leading axis passes
+    its own (``repro.models.lm.weight_decay_mask``)."""
     lr = cfg.lr if lr is None else lr
     if cfg.grad_clip:
         grads, gnorm = _clip_by_global_norm(grads, cfg.grad_clip)
@@ -104,13 +109,15 @@ def apply_updates(params, grads, state: OptState, cfg: OptimizerConfig,
     bc1 = 1 - cfg.b1 ** stepf
     bc2 = 1 - cfg.b2 ** stepf
 
-    def upd(p, m, v):
+    def upd(p, m, v, decay):
         mhat = m.astype(jnp.float32) / bc1
         vhat = v.astype(jnp.float32) / bc2
         u = mhat / (jnp.sqrt(vhat) + cfg.eps)
-        if cfg.weight_decay and p.ndim >= 2:     # decay matrices only
+        if cfg.weight_decay and decay:           # decay matrices only
             u = u + cfg.weight_decay * p.astype(jnp.float32)
         return (p.astype(jnp.float32) - lr * u).astype(p.dtype)
 
-    new_params = jax.tree.map(upd, params, mu, nu)
+    if decay_mask is None:
+        decay_mask = jax.tree.map(lambda p: p.ndim >= 2, params)
+    new_params = jax.tree.map(upd, params, mu, nu, decay_mask)
     return new_params, OptState(step, mu, nu), gnorm
