@@ -113,7 +113,10 @@ def sample_window_ref(keys, thresholds, win, *, n_onus: int,
     counter ``k >> 6``) instead of static. Same draws, same integer
     count and burst-length thresholds (``burst_thr`` from
     ``traffic.ref.geometric_tables``) — the produced stream is
-    bit-identical to every host backend (tested).
+    bit-identical to every host backend (tested).  Both counts are one
+    broadcast compare and a sum (the burst length through
+    ``traffic.ref.burst_length``), so the refill holds no loop and no
+    per-element gather.
     """
     keys = jnp.asarray(keys, jnp.uint32)
     thresholds = jnp.asarray(thresholds, jnp.int32)

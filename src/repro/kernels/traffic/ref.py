@@ -134,7 +134,10 @@ def geometric_tables(inv_burst: float):
     24-bit uniform (checked), so it is exactly
     ``length(u24) = 1 + #{n : u24 >= thresholds[n]}`` with int32
     ``thresholds[n]`` the first ``u24`` whose length exceeds ``n + 1``
-    — integer compares that every backend evaluates identically.
+    — integer compares that every backend evaluates identically.  The
+    device paths take that count directly, as a sum of compares against
+    every threshold (:func:`burst_length`, the Pallas kernel), never by
+    searching the table.
     """
     with jax.default_device(jax.devices("cpu")[0]):
         lut = np.asarray(geometric_lut(float(inv_burst)))
@@ -146,12 +149,30 @@ def geometric_tables(inv_burst: float):
     return lut, thresholds
 
 
+# thresholds per block of :func:`burst_length`'s table: XLA:CPU splits a
+# reduction over more than 32 elements of one axis into a tree that
+# materialises every compare.  The blocks lead the draws' axes, so the
+# TPU sums them without a layout copy of the draws.
+_LEVEL_BLOCK = 32
+
+
 def burst_length(x1, thresholds):
     """float32 burst lengths from threefry word 1 (its top 24 bits) and
-    int32 :func:`geometric_tables` thresholds."""
+    int32 :func:`geometric_tables` thresholds.
+
+    The length is ``1 + #{n : u24 >= thresholds[n]}`` (what
+    ``searchsorted(..., side="right")`` returns), taken as one broadcast
+    compare against every threshold and an int32 sum: no loop and no
+    gather, which XLA fuses into a single reduce.  The table goes in
+    front of the draws' axes as ``(blocks, 32)``, padded with the int32
+    maximum, which no 24-bit draw reaches, so the padding counts nothing.
+    """
     u24 = (jnp.asarray(x1, jnp.uint32) >> jnp.uint32(8)).astype(jnp.int32)
-    n = jnp.searchsorted(jnp.asarray(thresholds, jnp.int32), u24,
-                         side="right")
+    thresholds = jnp.asarray(thresholds, jnp.int32)
+    table = jnp.pad(thresholds, (0, -thresholds.shape[0] % _LEVEL_BLOCK),
+                    constant_values=jnp.iinfo(jnp.int32).max)
+    table = table.reshape((-1, _LEVEL_BLOCK) + (1,) * u24.ndim)
+    n = (u24 >= table).sum((0, 1), dtype=jnp.int32)
     return (n + 1).astype(jnp.float32)
 
 

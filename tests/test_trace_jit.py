@@ -7,7 +7,8 @@
 * the loop-cycle counter equals the numpy engine's per-phase cycles;
 * the spans reach a ``jax.profiler`` trace with no collector at all;
 * ``ops.scope_map`` names the scope of the compiled programs' ops;
-* the bs programs' slot grants reach the ONUs without a scatter.
+* the bs programs' slot grants reach the ONUs without a scatter;
+* the fcfs programs' sampler refills its window without a loop.
 """
 import glob
 import re
@@ -280,3 +281,32 @@ def test_bs_slot_grants_compile_without_a_scatter(name):
     assert programs == {"bs": {"ponsim_bs"}, "cps_both": {"ponsim_bs_cps"},
                         "deadline": {"ponsim_bs_deadline"},
                         "outage": {"ponsim_bs_outage"}}[name]
+
+
+# an HLO instruction whose opcode is while
+_WHILE = re.compile(
+    r"^\s+(?:ROOT )?%?([\w.-]+) = (?:\([^)]*\)|\S+) while\(", re.M)
+
+
+@pytest.mark.parametrize("name", ["fcfs_fast", "fcfs_many_per_onu",
+                                  "cps_both", "deadline"])
+def test_sampler_refill_compiles_without_a_loop(name):
+    programs = set()
+    for args, kw in _phase_calls(_spec(name)):
+        if args[4] != "fcfs":
+            continue
+        prog, dyn = ops.phase_program(*args, **kw)
+        programs.add(prog.__name__)
+        with jax.enable_x64(True):
+            text = prog.lower(dyn).compile().as_text()
+        scopes = ops.hlo_scopes(text)
+        assert "sampler" in scopes.values()
+        assert _WHILE.findall(text), prog.__name__     # the cycle loop
+        assert [op for op in _WHILE.findall(text)
+                if scopes.get(op) == "sampler"] == [], prog.__name__
+    assert programs == {
+        "fcfs_fast": {"ponsim_fcfs_fast"},
+        "fcfs_many_per_onu": {"ponsim_fcfs"},
+        "cps_both": {"ponsim_fcfs_fast_cps"},
+        "deadline": {"ponsim_fcfs_fast", "ponsim_fcfs_fast_deadline"},
+    }[name]

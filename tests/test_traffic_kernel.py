@@ -121,6 +121,33 @@ class TestBackendParity:
         assert np.array_equal(solo[0], batched[0])
 
 
+class TestBurstLength:
+    """The threshold count equals a right-sided binary search of the
+    table at every edge of it, under the scoped x64 the programs use."""
+
+    @pytest.mark.parametrize("inv_burst", [1 / 2, 1 / 16, 1 / 64],
+                             ids=["mean2", "mean16", "mean64"])
+    def test_count_is_a_right_searchsorted(self, inv_burst):
+        import jax
+
+        _, thr = traffic_ref.geometric_tables(inv_burst)
+        rng = np.random.default_rng(11)
+        u24 = np.concatenate([
+            thr - 1, thr, [0, (1 << 24) - 1],
+            rng.integers(0, 1 << 24, 4096),
+        ]).astype(np.uint32)
+        # word 1's low 8 bits are not part of the draw
+        x1 = (u24 << np.uint32(8)) | rng.integers(
+            0, 256, u24.shape).astype(np.uint32)
+        with jax.enable_x64(True):
+            got = np.asarray(jax.jit(traffic_ref.burst_length)(x1, thr))
+        want = np.searchsorted(thr, u24.astype(np.int64),
+                               side="right") + 1
+        assert got.dtype == np.float32
+        assert np.array_equal(got, want.astype(np.float32))
+        assert got.max() == len(thr) + 1 and got.min() == 1.0
+
+
 class TestDistribution:
     def test_mean_and_variance(self):
         key = ops.make_stream_key(seed=1, phase=0)
