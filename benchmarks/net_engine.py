@@ -28,7 +28,7 @@ from repro.net import (
     SweepCase,
     SweepSpec,
     simulate,
-    simulate_round,
+    simulate_round_reference,
 )
 
 TIER = "fast"
@@ -80,8 +80,8 @@ def time_reference_sweep(cfg=None, cases=None):
     cases = cases or _fig2b_cases()
     t0 = time.time()
     results = [
-        simulate_round(cfg, c.workload, c.load, c.policy, seed=c.seed,
-                       backend="reference")
+        simulate_round_reference(cfg, c.workload, c.load, c.policy,
+                                 seed=c.seed)
         for c in cases
     ]
     return time.time() - t0, results

@@ -6,9 +6,10 @@ load) grid of the Fig. 3 operating point over R rounds two ways —
 
 * ``timeline``: ONE stacked simulation (round axis folded into the
   engine batch, ``repro.net.timeline``);
-* ``per-round``: the PR 2 loop — one engine call per round, queue state
-  rebuilt every round (what ``FLNetworkCoSim`` did before the timeline
-  backend; elastic membership defeats its fixed-client-set cache);
+* ``per-round``: the sequential timeline driver (``mode="sequential"``)
+  — one engine call per round, queue state rebuilt every round (what
+  ``FLNetworkCoSim`` did before the timeline backend; elastic
+  membership defeats its fixed-client-set cache);
 
 plus timeline rounds/sec at growing ONU counts, and a module-aggregated
 profile of the folded run showing where time goes (the counter-based
@@ -38,7 +39,6 @@ from repro.net import (
     SweepSpec,
     TimelineSchedule,
     simulate,
-    simulate_timeline_per_round,
 )
 
 TIER = "slow"                     # CI's dedicated step runs it instead
@@ -213,7 +213,8 @@ def measure(full: bool = False) -> dict:
         repeats=3 if full else 2,
     )
     per_round_wall, per_round = _best_of(
-        lambda: simulate_timeline_per_round(cfg, cases, sched),
+        lambda: simulate(SweepSpec(cases=tuple(cases), pon=cfg,
+                                   schedule=sched, mode="sequential")),
         repeats=2 if full else 1,
     )
     assert all(

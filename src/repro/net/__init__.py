@@ -1,9 +1,9 @@
 """PON network substrate: traffic, DBA engines, round + timeline sims.
 
 The curated surface is ``__all__``: build a :class:`SweepSpec` and run
-it with :func:`simulate`; the ``simulate_*`` functions remain as
-compatibility entry points (their keyword forms are deprecated) and as
-parity oracles. Everything else in the submodules is internal.
+it with :func:`simulate`, the one entry point to the round and timeline
+engines. The other ``simulate_*`` functions are the cycle-level parity
+oracles. Everything else in the submodules is internal.
 """
 from repro.faults import (
     FaultSchedule,
@@ -20,10 +20,7 @@ from repro.net.dba import (
     OnuQueue,
     SlicedDBA,
 )
-from repro.net.engine import (
-    SweepCase,
-    simulate_round_sweep,
-)
+from repro.net.engine import SweepCase
 from repro.net.jobs import (
     FAIRNESS_POLICIES,
     JobRoundStats,
@@ -42,16 +39,14 @@ from repro.net.sim import (
     FLRoundWorkload,
     PONConfig,
     RoundResult,
-    simulate_round,
+    simulate_round_reference,
 )
 from repro.net.timeline import (
     DEADLINE_POLICIES,
     TimelineResult,
     TimelineRound,
     TimelineSchedule,
-    simulate_timeline_per_round,
     simulate_timeline_reference,
-    simulate_timeline_sweep,
 )
 from repro.net.traffic import (
     PACKET_BITS,
@@ -65,7 +60,7 @@ from repro.net.traffic import (
 )
 
 __all__ = [
-    # spec facade (preferred entry point)
+    # spec facade (the one engine entry point)
     "SweepSpec",
     "simulate",
     # sweep building blocks
@@ -90,15 +85,12 @@ __all__ = [
     "TimelineSchedule",
     "TimelineRound",
     "TimelineResult",
-    "simulate_timeline_sweep",
-    "simulate_timeline_per_round",
     "simulate_timeline_reference",
     # faults (re-export: timeline fault model)
     "FaultSchedule",
     "RetryPolicy",
-    # single-round entry points / oracles
-    "simulate_round_sweep",
-    "simulate_round",
+    # single-PON cycle-level oracle
+    "simulate_round_reference",
     # DBA engines
     "DEFAULT_EFFICIENCY",
     "FCFSBestEffort",

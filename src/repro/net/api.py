@@ -1,13 +1,11 @@
 """Frozen sweep-spec facade over the round and timeline engines.
 
-PR 9 redesigns the public entry points around one immutable bundle:
 :class:`SweepSpec` carries the cases, the (optional) multi-round
-schedule and every sweep-level knob that used to travel as positional
-kwargs, validates the whole bundle once (``.validate()``), and
-dispatches through :func:`simulate`. The legacy keyword forms of
-``simulate_round_sweep``/``simulate_timeline_sweep`` still work — they
-emit a ``DeprecationWarning`` and delegate to the same drivers, so the
-two paths are result-identical (asserted in ``tests/test_api.py``).
+schedule and every sweep-level knob, validates the whole bundle once
+(``.validate()``), and :func:`simulate` dispatches it to the round or
+timeline engine. ``simulate`` is the one public entry point to both
+engines; ``tests/test_api.py`` checks that every spec field reaches
+the driver.
 
 Builders cover the common shapes::
 

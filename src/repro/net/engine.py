@@ -26,10 +26,9 @@ waterfill (``repro.net.multi_pon``):
   ``dl_done``/``ready``/``ul_done`` within float tolerance when both
   consume the same arrival process (property-tested).
 
-Public API: ``SweepCase`` + ``simulate_round_sweep`` (a whole sweep as
-one stacked simulation — legacy kwarg form; prefer building a
-``repro.net.SweepSpec`` and calling ``simulate(spec)``);
-``repro.net.sim.simulate_round`` uses this as its default backend.
+Public API: ``SweepCase``; a whole sweep runs as one stacked
+simulation through ``repro.net.simulate(SweepSpec(...))``, which calls
+``_round_sweep``.
 Multi-tenant cases (``SweepCase.jobs``) add a job axis: columns gain a
 job binding next to ``cid_of`` and each cycle's FL capacity is split
 across jobs by the case's fairness policy (``repro.net.jobs``).
@@ -37,7 +36,6 @@ across jobs by the case's fairness policy (``repro.net.jobs``).
 from __future__ import annotations
 
 import functools
-import warnings
 from collections import OrderedDict
 from dataclasses import dataclass, replace
 from typing import Dict, List, Optional, Sequence
@@ -1214,11 +1212,12 @@ def _round_sweep(cfg, cases: Sequence[SweepCase],
                  ) -> List["RoundResult"]:
     """Simulate every sweep case as one stacked array simulation.
 
-    Semantics match ``repro.net.sim.simulate_round``'s reference
-    implementation per case (property-tested); both backends consume the
-    same counter-based arrival stream keyed by (seed, phase,
-    stream_round, pon), so seeded results agree across backends and
-    batch compositions unless arrivals are injected.
+    Semantics match the cycle-by-cycle oracle
+    ``repro.net.sim.simulate_round_reference`` per case
+    (property-tested); both backends consume the same counter-based
+    arrival stream keyed by (seed, phase, stream_round, pon), so seeded
+    results agree across backends and batch compositions unless
+    arrivals are injected.
 
     A shared ``SweepCase.topology`` stacks every case over its
     ``n_pons`` wavelength segments: the simulation rows become
@@ -1696,59 +1695,3 @@ def _round_sweep(cfg, cases: Sequence[SweepCase],
                 _record_job_uploads(collector, case, results[-1])
     return results
 
-
-def simulate_round_sweep(cfg, cases=None,
-                         t_round_hint: float = 10.0,
-                         max_t: float = 600.0,
-                         ul_deadline_s=None,
-                         ul_outage_s=None,
-                         collector=None,
-                         backend: Optional[str] = None,
-                         ) -> List["RoundResult"]:
-    """Public round-sweep entry point.
-
-    Preferred form: pass a ``repro.net.SweepSpec`` —
-    ``simulate_round_sweep(spec)`` or ``simulate_round_sweep(cfg,
-    spec)`` with an explicit ``PONConfig`` — which validates the bundle
-    once and dispatches to the engine (``repro.net.api.simulate`` is
-    the same call). The spec must not carry a ``schedule``; timelines
-    go through ``simulate_timeline_sweep``/``simulate``.
-
-    The legacy kwarg form ``simulate_round_sweep(cfg, cases,
-    t_round_hint=..., ul_deadline_s=..., ...)`` still works, emits a
-    ``DeprecationWarning``, and delegates to the same engine —
-    results are identical (asserted in ``tests/test_api.py``). See
-    ``_round_sweep`` for the full semantics of every knob.
-    """
-    from repro.net.api import SweepSpec, simulate
-
-    spec = None
-    pon = None
-    if isinstance(cfg, SweepSpec):
-        if cases is not None:
-            raise TypeError(
-                "simulate_round_sweep(spec) takes no second argument; "
-                "put the PONConfig in spec.pon or call "
-                "simulate_round_sweep(cfg, spec)"
-            )
-        spec = cfg
-    elif isinstance(cases, SweepSpec):
-        spec, pon = cases, cfg
-    if spec is not None:
-        if spec.schedule is not None:
-            raise ValueError(
-                "spec carries a schedule; call simulate(spec) or "
-                "simulate_timeline_sweep(spec) for timelines"
-            )
-        return simulate(spec, pon, collector=collector)
-    warnings.warn(
-        "simulate_round_sweep(cfg, cases, **kwargs) is deprecated; "
-        "build a repro.net.SweepSpec and call simulate(spec) "
-        "(or pass the spec to simulate_round_sweep)",
-        DeprecationWarning, stacklevel=2,
-    )
-    return _round_sweep(
-        cfg, cases, t_round_hint=t_round_hint, max_t=max_t,
-        ul_deadline_s=ul_deadline_s, ul_outage_s=ul_outage_s,
-        collector=collector, backend=backend,
-    )

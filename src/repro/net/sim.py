@@ -265,14 +265,13 @@ def _upstream_phase(
     return done, dict(remaining)
 
 
-def simulate_round(
+def simulate_round_reference(
     cfg: PONConfig,
     workload: FLRoundWorkload,
     total_load: float,
     policy: str,
     seed: int = 0,
     t_round_hint: float = 10.0,
-    backend: str = "vectorized",
     _dl_sources=None,
     _ul_sources=None,
     ul_deadline_s: Optional[float] = None,
@@ -281,18 +280,12 @@ def simulate_round(
     stream_round: int = 0,
     topology=None,
 ) -> RoundResult:
-    """Simulate one synchronisation round under ``policy`` in {fcfs, bs}.
+    """Cycle-by-cycle oracle of one round under ``policy`` in {fcfs, bs}.
 
-    ``backend="vectorized"`` (default) runs the round on the batched
-    array engine (``repro.net.engine``); ``backend="jit"`` runs the
-    same engine with its device cycle loop
-    (``repro.kernels.ponsim``, numpy fallback on unsupported shapes);
-    ``backend="reference"`` keeps the original cycle-by-cycle
-    simulator. Both implement the same
-    semantics (property-tested against each other). The reference
-    backend keeps its own seeded numpy arrival draws unless
-    ``_dl_sources``/``_ul_sources`` inject per-ONU sources (parity-test
-    hook; forces the reference backend) — feeding it
+    The parity reference of the batched engine behind
+    ``repro.net.simulate`` (property-tested against it). It keeps its
+    own seeded numpy arrival draws unless ``_dl_sources``/``_ul_sources``
+    inject per-ONU sources (parity-test hook) — feeding it
     ``repro.net.traffic.CounterStream`` sources replays the engine's
     exact counter-based arrival process.
 
@@ -302,36 +295,14 @@ def simulate_round(
     PON under a topology) masks upstream capacity during an ONU/link
     outage window (``repro.faults``); ``no_dl_ids`` marks deadline
     carriers that skip the model download; ``stream_round`` keys the
-    engine's arrival stream for multi-round timelines.
+    multi-PON oracle's arrival stream for multi-round timelines.
 
     ``topology`` (``repro.net.multi_pon.MultiPonTopology``) stacks the
     round over several wavelength segments sharing a CPS uplink; the
-    reference backend then runs the cycle-by-cycle multi-PON oracle
+    round then runs the cycle-by-cycle multi-PON oracle
     (``simulate_multi_pon_round``), which draws from the engine's
     counter streams directly and accepts no injected sources.
     """
-    if backend not in ("vectorized", "reference", "jit"):
-        raise ValueError(f"unknown backend {backend!r}")
-    if (backend in ("vectorized", "jit") and _dl_sources is None
-            and _ul_sources is None):
-        from repro.net.engine import SweepCase, _round_sweep
-
-        return _round_sweep(
-            cfg,
-            [SweepCase(workload=workload, load=total_load, policy=policy,
-                       seed=seed, stream_round=stream_round,
-                       no_dl_ids=frozenset(no_dl_ids),
-                       topology=topology)],
-            t_round_hint=t_round_hint,
-            ul_deadline_s=ul_deadline_s,
-            ul_outage_s=None if ul_outage_s is None else [ul_outage_s],
-            backend="jit" if backend == "jit" else None,
-        )[0]
-    if backend == "jit":
-        raise ValueError(
-            "backend='jit' cannot replay injected per-ONU sources; "
-            "use backend='vectorized' or 'reference'"
-        )
     if topology is not None and not topology.trivial:
         from repro.net.multi_pon import simulate_multi_pon_round
 

@@ -50,16 +50,15 @@ Deadline policies (``TimelineSchedule.deadline_policy``):
 
 ``simulate_timeline_reference`` is the parity oracle: an explicit
 per-round Python loop over the *cycle-by-cycle dict simulator*
-(``backend="reference"``), fed the engine's exact counter streams via
-``repro.net.traffic.CounterStream`` — extended with the same two-pass
-rule for async rounds and the same policy folding. Tests require sync
-times, per-round served bits, staleness and policy outcomes to agree
-at rtol 1e-6 for all three policies and async arrivals, including
-elastic membership and multi-PON topologies.
+(``repro.net.sim.simulate_round_reference``), fed the engine's exact
+counter streams via ``repro.net.traffic.CounterStream`` — extended
+with the same two-pass rule for async rounds and the same policy
+folding. Tests require sync times, per-round served bits, staleness
+and policy outcomes to agree at rtol 1e-6 for all three policies and
+async arrivals, including elastic membership and multi-PON topologies.
 """
 from __future__ import annotations
 
-import warnings
 from dataclasses import dataclass, field, replace
 from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
@@ -74,8 +73,6 @@ __all__ = [
     "TimelineSchedule",
     "TimelineRound",
     "TimelineResult",
-    "simulate_timeline_sweep",
-    "simulate_timeline_per_round",
     "simulate_timeline_reference",
 ]
 
@@ -1075,88 +1072,6 @@ def _timeline_sweep(cfg, cases: Sequence[SweepCase],
     raise ValueError(f"unknown mode {mode!r}")
 
 
-def simulate_timeline_sweep(cfg, cases=None, schedule=None,
-                            mode: str = "auto",
-                            t_round_hint: float = 10.0,
-                            max_t: float = 600.0,
-                            collector=None,
-                            backend: Optional[str] = None,
-                            ) -> List[TimelineResult]:
-    """Advance the full multi-round timeline for every case.
-
-    Preferred form: build a :class:`repro.net.SweepSpec` carrying a
-    ``schedule`` and pass it as the sole positional argument (or as
-    ``cases`` with a ``PONConfig`` first). The legacy
-    ``(cfg, cases, schedule, **kwargs)`` form still works but emits a
-    ``DeprecationWarning``; both forms produce identical results (the
-    spec path is a thin frozen facade over the same driver).
-
-    See ``_timeline_sweep`` for mode semantics and the collector
-    contract.
-    """
-    from repro.net.api import SweepSpec, simulate
-
-    spec = None
-    pon = None
-    if isinstance(cfg, SweepSpec):
-        if cases is not None or schedule is not None:
-            raise TypeError(
-                "pass either a SweepSpec or (cfg, cases, schedule), "
-                "not both"
-            )
-        spec = cfg
-    elif isinstance(cases, SweepSpec):
-        if schedule is not None:
-            raise TypeError(
-                "pass the schedule inside the SweepSpec, not as a "
-                "third argument"
-            )
-        spec, pon = cases, cfg
-    if spec is not None:
-        if spec.schedule is None:
-            raise ValueError(
-                "simulate_timeline_sweep needs a spec with a "
-                "schedule; use simulate(spec) or "
-                "simulate_round_sweep(spec) for single-round sweeps"
-            )
-        if mode != "auto" and mode != spec.mode:
-            spec = replace(spec, mode=mode)
-        return simulate(spec, pon, collector=collector)
-    warnings.warn(
-        "simulate_timeline_sweep(cfg, cases, schedule, **kwargs) is "
-        "deprecated; build a repro.net.SweepSpec (with .schedule) and "
-        "call simulate(spec) (or pass the spec to "
-        "simulate_timeline_sweep)",
-        DeprecationWarning, stacklevel=2,
-    )
-    return _timeline_sweep(cfg, cases, schedule, mode=mode,
-                           t_round_hint=t_round_hint, max_t=max_t,
-                           collector=collector, backend=backend)
-
-
-def simulate_timeline_per_round(cfg, cases: Sequence[SweepCase],
-                                schedule: TimelineSchedule,
-                                t_round_hint: float = 10.0,
-                                max_t: float = 600.0,
-                                collector=None,
-                                backend: Optional[str] = None,
-                                ) -> List[TimelineResult]:
-    """The PR 2 per-round loop: one engine call per round, queue state
-    rebuilt every round. Identical results to ``simulate_timeline_sweep``
-    (same streams); kept as the benchmark baseline. Async schedules run
-    the (inherently per-round) two-pass async driver. Multi-job cases
-    delegate to the folded jobs driver — their rounds are independent,
-    so the per-round baseline and the fold coincide."""
-    cases = _validate(cases, schedule)
-    if any(case.jobs is not None for case in cases):
-        return _folded_jobs(cfg, cases, schedule, "auto", t_round_hint,
-                            max_t, collector=collector, backend=backend)
-    if schedule.asynchronous:
-        return _async(cfg, cases, schedule, t_round_hint, max_t,
-                      collector=collector, backend=backend)
-    return _sequential(cfg, cases, schedule, t_round_hint, max_t,
-                       collector=collector, backend=backend)
-
 
 # ---------------------------------------------------------------------------
 # reference loop (parity oracle)
@@ -1181,7 +1096,7 @@ def simulate_timeline_reference(cfg, cases: Sequence[SweepCase],
     from repro.kernels.traffic.ops import make_stream_key
     from repro.net.engine import _case_bg_rate
     from repro.net.multi_pon import simulate_multi_pon_round
-    from repro.net.sim import simulate_round
+    from repro.net.sim import simulate_round_reference
     from repro.net.traffic import CounterStream
 
     import math
@@ -1248,9 +1163,9 @@ def simulate_timeline_reference(cfg, cases: Sequence[SweepCase],
                     )
                     for phase in (0, 1)
                 ]
-                return simulate_round(
+                return simulate_round_reference(
                     cfg, wl, case.load, case.policy, seed=case.seed,
-                    t_round_hint=t_round_hint, backend="reference",
+                    t_round_hint=t_round_hint,
                     _dl_sources=[streams[0].source(i)
                                  for i in range(cfg.n_onus)],
                     _ul_sources=[streams[1].source(i)

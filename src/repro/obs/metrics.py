@@ -20,10 +20,10 @@ Building blocks:
   do not leak outside the data range).
 
 ``Collector`` is the config-and-state object the simulation stack
-threads through (``simulate_round_sweep``/``simulate_timeline_sweep``/
-``CoSimConfig``): it owns the histograms (FL upload delay, deadline
-slack, per-cycle utilization), named counters/gauges, per-phase engine
-accumulators (``PhaseStats``) and a span tracer.  The strict contract
+threads through (``repro.net.simulate``/``CoSimConfig``): it owns the
+histograms (FL upload delay, deadline slack, per-cycle utilization),
+named counters/gauges, per-phase engine accumulators (``PhaseStats``)
+and a span tracer.  The strict contract
 everywhere it is accepted: ``collector=None`` (the default) leaves
 every output bitwise identical — metrics observe, never perturb.
 """
@@ -405,11 +405,10 @@ class DevicePhase:
 class Collector:
     """The observability hub threaded through the co-sim stack.
 
-    Passing a ``Collector`` to ``simulate_round_sweep`` /
-    ``simulate_timeline_sweep`` / ``FLNetworkCoSim.run`` /
-    ``launch.train`` turns collection on; ``None`` (the default
-    everywhere) is the strict no-op whose outputs are bitwise identical
-    to a build without this module.
+    Passing a ``Collector`` to ``repro.net.simulate`` /
+    ``FLNetworkCoSim.run`` / ``launch.train`` turns collection on;
+    ``None`` (the default everywhere) is the strict no-op whose outputs
+    are bitwise identical to a build without this module.
 
     Collected state:
 

@@ -1,13 +1,12 @@
-"""The PR 9 SweepSpec facade (``repro.net.api``).
+"""The SweepSpec facade (``repro.net.api``).
 
-The frozen spec + ``simulate`` path must be result-identical to the
-deprecated keyword forms of ``simulate_round_sweep``/
-``simulate_timeline_sweep`` (which now warn and delegate), the
+``simulate(spec)`` is the one entry point to the round and timeline
+engines: every spec field must reach the driver it dispatches to, the
 builders must compose specs without mutation, ``validate()`` must
 reject malformed bundles with actionable errors, and the curated
 ``repro.net.__all__`` plus the job-aware stream keys are pinned.
 """
-import warnings
+from dataclasses import replace
 
 import numpy as np
 import pytest
@@ -24,9 +23,8 @@ from repro.net import (
     SweepSpec,
     TimelineSchedule,
     simulate,
-    simulate_round_sweep,
-    simulate_timeline_sweep,
 )
+from repro.obs import Collector, SpanTracer
 
 CFG = PONConfig(n_onus=8, line_rate_bps=1e9)
 
@@ -49,74 +47,76 @@ def _cases(n=6):
     )
 
 
-class TestSpecKwargEquivalence:
-    def test_round_sweep(self):
+def _assert_rounds_bitwise(a, b):
+    """Sync times and per-client done times equal, NaN (cut) included."""
+    for ra, rb in zip(a, b, strict=True):
+        assert ra.sync_time == rb.sync_time
+        for attr in ("dl_done", "ul_done"):
+            da, db = getattr(ra, attr), getattr(rb, attr)
+            assert list(da) == list(db)
+            np.testing.assert_array_equal(list(da.values()),
+                                          list(db.values()))
+
+
+def _traced(run):
+    """``run(collector)``'s result and the names of the host spans it
+    recorded, the driver's footprint (less ``simulate``'s own span)."""
+    col = Collector(tracer=SpanTracer())
+    out = run(col)
+    return out, [e["name"] for e in col.tracer.events
+                 if e["name"] != "net.simulate"]
+
+
+class TestSpecFieldsReachTheDriver:
+    """Every ``SweepSpec`` field reaches the driver ``simulate`` calls:
+    ``simulate(spec)`` is bitwise the internal driver called with that
+    field as a keyword, and takes the same path (the same host spans).
+    """
+
+    @pytest.mark.parametrize("field,value", [
+        ("t_round_hint", 0.2),
+        ("max_t", 0.2),
+        ("ul_deadline_s", 0.3),
+        ("ul_outage_s", ((0.1, 0.2), (0.1, 0.2))),
+        ("backend", "jit"),
+    ], ids=["t_round_hint", "max_t", "ul_deadline_s", "ul_outage_s",
+            "backend_jit"])
+    def test_round_field(self, field, value):
+        from repro.net.engine import _round_sweep
+
         cases = _cases()
         spec = SweepSpec(cases=cases, pon=CFG)
-        new = simulate(spec)
-        with pytest.warns(DeprecationWarning, match="SweepSpec"):
-            old = simulate_round_sweep(CFG, list(cases))
-        assert [r.sync_time for r in new] == [r.sync_time for r in old]
-        assert [r.ul_done for r in new] == [r.ul_done for r in old]
+        got, got_spans = _traced(lambda col: simulate(
+            replace(spec, **{field: value}), collector=col))
+        want, want_spans = _traced(lambda col: _round_sweep(
+            CFG, list(cases), collector=col, **{field: value}))
+        _assert_rounds_bitwise(got, want)
+        assert got_spans == want_spans
+        # each value changes the run, so a field lost on the way shows
+        base, base_spans = _traced(lambda col: simulate(spec,
+                                                        collector=col))
+        assert ([r.sync_time for r in got], got_spans) != (
+            [r.sync_time for r in base], base_spans)
 
-    def test_round_sweep_knobs(self):
-        cases = _cases()
-        spec = SweepSpec(cases=cases, pon=CFG, ul_deadline_s=2.0,
-                         t_round_hint=5.0)
-        new = simulate(spec)
-        with pytest.warns(DeprecationWarning):
-            old = simulate_round_sweep(CFG, list(cases),
-                                       ul_deadline_s=2.0,
-                                       t_round_hint=5.0)
-        assert [r.sync_time for r in new] == [r.sync_time for r in old]
+    @pytest.mark.parametrize("mode", ["auto", "folded", "sequential"])
+    def test_timeline_mode(self, mode):
+        from repro.net.timeline import _timeline_sweep
 
-    def test_timeline_sweep(self):
         cases = _cases()
         sched = TimelineSchedule(n_rounds=3)
-        spec = SweepSpec(cases=cases, pon=CFG, schedule=sched)
-        new = simulate(spec)
-        with pytest.warns(DeprecationWarning, match="SweepSpec"):
-            old = simulate_timeline_sweep(CFG, list(cases), sched)
-        for a, b in zip(new, old):
+        got, got_spans = _traced(lambda col: simulate(SweepSpec(
+            cases=cases, pon=CFG, schedule=sched, mode=mode),
+            collector=col))
+        want, want_spans = _traced(lambda col: _timeline_sweep(
+            CFG, list(cases), sched, mode=mode, collector=col))
+        assert got_spans == want_spans
+        assert len(got) == len(want)
+        for a, b in zip(got, want):
             assert list(a.sync_times) == list(b.sync_times)
             assert a.total_time_s == b.total_time_s
 
-    def test_spec_through_wrappers_no_warning(self):
-        """Passing a spec to the legacy names is the blessed path."""
-        cases = _cases()
-        spec = SweepSpec(cases=cases, pon=CFG)
-        tspec = spec.with_schedule(TimelineSchedule(n_rounds=2))
-        with warnings.catch_warnings():
-            warnings.simplefilter("error", DeprecationWarning)
-            r1 = simulate_round_sweep(spec)
-            r2 = simulate_round_sweep(CFG, spec)
-            t1 = simulate_timeline_sweep(tspec)
-            t2 = simulate_timeline_sweep(CFG, tspec)
-        assert [r.sync_time for r in r1] == [
-            r.sync_time for r in simulate(spec)
-        ]
-        assert [r.sync_time for r in r2] == [r.sync_time for r in r1]
-        assert [list(t.sync_times) for t in t1] == [
-            list(t.sync_times) for t in simulate(tspec)
-        ]
-        assert [t.total_time_s for t in t2] == [
-            t.total_time_s for t in t1
-        ]
 
-    def test_wrapper_schedule_mismatch(self):
-        spec = SweepSpec(cases=_cases(), pon=CFG)
-        with pytest.raises(ValueError, match="schedule"):
-            simulate_round_sweep(
-                spec.with_schedule(TimelineSchedule(n_rounds=2))
-            )
-        with pytest.raises(ValueError, match="schedule"):
-            simulate_timeline_sweep(spec)
-
-    def test_wrapper_double_cases(self):
-        spec = SweepSpec(cases=_cases(), pon=CFG)
-        with pytest.raises(TypeError):
-            simulate_round_sweep(spec, list(_cases()))
-
+class TestSpecKwargEquivalence:
     def test_explicit_cfg_overrides_spec_pon(self):
         cases = _cases()
         big = PONConfig(n_onus=8, line_rate_bps=2e9)
@@ -230,13 +230,19 @@ class TestCuratedSurface:
                      "JobRoundStats", "job_fair_split",
                      "FAIRNESS_POLICIES", "make_competing_jobs",
                      "simulate_jobs_round_reference",
-                     "DEADLINE_POLICIES", "simulate_round_sweep",
-                     "simulate_timeline_sweep"):
+                     "DEADLINE_POLICIES", "simulate_round_reference",
+                     "simulate_timeline_reference"):
             assert name in net.__all__, name
 
     def test_internal_drivers_not_exported(self):
         assert "_round_sweep" not in net.__all__
         assert "_timeline_sweep" not in net.__all__
+        # one engine entry point; every other simulate* is an oracle
+        assert {n for n in dir(net) if n.startswith("simulate")} == {
+            "simulate", "simulate_round_reference",
+            "simulate_multi_pon_round", "simulate_jobs_round_reference",
+            "simulate_timeline_reference",
+        }
 
 
 class TestJobStreamKeys:

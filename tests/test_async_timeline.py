@@ -19,10 +19,10 @@ from repro.net import (
     MultiPonTopology,
     PONConfig,
     SweepCase,
+    SweepSpec,
     TimelineSchedule,
-    simulate_timeline_per_round,
+    simulate,
     simulate_timeline_reference,
-    simulate_timeline_sweep,
 )
 from repro.net.timeline import _round_view
 
@@ -67,7 +67,7 @@ class TestPolicyParityVsOracle:
                                  deadline_policy=dpolicy)
         cases = [SweepCase(workload=_wl(policy), load=0.6,
                            policy=policy, seed=5)]
-        eng = simulate_timeline_sweep(CFG, cases, sched)
+        eng = simulate(SweepSpec(cases=cases, pon=CFG, schedule=sched))
         ref = simulate_timeline_reference(CFG, cases, sched)
         cut = "dropped" if dpolicy == "drop" else "partial"
         assert sum(len(getattr(r, cut)) for r in eng[0].rounds) > 0, (
@@ -81,7 +81,7 @@ class TestPolicyParityVsOracle:
         sched = TimelineSchedule(n_rounds=4, buffer_k=buffer_k)
         cases = [SweepCase(workload=_wl(policy), load=0.6,
                            policy=policy, seed=5)]
-        eng = simulate_timeline_sweep(CFG, cases, sched)
+        eng = simulate(SweepSpec(cases=cases, pon=CFG, schedule=sched))
         ref = simulate_timeline_reference(CFG, cases, sched)
         _assert_equal(eng, ref)
         assert sum(len(r.deferred) for r in eng[0].rounds) > 0, (
@@ -101,7 +101,7 @@ class TestPolicyParityVsOracle:
                              deadline_policy="drop"),
         ):
             _assert_equal(
-                simulate_timeline_sweep(CFG, cases, sched),
+                simulate(SweepSpec(cases=cases, pon=CFG, schedule=sched)),
                 simulate_timeline_reference(CFG, cases, sched),
             )
 
@@ -112,10 +112,10 @@ class TestPolicyParityVsOracle:
             cases = [SweepCase(workload=_wl("fcfs"), load=0.6,
                                policy="fcfs", seed=7)]
             _assert_equal(
-                simulate_timeline_sweep(CFG, cases, sched,
-                                        mode="folded"),
-                simulate_timeline_sweep(CFG, cases, sched,
-                                        mode="sequential"),
+                simulate(SweepSpec(cases=cases, pon=CFG, schedule=sched,
+                                   mode="folded")),
+                simulate(SweepSpec(cases=cases, pon=CFG, schedule=sched,
+                                   mode="sequential")),
                 rtol=1e-12,
             )
 
@@ -130,14 +130,15 @@ class TestDeferUnchanged:
     def test_explicit_defer_identical_to_default(self):
         cases = [SweepCase(workload=_wl("fcfs"), load=0.6,
                            policy="fcfs", seed=5)]
-        a = simulate_timeline_sweep(
-            CFG, cases, TimelineSchedule(n_rounds=3, deadline_s=0.35),
-        )
-        b = simulate_timeline_sweep(
-            CFG, cases,
-            TimelineSchedule(n_rounds=3, deadline_s=0.35,
-                             deadline_policy="defer"),
-        )
+        a = simulate(SweepSpec(
+            cases=cases, pon=CFG,
+            schedule=TimelineSchedule(n_rounds=3, deadline_s=0.35),
+        ))
+        b = simulate(SweepSpec(
+            cases=cases, pon=CFG,
+            schedule=TimelineSchedule(n_rounds=3, deadline_s=0.35,
+                                      deadline_policy="defer"),
+        ))
         for x, y in zip(a[0].rounds, b[0].rounds):
             assert x.sync_time == y.sync_time
             assert x.ul_bits == y.ul_bits
@@ -164,7 +165,8 @@ class TestDeferUnchanged:
             TimelineSchedule(n_rounds=1, deadline_s=30.0,
                              deadline_policy="drop"),
         ):
-            res = simulate_timeline_sweep(cfg, [case], sched)[0]
+            res = simulate(SweepSpec(cases=[case], pon=cfg,
+                                     schedule=sched))[0]
             assert res.rounds[0].sync_time == pytest.approx(
                 5.058100000000024, abs=1e-9
             )
@@ -175,10 +177,11 @@ class TestPolicySemantics:
         sched = TimelineSchedule(n_rounds=rounds, deadline_s=deadline,
                                  deadline_policy=dpolicy)
         wl = _wl("fcfs")
-        res = simulate_timeline_sweep(
-            CFG, [SweepCase(workload=wl, load=0.6, policy="fcfs",
-                            seed=7)], sched,
-        )[0]
+        res = simulate(SweepSpec(
+            cases=[SweepCase(workload=wl, load=0.6, policy="fcfs",
+                             seed=7)],
+            pon=CFG, schedule=sched,
+        ))[0]
         return wl, res
 
     def test_drop_discards_and_reenters_fresh(self):
@@ -212,10 +215,11 @@ class TestPolicySemantics:
     def test_async_fires_at_kth_arrival(self):
         k = 2
         sched = TimelineSchedule(n_rounds=4, buffer_k=k)
-        res = simulate_timeline_sweep(
-            CFG, [SweepCase(workload=_wl("fcfs"), load=0.6,
-                            policy="fcfs", seed=7)], sched,
-        )[0]
+        res = simulate(SweepSpec(
+            cases=[SweepCase(workload=_wl("fcfs"), load=0.6,
+                             policy="fcfs", seed=7)],
+            pon=CFG, schedule=sched,
+        ))[0]
         for r in res.rounds:
             pending = len(r.ul_bits)
             assert len(r.arrived) >= min(k, pending)
@@ -228,10 +232,11 @@ class TestPolicySemantics:
 
     def test_async_staleness_counts_rounds_in_flight(self):
         sched = TimelineSchedule(n_rounds=5, buffer_k=1)
-        res = simulate_timeline_sweep(
-            CFG, [SweepCase(workload=_wl("fcfs"), load=0.6,
-                            policy="fcfs", seed=7)], sched,
-        )[0]
+        res = simulate(SweepSpec(
+            cases=[SweepCase(workload=_wl("fcfs"), load=0.6,
+                             policy="fcfs", seed=7)],
+            pon=CFG, schedule=sched,
+        ))[0]
         # with k=1 the slowest clients stay in flight across several
         # aggregations and must arrive with staleness > 0
         stale = [t for r in res.rounds for t in r.staleness.values()]
@@ -243,10 +248,11 @@ class TestPolicySemantics:
     def test_async_conserves_upload_bits(self):
         wl = _wl("fcfs")
         sched = TimelineSchedule(n_rounds=5, buffer_k=2)
-        res = simulate_timeline_sweep(
-            CFG, [SweepCase(workload=wl, load=0.6, policy="fcfs",
-                            seed=7)], sched,
-        )[0]
+        res = simulate(SweepSpec(
+            cases=[SweepCase(workload=wl, load=0.6, policy="fcfs",
+                             seed=7)],
+            pon=CFG, schedule=sched,
+        ))[0]
         m_ud = {c.client_id: c.m_ud_bits for c in wl.clients}
         served = {cid: 0.0 for cid in m_ud}
         done = {cid: 0 for cid in m_ud}
@@ -276,33 +282,22 @@ class TestScheduleValidation:
 
     def test_async_rejects_folded(self):
         with pytest.raises(ValueError, match="folded"):
-            simulate_timeline_sweep(
-                CFG,
-                [SweepCase(workload=_wl("fcfs"), load=0.5,
-                           policy="fcfs", seed=0)],
-                TimelineSchedule(n_rounds=2, buffer_k=2),
+            simulate(SweepSpec(
+                cases=[SweepCase(workload=_wl("fcfs"), load=0.5,
+                                 policy="fcfs", seed=0)],
+                pon=CFG, schedule=TimelineSchedule(n_rounds=2, buffer_k=2),
                 mode="folded",
-            )
+            ))
 
     def test_defer_deadline_rejects_folded(self):
         with pytest.raises(ValueError, match="folded"):
-            simulate_timeline_sweep(
-                CFG,
-                [SweepCase(workload=_wl("fcfs"), load=0.5,
-                           policy="fcfs", seed=0)],
-                TimelineSchedule(n_rounds=2, deadline_s=0.5),
+            simulate(SweepSpec(
+                cases=[SweepCase(workload=_wl("fcfs"), load=0.5,
+                                 policy="fcfs", seed=0)],
+                pon=CFG,
+                schedule=TimelineSchedule(n_rounds=2, deadline_s=0.5),
                 mode="folded",
-            )
-
-    def test_per_round_handles_async(self):
-        sched = TimelineSchedule(n_rounds=2, buffer_k=2)
-        cases = [SweepCase(workload=_wl("fcfs"), load=0.5,
-                           policy="fcfs", seed=0)]
-        _assert_equal(
-            simulate_timeline_per_round(CFG, cases, sched),
-            simulate_timeline_sweep(CFG, cases, sched),
-            rtol=1e-12,
-        )
+            ))
 
 
 class TestScheduleDefensiveCopies:
@@ -318,12 +313,12 @@ class TestScheduleDefensiveCopies:
                                  m_ud_bits=m_ud, deadline_s=dl)
         cases = [SweepCase(workload=_wl("fcfs"), load=0.5,
                            policy="fcfs", seed=3)]
-        before = simulate_timeline_sweep(CFG, cases, sched)
+        before = simulate(SweepSpec(cases=cases, pon=CFG, schedule=sched))
         # caller mutates everything after construction
         memb[:] = False
         m_ud[:] = 1.0
         dl[:] = 1e-4
-        after = simulate_timeline_sweep(CFG, cases, sched)
+        after = simulate(SweepSpec(cases=cases, pon=CFG, schedule=sched))
         ref = simulate_timeline_reference(CFG, cases, sched)
         _assert_equal(before, after, rtol=1e-12)
         _assert_equal(after, ref)

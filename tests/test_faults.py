@@ -40,9 +40,10 @@ from repro.net import (
     MultiPonTopology,
     PONConfig,
     SweepCase,
+    SweepSpec,
     TimelineSchedule,
+    simulate,
     simulate_timeline_reference,
-    simulate_timeline_sweep,
 )
 from repro.net.timeline import _RetryEntry, _round_setup
 
@@ -211,11 +212,12 @@ class TestFaultsOffBitwise:
         cases = [SweepCase(workload=_wl("fcfs"), load=0.6,
                            policy="fcfs", seed=5)]
         for kw in self.SCHEDS:
-            off = simulate_timeline_sweep(
-                CFG, cases, TimelineSchedule(**kw))
-            triv = simulate_timeline_sweep(
-                CFG, cases,
-                TimelineSchedule(faults=FaultSchedule(), **kw))
+            off = simulate(SweepSpec(cases=cases, pon=CFG,
+                                     schedule=TimelineSchedule(**kw)))
+            triv = simulate(SweepSpec(
+                cases=cases, pon=CFG,
+                schedule=TimelineSchedule(faults=FaultSchedule(), **kw),
+            ))
             for a, b in zip(off, triv):
                 assert np.array_equal(a.sync_times, b.sync_times)
                 for x, y in zip(a.rounds, b.rounds):
@@ -241,8 +243,8 @@ class TestFaultsOffBitwise:
                              deadline_policy="drop",
                              faults=FaultSchedule(seed=9)),
         ):
-            res = simulate_timeline_sweep(
-                PONConfig(n_onus=128), [case], sched)[0]
+            res = simulate(SweepSpec(cases=[case], pon=PONConfig(n_onus=128),
+                                     schedule=sched))[0]
             assert res.rounds[0].sync_time == pytest.approx(
                 5.058100000000024, abs=1e-9
             )
@@ -265,7 +267,7 @@ class TestFaultParityVsOracle:
         sched = TimelineSchedule(faults=FAULTS, **sched_kw)
         cases = [SweepCase(workload=_wl(policy), load=0.6,
                            policy=policy, seed=5)]
-        eng = simulate_timeline_sweep(CFG, cases, sched)
+        eng = simulate(SweepSpec(cases=cases, pon=CFG, schedule=sched))
         ref = simulate_timeline_reference(CFG, cases, sched)
         assert sum(len(r.failed) + len(r.lost)
                    for r in eng[0].rounds) > 0, (
@@ -288,7 +290,7 @@ class TestFaultParityVsOracle:
             TimelineSchedule(n_rounds=4, buffer_k=3, faults=faults),
         ):
             _assert_equal(
-                simulate_timeline_sweep(CFG, cases, sched),
+                simulate(SweepSpec(cases=cases, pon=CFG, schedule=sched)),
                 simulate_timeline_reference(CFG, cases, sched),
             )
 
@@ -301,11 +303,12 @@ class TestFaultParityVsOracle:
         sched = TimelineSchedule(n_rounds=4, faults=faults)
         cases = [SweepCase(workload=_wl("fcfs"), load=0.6,
                            policy="fcfs", seed=5)]
-        base = simulate_timeline_sweep(
-            CFG, cases, TimelineSchedule(n_rounds=4))
-        fold = simulate_timeline_sweep(CFG, cases, sched, mode="folded")
-        seq = simulate_timeline_sweep(CFG, cases, sched,
-                                      mode="sequential")
+        base = simulate(SweepSpec(cases=cases, pon=CFG,
+                                  schedule=TimelineSchedule(n_rounds=4)))
+        fold = simulate(SweepSpec(cases=cases, pon=CFG, schedule=sched,
+                                  mode="folded"))
+        seq = simulate(SweepSpec(cases=cases, pon=CFG, schedule=sched,
+                                 mode="sequential"))
         ref = simulate_timeline_reference(CFG, cases, sched)
         assert not np.array_equal(base[0].sync_times,
                                   fold[0].sync_times), (
@@ -319,7 +322,8 @@ class TestFaultParityVsOracle:
         cases = [SweepCase(workload=_wl("fcfs"), load=0.6,
                            policy="fcfs", seed=5)]
         with pytest.raises(ValueError, match="folded"):
-            simulate_timeline_sweep(CFG, cases, sched, mode="folded")
+            simulate(SweepSpec(cases=cases, pon=CFG, schedule=sched,
+                               mode="folded"))
 
 
 # ---------------------------------------------------------------------------
@@ -336,7 +340,7 @@ class TestRetrySemantics:
         )
         cases = [SweepCase(workload=_wl("fcfs"), load=0.6,
                            policy="fcfs", seed=5)]
-        return simulate_timeline_sweep(CFG, cases, sched)[0]
+        return simulate(SweepSpec(cases=cases, pon=CFG, schedule=sched))[0]
 
     def test_retry_due_rounds_follow_backoff(self):
         res = self._run()
@@ -425,7 +429,7 @@ class TestQuorumTimeline:
         cases = [SweepCase(workload=_wl("fcfs"), load=0.6,
                            policy="fcfs", seed=5)]
         sched = self._sched()
-        eng = simulate_timeline_sweep(CFG, cases, sched)
+        eng = simulate(SweepSpec(cases=cases, pon=CFG, schedule=sched))
         ref = simulate_timeline_reference(CFG, cases, sched)
         assert sum(r.deadline_extensions for r in eng[0].rounds) > 0, (
             "deadline chosen tight enough to force extensions"
@@ -433,11 +437,11 @@ class TestQuorumTimeline:
         _assert_equal(eng, ref)
 
     def test_extension_doubles_until_met_or_degrades(self):
-        res = simulate_timeline_sweep(
-            CFG, [SweepCase(workload=_wl("fcfs"), load=0.6,
-                            policy="fcfs", seed=5)],
-            self._sched(),
-        )[0]
+        res = simulate(SweepSpec(
+            cases=[SweepCase(workload=_wl("fcfs"), load=0.6,
+                             policy="fcfs", seed=5)],
+            pon=CFG, schedule=self._sched(),
+        ))[0]
         for r in res.rounds:
             assert r.quorum_met is not None
             assert 0 <= r.deadline_extensions <= 2

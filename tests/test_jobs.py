@@ -27,7 +27,6 @@ from repro.net import (
     make_competing_jobs,
     simulate,
     simulate_jobs_round_reference,
-    simulate_timeline_per_round,
 )
 
 CFG = PONConfig(n_onus=8, line_rate_bps=1e9)
@@ -366,16 +365,6 @@ class TestJobTimelines:
                 assert tl.rounds[r].job_sync[jid] == pytest.approx(
                     js.sync_time, rel=1e-6
                 )
-
-    def test_per_round_delegates_to_folded(self):
-        case = _mk_case(n_clients=6, n_jobs=2)
-        sched = TimelineSchedule(n_rounds=3)
-        a = simulate(SweepSpec(cases=(case,), pon=CFG,
-                               schedule=sched))[0]
-        b = simulate_timeline_per_round(CFG, [case], sched)[0]
-        assert [x.sync_time for x in a.rounds] == [
-            x.sync_time for x in b.rounds
-        ]
 
     def test_schedule_features_rejected(self):
         case = _mk_case(n_jobs=2)

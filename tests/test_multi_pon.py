@@ -21,12 +21,12 @@ from repro.net import (
     MultiPonTopology,
     PONConfig,
     SweepCase,
+    SweepSpec,
     TimelineSchedule,
     cps_waterfill,
+    simulate,
     simulate_multi_pon_round,
-    simulate_round_sweep,
     simulate_timeline_reference,
-    simulate_timeline_sweep,
 )
 
 CFG = PONConfig(n_onus=4, line_rate_bps=1e9)
@@ -134,11 +134,11 @@ class TestEngineMatchesOracle:
         wl = FLRoundWorkload(clients=_clients(ids, seed=trial),
                              model_bits=1.2e6)
         load = float(rng.uniform(0.1, 0.4))
-        eng = simulate_round_sweep(
-            cfg,
-            [SweepCase(workload=wl, load=load, policy=policy,
-                       seed=trial, topology=topo)],
-        )[0]
+        eng = simulate(SweepSpec(
+            cases=[SweepCase(workload=wl, load=load, policy=policy,
+                             seed=trial, topology=topo)],
+            pon=cfg,
+        ))[0]
         ref = simulate_multi_pon_round(
             cfg, topo, wl, load, policy, seed=trial
         )
@@ -155,9 +155,9 @@ class TestEngineMatchesOracle:
             for policy in ("fcfs", "bs") for load in (0.2, 0.35)
             for s in (0, 1)
         ]
-        batched = simulate_round_sweep(CFG, cases)
+        batched = simulate(SweepSpec(cases=cases, pon=CFG))
         for case, got in zip(cases, batched):
-            solo = simulate_round_sweep(CFG, [case])[0]
+            solo = simulate(SweepSpec(cases=[case], pon=CFG))[0]
             assert got.sync_time == solo.sync_time
             assert got.ul_done == solo.ul_done
 
@@ -171,10 +171,11 @@ class TestEngineMatchesOracle:
             model_bits=2e6,
         )
         eng = {
-            name: simulate_round_sweep(
-                CFG, [SweepCase(workload=wl, load=0.2, policy="fcfs",
-                                seed=0, topology=t)],
-            )[0]
+            name: simulate(SweepSpec(
+                cases=[SweepCase(workload=wl, load=0.2, policy="fcfs",
+                                 seed=0, topology=t)],
+                pon=CFG,
+            ))[0]
             for name, t in (("eq", topo_eq), ("slow", topo_slow))
         }
         ref = simulate_multi_pon_round(CFG, topo_slow, wl, 0.2, "fcfs",
@@ -196,10 +197,11 @@ class TestEngineMatchesOracle:
         syncs = []
         for cps in (None, 2.0e9, 1.5e9, 1.05e9):
             topo = MultiPonTopology(n_pons=2, cps_rate_bps=cps)
-            syncs.append(simulate_round_sweep(
-                CFG, [SweepCase(workload=wl, load=0.35, policy="fcfs",
-                                seed=1, topology=topo)],
-            )[0].sync_time)
+            syncs.append(simulate(SweepSpec(
+                cases=[SweepCase(workload=wl, load=0.35, policy="fcfs",
+                                 seed=1, topology=topo)],
+                pon=CFG,
+            ))[0].sync_time)
         assert all(b >= a - 1e-9 for a, b in zip(syncs, syncs[1:])), syncs
 
 
@@ -231,7 +233,8 @@ class TestTimelineMultiPon:
         cases = [SweepCase(workload=self._wl(policy), load=0.3,
                            policy=policy, seed=7, topology=self.TOPO)]
         self._assert_equal(
-            simulate_timeline_sweep(CFG, cases, sched, mode="folded"),
+            simulate(SweepSpec(cases=cases, pon=CFG, schedule=sched,
+                               mode="folded")),
             simulate_timeline_reference(CFG, cases, sched),
         )
 
@@ -240,7 +243,7 @@ class TestTimelineMultiPon:
         sched = TimelineSchedule(n_rounds=3, deadline_s=0.25)
         cases = [SweepCase(workload=self._wl(policy), load=0.3,
                            policy=policy, seed=9, topology=self.TOPO)]
-        eng = simulate_timeline_sweep(CFG, cases, sched)
+        eng = simulate(SweepSpec(cases=cases, pon=CFG, schedule=sched))
         ref = simulate_timeline_reference(CFG, cases, sched)
         assert sum(len(r.deferred) for r in eng[0].rounds) > 0
         self._assert_equal(eng, ref)
@@ -294,11 +297,11 @@ class TestStreamPinning:
         wl = FLRoundWorkload(clients=clients, model_bits=26.416e6)
         cfg = PONConfig(n_onus=128)
         for topo in (None, MultiPonTopology()):
-            r = simulate_round_sweep(
-                cfg,
-                [SweepCase(workload=wl, load=0.8, policy="fcfs", seed=1,
-                           topology=topo)],
-            )[0]
+            r = simulate(SweepSpec(
+                cases=[SweepCase(workload=wl, load=0.8, policy="fcfs",
+                                 seed=1, topology=topo)],
+                pon=cfg,
+            ))[0]
             assert r.sync_time == pytest.approx(5.058100000000024,
                                                 abs=1e-9)
 
@@ -313,16 +316,16 @@ class TestTopologyValidation:
                       topology=MultiPonTopology(n_pons=3)),
         ]
         with pytest.raises(ValueError, match="share one"):
-            simulate_round_sweep(CFG, cases)
+            simulate(SweepSpec(cases=cases, pon=CFG))
 
     def test_bs_ids_must_fit_the_stack(self):
         wl = FLRoundWorkload(clients=_clients([9]), model_bits=1e6)
         with pytest.raises(ValueError, match="client_id < n_onus"):
-            simulate_round_sweep(
-                CFG,
-                [SweepCase(workload=wl, load=0.3, policy="bs", seed=0,
-                           topology=MultiPonTopology(n_pons=2))],
-            )
+            simulate(SweepSpec(
+                cases=[SweepCase(workload=wl, load=0.3, policy="bs", seed=0,
+                                 topology=MultiPonTopology(n_pons=2))],
+                pon=CFG,
+            ))
 
     def test_pon_rates_length_checked(self):
         with pytest.raises(ValueError, match="pon_rates_bps"):

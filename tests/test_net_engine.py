@@ -20,8 +20,9 @@ from repro.net import (  # noqa: E402
     PONConfig,
     PrecomputedSource,
     SweepCase,
-    simulate_round,
-    simulate_round_sweep,
+    SweepSpec,
+    simulate,
+    simulate_round_reference,
 )
 
 PACKET = 12_000.0            # 1500 B frames, as the traffic model
@@ -46,16 +47,16 @@ def _run_both(cfg, wl, policy, load, seed):
                          cfg.cycle_time_s)
     ul = _arrival_matrix(rng, T, cfg.n_onus, load, cfg.line_rate_bps,
                          cfg.cycle_time_s)
-    ref = simulate_round(
-        cfg, wl, load, policy, seed=seed, backend="reference",
+    ref = simulate_round_reference(
+        cfg, wl, load, policy, seed=seed,
         _dl_sources=[PrecomputedSource(dl[:, i]) for i in range(cfg.n_onus)],
         _ul_sources=[PrecomputedSource(ul[:, i]) for i in range(cfg.n_onus)],
     )
-    eng = simulate_round_sweep(
-        cfg,
-        [SweepCase(workload=wl, load=load, policy=policy, seed=seed,
-                   dl_arrivals=dl, ul_arrivals=ul)],
-    )[0]
+    eng = simulate(SweepSpec(
+        cases=[SweepCase(workload=wl, load=load, policy=policy, seed=seed,
+                         dl_arrivals=dl, ul_arrivals=ul)],
+        pon=cfg,
+    ))[0]
     return ref, eng
 
 
@@ -161,19 +162,20 @@ class TestSeedRegression:
         return FLRoundWorkload(clients=clients, model_bits=26.416e6)
 
     def test_reference_fcfs_sync_unchanged_from_seed(self):
-        r = simulate_round(PONConfig(n_onus=128), self._workload(), 0.8,
-                           "fcfs", seed=1, backend="reference")
+        r = simulate_round_reference(PONConfig(n_onus=128),
+                                     self._workload(), 0.8, "fcfs", seed=1)
         assert r.sync_time == pytest.approx(5.029100000000014, abs=1e-9)
 
     def test_reference_bs_sync_unchanged_from_seed(self):
-        r = simulate_round(PONConfig(n_onus=128), self._workload(), 0.8,
-                           "bs", seed=1, backend="reference")
+        r = simulate_round_reference(PONConfig(n_onus=128),
+                                     self._workload(), 0.8, "bs", seed=1)
         assert r.sync_time == pytest.approx(4.909099999999974, abs=1e-9)
 
     def test_vectorized_close_to_reference_at_operating_point(self):
         # different RNG stream, same queueing model: close, not equal
-        r = simulate_round(PONConfig(n_onus=128), self._workload(), 0.8,
-                           "fcfs", seed=1, backend="vectorized")
+        case = SweepCase(workload=self._workload(), load=0.8,
+                         policy="fcfs", seed=1)
+        r = simulate(SweepSpec(cases=(case,), pon=PONConfig(n_onus=128)))[0]
         assert r.sync_time == pytest.approx(5.0291, rel=0.05)
 
 
@@ -197,9 +199,9 @@ class TestSweepAPI:
         """Batch composition must not change any case's result."""
         cfg = PONConfig(n_onus=8, line_rate_bps=1e9)
         cases = self._cases()
-        batched = simulate_round_sweep(cfg, cases)
+        batched = simulate(SweepSpec(cases=cases, pon=cfg))
         for case, got in zip(cases, batched):
-            solo = simulate_round_sweep(cfg, [case])[0]
+            solo = simulate(SweepSpec(cases=[case], pon=cfg))[0]
             assert got.sync_time == solo.sync_time
             assert got.ul_done == solo.ul_done
 
@@ -207,7 +209,8 @@ class TestSweepAPI:
         cfg = PONConfig(n_onus=8, line_rate_bps=1e9)
         res = {(c.policy, c.load, c.seed): r
                for c, r in zip(self._cases(),
-                               simulate_round_sweep(cfg, self._cases()))}
+                               simulate(SweepSpec(cases=self._cases(),
+                                                  pon=cfg)))}
         # BS is load-independent; FCFS grows with load
         assert res[("bs", 0.8, 0)].sync_time == pytest.approx(
             res[("bs", 0.3, 0)].sync_time, rel=0.05
@@ -220,10 +223,11 @@ class TestSweepAPI:
                                  m_ud_bits=1e6)]
         wl = FLRoundWorkload(clients=clients, model_bits=1e6)
         with pytest.raises(ValueError, match="client_id < n_onus"):
-            simulate_round_sweep(
-                PONConfig(n_onus=4),
-                [SweepCase(workload=wl, load=0.5, policy="bs", seed=0)],
-            )
+            simulate(SweepSpec(
+                cases=[SweepCase(workload=wl, load=0.5, policy="bs",
+                                 seed=0)],
+                pon=PONConfig(n_onus=4),
+            ))
 
     def test_duplicate_client_ids_rejected(self):
         clients = [
@@ -232,10 +236,11 @@ class TestSweepAPI:
         ]
         wl = FLRoundWorkload(clients=clients, model_bits=1e6)
         with pytest.raises(ValueError, match="duplicate client_id"):
-            simulate_round_sweep(
-                PONConfig(n_onus=4),
-                [SweepCase(workload=wl, load=0.5, policy="fcfs", seed=0)],
-            )
+            simulate(SweepSpec(
+                cases=[SweepCase(workload=wl, load=0.5, policy="fcfs",
+                                 seed=0)],
+                pon=PONConfig(n_onus=4),
+            ))
 
 
 class TestBgQueueSnapHead:

@@ -23,9 +23,9 @@ from repro.net import (
     MultiPonTopology,
     PONConfig,
     SweepCase,
+    SweepSpec,
     TimelineSchedule,
-    simulate_round_sweep,
-    simulate_timeline_sweep,
+    simulate,
 )
 from repro.net.multi_pon import simulate_multi_pon_round
 from repro.obs import (
@@ -81,9 +81,9 @@ class TestDisabledCollectorIdentity:
     def test_round_sweep_bitwise_and_pinned(self):
         cfg = PONConfig(n_onus=128)
         cases = [_op_point_case("fcfs"), _op_point_case("bs")]
-        base = simulate_round_sweep(cfg, cases)
+        base = simulate(SweepSpec(cases=cases, pon=cfg))
         col = Collector(tracer=SpanTracer())
-        inst = simulate_round_sweep(cfg, cases, collector=col)
+        inst = simulate(SweepSpec(cases=cases, pon=cfg), collector=col)
         for a, b in zip(base, inst):
             assert a.sync_time == b.sync_time
             assert a.dl_done == b.dl_done
@@ -108,9 +108,9 @@ class TestDisabledCollectorIdentity:
     def test_timeline_bitwise(self, schedule):
         cfg = PONConfig(n_onus=128)
         cases = [_op_point_case("fcfs"), _op_point_case("bs")]
-        base = simulate_timeline_sweep(cfg, cases, schedule)
-        on = simulate_timeline_sweep(cfg, cases, schedule,
-                                     collector=Collector())
+        base = simulate(SweepSpec(cases=cases, pon=cfg, schedule=schedule))
+        on = simulate(SweepSpec(cases=cases, pon=cfg,
+                                schedule=schedule), collector=Collector())
         for a, b in zip(base, on):
             assert np.array_equal(a.sync_times, b.sync_times)
             assert _fingerprint(a) == _fingerprint(b)

@@ -3,7 +3,14 @@ import numpy as np
 import pytest
 
 from repro.core.slicing import ClientProfile
-from repro.net import FLRoundWorkload, OnuQueue, PONConfig, simulate_round
+from repro.net import (
+    FLRoundWorkload,
+    OnuQueue,
+    PONConfig,
+    SweepCase,
+    SweepSpec,
+    simulate,
+)
 from repro.net.dba import FCFSBestEffort
 from repro.net.traffic import PoissonSource, background_rate_for_load
 
@@ -17,6 +24,12 @@ def mk_workload(n=32, seed=0):
         for i, t in enumerate(rng.uniform(1.0, 5.0, n))
     ]
     return FLRoundWorkload(clients=clients, model_bits=M)
+
+
+def simulate_one(cfg, wl, load, policy, seed):
+    """One round of the batched engine, through the public entry point."""
+    case = SweepCase(workload=wl, load=load, policy=policy, seed=seed)
+    return simulate(SweepSpec(cases=(case,), pon=cfg))[0]
 
 
 class TestOnuQueue:
@@ -63,28 +76,28 @@ class TestRoundSimulation:
     def test_bs_sync_time_independent_of_load(self):
         cfg = PONConfig(n_onus=32)
         wl = mk_workload(32)
-        r_low = simulate_round(cfg, wl, 0.3, "bs", seed=1)
-        r_high = simulate_round(cfg, wl, 0.8, "bs", seed=1)
+        r_low = simulate_one(cfg, wl, 0.3, "bs", seed=1)
+        r_high = simulate_one(cfg, wl, 0.8, "bs", seed=1)
         assert r_high.sync_time == pytest.approx(r_low.sync_time, rel=0.05)
 
     def test_fcfs_sync_grows_with_load(self):
         cfg = PONConfig(n_onus=32)
         wl = mk_workload(32)
-        r_low = simulate_round(cfg, wl, 0.3, "fcfs", seed=1)
-        r_high = simulate_round(cfg, wl, 0.85, "fcfs", seed=1)
+        r_low = simulate_one(cfg, wl, 0.3, "fcfs", seed=1)
+        r_high = simulate_one(cfg, wl, 0.85, "fcfs", seed=1)
         assert r_high.sync_time > r_low.sync_time
 
     def test_bs_beats_fcfs_at_high_load(self):
         cfg = PONConfig(n_onus=32)
         wl = mk_workload(32)
-        r_bs = simulate_round(cfg, wl, 0.8, "bs", seed=1)
-        r_fcfs = simulate_round(cfg, wl, 0.8, "fcfs", seed=1)
+        r_bs = simulate_one(cfg, wl, 0.8, "bs", seed=1)
+        r_fcfs = simulate_one(cfg, wl, 0.8, "fcfs", seed=1)
         assert r_bs.sync_time < r_fcfs.sync_time
 
     def test_bs_sync_pinned_near_compute_bound(self):
         cfg = PONConfig(n_onus=32)
         wl = mk_workload(32)
-        r = simulate_round(cfg, wl, 0.8, "bs", seed=1)
+        r = simulate_one(cfg, wl, 0.8, "bs", seed=1)
         # comm overhead = slice drain, a small fraction of the round
         assert r.comm_overhead < 0.25 * r.sync_time
 
@@ -92,7 +105,7 @@ class TestRoundSimulation:
         cfg = PONConfig(n_onus=16)
         wl = mk_workload(16)
         for policy in ("fcfs", "bs"):
-            r = simulate_round(cfg, wl, 0.5, policy, seed=2)
+            r = simulate_one(cfg, wl, 0.5, policy, seed=2)
             assert len(r.ul_done) == 16
             assert r.sync_time < 60.0
 

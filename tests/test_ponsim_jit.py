@@ -36,16 +36,13 @@ from repro.kernels.traffic.ref import WINDOW, geometric_tables
 from repro.net import (
     FLRoundWorkload,
     PONConfig,
-    PrecomputedSource,
     SweepCase,
     SweepSpec,
     simulate,
-    simulate_round,
-    simulate_round_sweep,
 )
 from repro.net.engine import PACKET_BITS, _waterfill
 from repro.net.multi_pon import MultiPonTopology
-from repro.net.timeline import TimelineSchedule, simulate_timeline_sweep
+from repro.net.timeline import TimelineSchedule
 from repro.net.traffic import burst_lambda
 
 CFG = PONConfig(n_onus=4, line_rate_bps=1e9)
@@ -99,7 +96,7 @@ def _assert_timeline_parity(a, b):
 
 
 class TestEngineParity:
-    """simulate_round_sweep(backend="jit") vs the default numpy engine."""
+    """``SweepSpec(backend="jit")`` vs the default numpy engine."""
 
     @pytest.mark.parametrize("policy,load", [
         ("fcfs", 0.2), ("fcfs", 0.6), ("fcfs", 0.9),
@@ -108,8 +105,8 @@ class TestEngineParity:
     def test_single_round(self, policy, load):
         wl = WL_MULTI if policy == "fcfs" else WL
         cases = [SweepCase(workload=wl, load=load, policy=policy, seed=7)]
-        a = simulate_round_sweep(CFG, cases)
-        b = simulate_round_sweep(CFG, cases, backend="jit")
+        a = simulate(SweepSpec(cases=cases, pon=CFG))
+        b = simulate(SweepSpec(cases=cases, pon=CFG, backend="jit"))
         _assert_round_parity(a[0], b[0])
 
     @pytest.mark.parametrize("policy", ["fcfs", "bs"])
@@ -117,8 +114,8 @@ class TestEngineParity:
         wl = WL_MULTI if policy == "fcfs" else WL
         cases = [SweepCase(workload=wl, load=0.8, policy=policy, seed=3)]
         kw = dict(ul_deadline_s=[1.5], ul_outage_s=[(0.2, 0.6)])
-        a = simulate_round_sweep(CFG, cases, **kw)
-        b = simulate_round_sweep(CFG, cases, backend="jit", **kw)
+        a = simulate(SweepSpec(cases=cases, pon=CFG, **kw))
+        b = simulate(SweepSpec(cases=cases, pon=CFG, backend="jit", **kw))
         _assert_round_parity(a[0], b[0])
 
     @pytest.mark.parametrize("policy", ["fcfs", "bs"])
@@ -130,22 +127,23 @@ class TestEngineParity:
         cases = [SweepCase(workload=wl, load=0.3, policy=policy, seed=5,
                            topology=topo)]
         for kw in ({}, {"ul_deadline_s": [1.2], "ul_outage_s": [outage]}):
-            a = simulate_round_sweep(CFG, cases, **kw)
-            b = simulate_round_sweep(CFG, cases, backend="jit", **kw)
+            a = simulate(SweepSpec(cases=cases, pon=CFG, **kw))
+            b = simulate(SweepSpec(cases=cases, pon=CFG, backend="jit", **kw))
             _assert_round_parity(a[0], b[0])
 
     def test_mixed_batch(self):
         cases = [SweepCase(workload=WL_MULTI, load=l, policy="fcfs", seed=s)
                  for l in (0.3, 0.7) for s in (1, 2)]
         cases.append(SweepCase(workload=WL, load=0.5, policy="bs", seed=4))
-        a = simulate_round_sweep(CFG, cases)
-        b = simulate_round_sweep(CFG, cases, backend="jit")
+        a = simulate(SweepSpec(cases=cases, pon=CFG))
+        b = simulate(SweepSpec(cases=cases, pon=CFG, backend="jit"))
         for ra, rb in zip(a, b):
             _assert_round_parity(ra, rb)
 
     def test_simulate_round_backend(self):
-        a = simulate_round(CFG, WL, 0.5, "fcfs", seed=9)
-        b = simulate_round(CFG, WL, 0.5, "fcfs", seed=9, backend="jit")
+        case = SweepCase(workload=WL, load=0.5, policy="fcfs", seed=9)
+        a = simulate(SweepSpec(cases=(case,), pon=CFG))[0]
+        b = simulate(SweepSpec(cases=(case,), pon=CFG, backend="jit"))[0]
         _assert_round_parity(a, b)
 
     def test_jit_rejects_injected_arrivals(self):
@@ -153,17 +151,11 @@ class TestEngineParity:
         cases = [SweepCase(workload=WL, load=0.3, policy="fcfs", seed=0,
                            dl_arrivals=dl, ul_arrivals=dl)]
         with pytest.raises(ValueError, match="jit"):
-            simulate_round_sweep(CFG, cases, backend="jit")
-        with pytest.raises(ValueError, match="jit"):
-            simulate_round(
-                CFG, WL, 0.3, "fcfs", seed=0, backend="jit",
-                _ul_sources=[PrecomputedSource(np.zeros(64))
-                             for _ in range(CFG.n_onus)],
-            )
+            simulate(SweepSpec(cases=cases, pon=CFG, backend="jit"))
 
 
 class TestTimelineParity:
-    """simulate_timeline_sweep(backend="jit") across every mode."""
+    """Timeline specs with ``backend="jit"`` across every mode."""
 
     @pytest.mark.parametrize("policy", ["fcfs", "bs"])
     @pytest.mark.parametrize("schedule", [
@@ -181,8 +173,9 @@ class TestTimelineParity:
             "faults"])
     def test_modes(self, policy, schedule):
         cases = [SweepCase(workload=WL, load=0.4, policy=policy, seed=11)]
-        a = simulate_timeline_sweep(CFG, cases, schedule)
-        b = simulate_timeline_sweep(CFG, cases, schedule, backend="jit")
+        a = simulate(SweepSpec(cases=cases, pon=CFG, schedule=schedule))
+        b = simulate(SweepSpec(cases=cases, pon=CFG, schedule=schedule,
+                               backend="jit"))
         _assert_timeline_parity(a[0], b[0])
 
     def test_multi_pon_timeline(self):
@@ -192,8 +185,9 @@ class TestTimelineParity:
                            topology=topo)]
         schedule = TimelineSchedule(n_rounds=3, deadline_s=0.6,
                                     deadline_policy="drop")
-        a = simulate_timeline_sweep(CFG, cases, schedule)
-        b = simulate_timeline_sweep(CFG, cases, schedule, backend="jit")
+        a = simulate(SweepSpec(cases=cases, pon=CFG, schedule=schedule))
+        b = simulate(SweepSpec(cases=cases, pon=CFG, schedule=schedule,
+                               backend="jit"))
         _assert_timeline_parity(a[0], b[0])
 
 
@@ -247,16 +241,16 @@ class TestCompileCaching:
     def test_no_retrace_on_same_shape(self):
         ponsim_ops.clear_cache()
         cases = [SweepCase(workload=WL, load=0.5, policy="fcfs", seed=21)]
-        simulate_round_sweep(CFG, cases, backend="jit")
+        simulate(SweepSpec(cases=cases, pon=CFG, backend="jit"))
         first = ponsim_ops.compile_count()
         assert first > 0
         # same spec (same shapes, same load hence same n_draws), new
         # seed: the stream keys are dynamic inputs — zero new traces
         cases2 = [SweepCase(workload=WL, load=0.5, policy="fcfs", seed=22)]
-        simulate_round_sweep(CFG, cases2, backend="jit")
+        simulate(SweepSpec(cases=cases2, pon=CFG, backend="jit"))
         assert ponsim_ops.compile_count() == first
         # new batch shape: retraces
-        simulate_round_sweep(CFG, cases + cases2, backend="jit")
+        simulate(SweepSpec(cases=cases + cases2, pon=CFG, backend="jit"))
         assert ponsim_ops.compile_count() > first
 
 
@@ -268,7 +262,7 @@ class TestPrecisionPolicy:
 
         assert jax.config.jax_enable_x64 is False
         cases = [SweepCase(workload=WL, load=0.5, policy="bs", seed=13)]
-        res = simulate_round_sweep(CFG, cases, backend="jit")
+        res = simulate(SweepSpec(cases=cases, pon=CFG, backend="jit"))
         assert np.isfinite(res[0].sync_time)
         assert jax.config.jax_enable_x64 is False
 
@@ -284,8 +278,8 @@ class TestHostFallbacks:
             before = ponsim_ops.host_fallbacks()["ring_overflow"]
             cases = [SweepCase(workload=WL, load=0.9, policy="fcfs",
                                seed=7)]
-            a = simulate_round_sweep(CFG, cases)
-            b = simulate_round_sweep(CFG, cases, backend="jit")
+            a = simulate(SweepSpec(cases=cases, pon=CFG))
+            b = simulate(SweepSpec(cases=cases, pon=CFG, backend="jit"))
             assert ponsim_ops.host_fallbacks()["ring_overflow"] > before
             _assert_round_parity(a[0], b[0])
         finally:
@@ -299,8 +293,8 @@ class TestHostFallbacks:
         cases = [SweepCase(workload=WL, load=0.3, policy="fcfs", seed=2,
                            jobs=jobs)]
         before = ponsim_ops.host_fallbacks()["multi_job"]
-        a = simulate_round_sweep(CFG, cases)
-        b = simulate_round_sweep(CFG, cases, backend="jit")
+        a = simulate(SweepSpec(cases=cases, pon=CFG))
+        b = simulate(SweepSpec(cases=cases, pon=CFG, backend="jit"))
         assert ponsim_ops.host_fallbacks()["multi_job"] == before + 1
         assert a[0].sync_time == b[0].sync_time
 
@@ -323,8 +317,8 @@ class TestPallasWaterfillKernel:
         for seed in (0, 3):
             cases = [SweepCase(workload=wl, load=load, policy=policy,
                                seed=seed)]
-            a = simulate_round_sweep(CFG, cases)
-            b = simulate_round_sweep(CFG, cases, backend="jit")
+            a = simulate(SweepSpec(cases=cases, pon=CFG))
+            b = simulate(SweepSpec(cases=cases, pon=CFG, backend="jit"))
             _assert_round_parity(a[0], b[0])
 
     def test_matches_engine_waterfill(self):

@@ -14,10 +14,10 @@ from repro.net import (
     FLRoundWorkload,
     PONConfig,
     SweepCase,
+    SweepSpec,
     TimelineSchedule,
-    simulate_timeline_per_round,
+    simulate,
     simulate_timeline_reference,
-    simulate_timeline_sweep,
 )
 
 CFG = PONConfig(n_onus=8, line_rate_bps=1e9)
@@ -68,7 +68,8 @@ class TestParityAgainstReference:
                  SweepCase(workload=_wl(policy), load=0.8,
                            policy=policy, seed=4)]
         _assert_equal(
-            simulate_timeline_sweep(CFG, cases, sched, mode="folded"),
+            simulate(SweepSpec(cases=cases, pon=CFG, schedule=sched,
+                               mode="folded")),
             simulate_timeline_reference(CFG, cases, sched),
         )
 
@@ -77,7 +78,7 @@ class TestParityAgainstReference:
         sched = TimelineSchedule(n_rounds=4, deadline_s=0.35)
         cases = [SweepCase(workload=_wl(policy), load=0.6,
                            policy=policy, seed=5)]
-        eng = simulate_timeline_sweep(CFG, cases, sched)
+        eng = simulate(SweepSpec(cases=cases, pon=CFG, schedule=sched))
         ref = simulate_timeline_reference(CFG, cases, sched)
         assert sum(len(r.deferred) for r in eng[0].rounds) > 0, (
             "deadline chosen to force deferral"
@@ -92,9 +93,10 @@ class TestParityAgainstReference:
         for policy in ("fcfs", "bs"):
             cases = [SweepCase(workload=_wl(policy), load=0.7,
                                policy=policy, seed=1)]
-            fold = simulate_timeline_sweep(CFG, cases, sched,
-                                           mode="folded")
-            seq = simulate_timeline_per_round(CFG, cases, sched)
+            fold = simulate(SweepSpec(cases=cases, pon=CFG, schedule=sched,
+                                      mode="folded"))
+            seq = simulate(SweepSpec(cases=cases, pon=CFG, schedule=sched,
+                                     mode="sequential"))
             _assert_equal(fold, seq, rtol=1e-12)
 
 
@@ -107,10 +109,11 @@ class TestMembershipDynamics:
         sched = TimelineSchedule(n_rounds=3, membership=memb)
         wl = _wl("fcfs")
         skipped = wl.clients[2].client_id
-        res = simulate_timeline_sweep(
-            CFG, [SweepCase(workload=wl, load=0.5, policy="fcfs",
-                            seed=0)], sched,
-        )[0]
+        res = simulate(SweepSpec(
+            cases=[SweepCase(workload=wl, load=0.5, policy="fcfs",
+                             seed=0)],
+            pon=CFG, schedule=sched,
+        ))[0]
         assert res.rounds[1].ul_bits.get(skipped, 0.0) == 0.0
         assert skipped not in res.rounds[1].arrived
         # and participates normally around it
@@ -124,10 +127,11 @@ class TestMembershipDynamics:
         clients = _clients(range(4))
         wl = FLRoundWorkload(clients=clients, model_bits=1e6,
                              t_aggregate=0.25)
-        res = simulate_timeline_sweep(
-            CFG, [SweepCase(workload=wl, load=0.4, policy="fcfs",
-                            seed=0)], sched,
-        )[0]
+        res = simulate(SweepSpec(
+            cases=[SweepCase(workload=wl, load=0.4, policy="fcfs",
+                             seed=0)],
+            pon=CFG, schedule=sched,
+        ))[0]
         assert res.rounds[1].sync_time == 0.25
         assert res.rounds[1].ul_bits == {}
 
@@ -139,10 +143,11 @@ class TestDeadlineDynamics:
     def _run(self, policy="fcfs", deadline=0.3, rounds=5):
         sched = TimelineSchedule(n_rounds=rounds, deadline_s=deadline)
         wl = _wl(policy)
-        return wl, simulate_timeline_sweep(
-            CFG, [SweepCase(workload=wl, load=0.6, policy=policy,
-                            seed=7)], sched,
-        )[0]
+        return wl, simulate(SweepSpec(
+            cases=[SweepCase(workload=wl, load=0.6, policy=policy,
+                             seed=7)],
+            pon=CFG, schedule=sched,
+        ))[0]
 
     def test_deferred_bits_resume_next_round(self):
         wl, res = self._run()
@@ -183,12 +188,11 @@ class TestDeadlineDynamics:
     def test_folded_mode_rejects_deadlines(self):
         sched = TimelineSchedule(n_rounds=2, deadline_s=0.5)
         with pytest.raises(ValueError, match="folded"):
-            simulate_timeline_sweep(
-                CFG,
-                [SweepCase(workload=_wl("fcfs"), load=0.5,
-                           policy="fcfs", seed=0)],
-                sched, mode="folded",
-            )
+            simulate(SweepSpec(
+                cases=[SweepCase(workload=_wl("fcfs"), load=0.5,
+                                 policy="fcfs", seed=0)],
+                pon=CFG, schedule=sched, mode="folded",
+            ))
 
 
 class TestScheduleValidation:
@@ -196,12 +200,11 @@ class TestScheduleValidation:
         sched = TimelineSchedule(n_rounds=2,
                                  membership=np.ones((2, 3), bool))
         with pytest.raises(ValueError, match="membership"):
-            simulate_timeline_sweep(
-                CFG,
-                [SweepCase(workload=_wl("fcfs"), load=0.5,
-                           policy="fcfs", seed=0)],
-                sched,
-            )
+            simulate(SweepSpec(
+                cases=[SweepCase(workload=_wl("fcfs"), load=0.5,
+                                 policy="fcfs", seed=0)],
+                pon=CFG, schedule=sched,
+            ))
 
     def test_membership_shape_checked(self):
         with pytest.raises(ValueError, match="membership"):
@@ -212,20 +215,18 @@ class TestScheduleValidation:
         case = SweepCase(workload=_wl("fcfs"), load=0.5, policy="fcfs",
                          seed=0, dl_arrivals=np.zeros((10, 8)))
         with pytest.raises(ValueError, match="counter streams"):
-            simulate_timeline_sweep(
-                CFG, [case], TimelineSchedule(n_rounds=1),
-            )
+            simulate(SweepSpec(cases=[case], pon=CFG,
+                               schedule=TimelineSchedule(n_rounds=1)))
 
     def test_per_round_m_ud_override(self):
         sched = TimelineSchedule(
             n_rounds=2, m_ud_bits=np.array([4e5, 8e5])
         )
-        res = simulate_timeline_sweep(
-            CFG,
-            [SweepCase(workload=_wl("fcfs"), load=0.4, policy="fcfs",
-                       seed=0)],
-            sched,
-        )[0]
+        res = simulate(SweepSpec(
+            cases=[SweepCase(workload=_wl("fcfs"), load=0.4, policy="fcfs",
+                             seed=0)],
+            pon=CFG, schedule=sched,
+        ))[0]
         for r, expect in zip(res.rounds, (4e5, 8e5)):
             for bits in r.ul_bits.values():
                 assert bits == pytest.approx(expect)

@@ -185,7 +185,13 @@ class TestEngineChunkInvariance:
     def test_sweep_invariant_to_chunk_target(self, monkeypatch):
         from repro.core.slicing import ClientProfile
         from repro.net import engine as E
-        from repro.net import FLRoundWorkload, PONConfig, SweepCase
+        from repro.net import (
+            FLRoundWorkload,
+            PONConfig,
+            SweepCase,
+            SweepSpec,
+            simulate,
+        )
 
         clients = [
             ClientProfile(client_id=i, t_ud=0.1 + 0.05 * i, t_dl=0.0,
@@ -197,7 +203,7 @@ class TestEngineChunkInvariance:
         case = SweepCase(workload=wl, load=0.6, policy="fcfs", seed=5)
 
         def run():
-            r = E.simulate_round_sweep(cfg, [case])[0]
+            r = simulate(SweepSpec(cases=[case], pon=cfg))[0]
             return r.sync_time, r.ul_done
 
         base_sync, base_ul = run()
