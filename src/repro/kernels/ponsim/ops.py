@@ -283,8 +283,8 @@ def _build_program(spec, lay, n_draws: int):
             overlap = (jnp.minimum(te_g, t + cyc)
                        - jnp.maximum(dyn["ts"], t))
             want = dyn["srate"] * jnp.maximum(overlap, 0.0)
-            want = jnp.minimum(
-                want, jnp.take_along_axis(backlog_onu, dyn["sonu"], 1))
+            want = jnp.minimum(want, select_columns(backlog_onu,
+                                                    dyn["sonu"]))
             want = jnp.where(active & (want > 0.0), want, 0.0)
             return want, _ref.prefix_sum(want)
 
@@ -688,8 +688,9 @@ def _build_program(spec, lay, n_draws: int):
 def slot_of_onu(sonu, svalid, n_onus: int) -> np.ndarray:
     """``(R, N, K)`` int32 table of the slots each ONU holds in each row:
     the indices of its valid slots in increasing slot order, padded with
-    ``S``, which :func:`grants_per_onu` reads as 0.0.  ``K`` is the most
-    valid slots any ONU holds in any row, and at least 1."""
+    ``S``, which matches no slot, so :func:`grants_per_onu` reads it as
+    +0.0.  ``K`` is the most valid slots any ONU holds in any row, and at
+    least 1."""
     sonu = np.asarray(sonu, np.int64)
     R, S = sonu.shape
     onu = np.where(np.asarray(svalid, bool), sonu, n_onus)  # N: invalid
@@ -710,20 +711,33 @@ def slot_of_onu(sonu, svalid, n_onus: int) -> np.ndarray:
     return table[:, :n_onus]
 
 
+def select_columns(values, index):
+    """``values[r, index[r, j]]``, ``(R, J)``, for ``values`` ``(R, C)``
+    that are all >= 0, by a one-hot select and a max over the columns,
+    without a gather: the TPU runs a lane-indexed gather nearly one
+    element at a time, the select and max on the vector unit.  Each max
+    holds at most one term that is not +0.0, so it is exactly that term;
+    an index outside ``[0, C)`` reads +0.0, and so does a -0.0, as an add
+    from +0.0 gives.  The columns run along the second axis of the
+    select, so the max adds no cross-lane step.  ``index`` is int32:
+    int64 compares are emulated on the TPU."""
+    zero = jnp.zeros((), values.dtype)
+    cols = lax.broadcasted_iota(index.dtype, (1, values.shape[1], 1), 1)
+    hit = jnp.where(index[:, None, :] == cols, values[:, :, None],
+                    zero).max(axis=1)
+    return jnp.where(hit == zero, zero, hit)
+
+
 def grants_per_onu(grants, table):
     """Slot grants ``(R, S)`` summed per ONU, ``(R, N)``, without a
-    scatter: level ``k`` of ``table`` (:func:`slot_of_onu`) gathers each
-    ONU's ``k``-th valid slot, padding (index ``S``) reads +0.0, and the
-    levels add in slot order.  ``np.add.at`` adds in that order from
-    +0.0, and invalid slots carry exactly 0.0, so the sums are bitwise
-    the numpy engine's."""
-    def level(lvl):
-        return jnp.take_along_axis(grants, table[:, :, lvl], axis=1,
-                                   mode="fill", fill_value=0.0)
-
-    acc = level(0)
+    scatter or a gather: level ``k`` of ``table`` (:func:`slot_of_onu`)
+    selects each ONU's ``k``-th valid slot (:func:`select_columns`),
+    padding (index ``S``) reads +0.0, and the levels add in slot order.
+    ``np.add.at`` adds in that order from +0.0, and invalid slots carry
+    exactly 0.0, so the sums are bitwise the numpy engine's."""
+    acc = select_columns(grants, table[:, :, 0])
     for lvl in range(1, table.shape[2]):
-        acc = acc + level(lvl)
+        acc = acc + select_columns(grants, table[:, :, lvl])
     return acc
 
 
@@ -836,7 +850,7 @@ def _phase_inputs(cfg, lay, rem_init, ready_t, mode: str, *,
         S = ts.shape[1]
         dyn.update(ts=np.asarray(ts, np.float64),
                    te=np.asarray(te, np.float64),
-                   sonu=np.asarray(sonu, np.int64),
+                   sonu=np.asarray(sonu, np.int32),
                    srate=np.asarray(srate, np.float64),
                    svalid=np.asarray(svalid, bool))
         dyn["slot_of_onu"] = slot_of_onu(dyn["sonu"], dyn["svalid"], N)

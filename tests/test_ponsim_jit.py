@@ -436,13 +436,31 @@ class TestSlotGrantMap:
         rng = np.random.default_rng(fanin)
         for _ in range(50):
             # magnitudes 1e-3..1e17, so the order of the adds shows; some
-            # valid slots grant exactly 0.0, as inactive slots do
+            # valid slots grant exactly 0.0, as inactive slots do, some
+            # -0.0, which an add from +0.0 turns into +0.0
             g = 10.0 ** rng.uniform(-3.0, 17.0, sonu.shape)
-            g = np.where(valid & (rng.random(sonu.shape) < 0.85), g, 0.0)
+            zero = np.where(valid & (rng.random(sonu.shape) < 0.5), -0.0, 0.0)
+            g = np.where(valid & (rng.random(sonu.shape) < 0.85), g, zero)
             want = np.zeros((sonu.shape[0], N_SLOT_ONUS))
             np.add.at(want, (rows, sonu), g)
             with jax.enable_x64(True):
                 got = np.asarray(mapped(g, table))
+            assert got.dtype == np.float64
+            assert np.array_equal(got.view(np.int64), want.view(np.int64))
+
+    @pytest.mark.parametrize("fanin", sorted(SLOT_LAYOUTS))
+    def test_lookup_bitwise_equals_take_along_axis(self, fanin):
+        """The ONU-to-slot lookup of the slot wants: each slot reads its
+        ONU's backlog, valid and invalid (padding) slots alike."""
+        sonu = np.asarray(SLOT_LAYOUTS[fanin][0], np.int32)
+        lookup = jax.jit(ponsim_ops.select_columns)
+        rng = np.random.default_rng(fanin)
+        for _ in range(50):
+            b = 10.0 ** rng.uniform(-3.0, 17.0, (sonu.shape[0], N_SLOT_ONUS))
+            b = np.where(rng.random(b.shape) < 0.85, b, 0.0)
+            want = np.take_along_axis(b, sonu, axis=1)
+            with jax.enable_x64(True):
+                got = np.asarray(lookup(b, sonu))
             assert got.dtype == np.float64
             assert np.array_equal(got.view(np.int64), want.view(np.int64))
 

@@ -7,7 +7,8 @@
 * the loop-cycle counter equals the numpy engine's per-phase cycles;
 * the spans reach a ``jax.profiler`` trace with no collector at all;
 * ``ops.scope_map`` names the scope of the compiled programs' ops;
-* the bs programs' slot grants reach the ONUs without a scatter;
+* the bs programs' slot grants reach the ONUs without a scatter, and
+  slots and ONUs map to each other without a gather;
 * the fcfs programs' sampler refills its window without a loop.
 """
 import glob
@@ -263,24 +264,57 @@ OUTAGE = SweepSpec(cases=(SweepCase(workload=WL, load=0.8, policy="bs",
                    pon=CFG, backend="jit", ul_outage_s=[(0.1, 0.3)])
 
 
-@pytest.mark.parametrize("name", ["bs", "cps_both", "deadline", "outage"])
-def test_bs_slot_grants_compile_without_a_scatter(name):
+BS_PROGRAMS = {"bs": {"ponsim_bs"}, "cps_both": {"ponsim_bs_cps"},
+               "deadline": {"ponsim_bs_deadline"},
+               "outage": {"ponsim_bs_outage"}}
+
+
+def _bs_compiled(name):
+    """``(program name, HLO text, scopes, (R, S, N))`` of every bs phase
+    of sweep ``name``, compiled for the CPU."""
     spec = OUTAGE if name == "outage" else _spec(name)
-    programs = set()
+    out = []
     for args, kw in _phase_calls(spec):
         if args[4] != "bs":
             continue
         prog, dyn = ops.phase_program(*args, **kw)
-        programs.add(prog.__name__)
         with jax.enable_x64(True):
             text = prog.lower(dyn).compile().as_text()
-        scopes = ops.hlo_scopes(text)
+        shape = (*dyn["ts"].shape, args[0].n_onus)
+        out.append((prog.__name__, text, ops.hlo_scopes(text), shape))
+    return out
+
+
+@pytest.mark.parametrize("name", sorted(BS_PROGRAMS))
+def test_bs_slot_grants_compile_without_a_scatter(name):
+    programs = set()
+    for prog_name, text, scopes, _ in _bs_compiled(name):
+        programs.add(prog_name)
         assert "grants" in scopes.values()
         assert [op for op in _SCATTER.findall(text)
-                if scopes.get(op) == "grants"] == [], prog.__name__
-    assert programs == {"bs": {"ponsim_bs"}, "cps_both": {"ponsim_bs_cps"},
-                        "deadline": {"ponsim_bs_deadline"},
-                        "outage": {"ponsim_bs_outage"}}[name]
+                if scopes.get(op) == "grants"] == [], prog_name
+    assert programs == BS_PROGRAMS[name]
+
+
+# an HLO instruction whose opcode is gather: its name and result dims
+_GATHER = re.compile(
+    r"^\s+(?:ROOT )?%?([\w.-]+) = \w+\[([\d,]*)\]\S* gather\(", re.M)
+
+
+@pytest.mark.parametrize("name", sorted(BS_PROGRAMS))
+def test_bs_slot_map_compiles_without_a_gather(name):
+    """Slots reach ONUs, and ONUs slots, by a one-hot select: no gather
+    of scope ``grants`` gives an ``(R, S)`` or ``(R, N)`` result, in
+    whatever shape the compiler puts it (``f64[R*S,1,1]``); the CPS
+    split's ``(R / P, 1)`` gather may stay."""
+    programs = set()
+    for prog_name, text, scopes, (R, S, N) in _bs_compiled(name):
+        programs.add(prog_name)
+        sizes = [int(np.prod([int(d) for d in dims.split(",") if d]))
+                 for op, dims in _GATHER.findall(text)
+                 if scopes.get(op) == "grants"]
+        assert R * S not in sizes and R * N not in sizes, prog_name
+    assert programs == BS_PROGRAMS[name]
 
 
 # an HLO instruction whose opcode is while
